@@ -124,8 +124,8 @@ def bench_ours():
     state, _best, _sched, rng, series = trainer.fit_staged(
         state, staged, EPOCHS, rng
     )
-    # best of two timed runs: the dev chip is shared and run-to-run
-    # contention varies by tens of percent
+    # best of two timed runs (ROADMAP S0 replaces this with a median and
+    # quartiles over repeats)
     best_dt = None
     for _ in range(2):
         t0 = time.perf_counter()
@@ -304,8 +304,7 @@ def _extra_configs():
         configs.append(dict(model_type=m, hidden=256, **oc20))
         configs.append(dict(model_type=m, hidden=256, dense=True, bf16=True,
                             **oc20))
-    # DimeNet at the BASELINE.md row scale (hidden 128; 256 is OOM-prone
-    # on a shared chip)
+    # DimeNet at hidden 128 (its published embedding width)
     configs.append(dict(model_type="DimeNet", hidden=128, **oc20))
     configs.append(dict(model_type="DimeNet", hidden=128, dense=True,
                         bf16=True, **oc20))
@@ -544,30 +543,19 @@ def bench_mesh(mesh_arg: str):
 
 def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from benchmarks.model_bench import require_tpu
+
+    # no chip, no number: a run that finds no TPU (or a device the peak
+    # table does not know) fails here instead of timing the CPU, and a
+    # phase that fails below fails the run — nothing becomes a null
+    require_tpu()
     if "--mesh" in sys.argv:
         bench_mesh(sys.argv[sys.argv.index("--mesh") + 1])
         return
-    # primary headline FIRST: a failure in the (much longer) legacy
-    # measurement must not cost the round its recorded number
     headline_row = bench_headline_mxu()
     ours = float(headline_row["graphs_per_sec"])
-    # the headline's MFU only rides the driver-parsed line when the
-    # device kind has a REAL peak entry — model_bench's 197-TFLOP/s
-    # fallback is fine for the annotated BENCH_EXTRA rows (they carry
-    # peak_tflops_assumed) but would record a fabricated campaign metric
-    # here, where no disclaimer travels with the number
-    from hydragnn_tpu.obs.ledger import PEAK_FLOPS
-
-    mfu_pct = (
-        headline_row.get("mfu_pct")
-        if headline_row.get("device_kind") in PEAK_FLOPS
-        else None
-    )
-    try:
-        legacy = bench_ours()
-    except Exception as e:
-        print(f"legacy headline failed: {e}", file=sys.stderr)
-        legacy = None
+    mfu_pct = headline_row.get("mfu_pct")
+    legacy = bench_ours()
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "BENCH_EXTRA.json")
     cursor = read_refresh_cursor(out)
@@ -590,35 +578,33 @@ def main():
         )
     from benchmarks.model_bench import make_graphs
 
-    try:
-        base = bench_torch_baseline(
-            samples=make_graphs(
-                MXU_HEADLINE["num_graphs"],
-                MXU_HEADLINE["nodes"],
-                MXU_HEADLINE["degree"],
-            ),
-            hidden=MXU_HEADLINE["hidden"],
-            steps=2,  # eager-CPU steps at this scale are seconds each
-        )
-    except Exception as e:
-        print(f"mxu baseline failed: {e}", file=sys.stderr)
-        base = None
-    legacy_base = None
-    if legacy is not None:
-        try:
-            legacy_base = bench_torch_baseline()
-        except Exception as e:
-            print(f"legacy baseline failed: {e}", file=sys.stderr)
+    base = bench_torch_baseline(
+        samples=make_graphs(
+            MXU_HEADLINE["num_graphs"],
+            MXU_HEADLINE["nodes"],
+            MXU_HEADLINE["degree"],
+        ),
+        hidden=MXU_HEADLINE["hidden"],
+        steps=2,  # eager-CPU steps at this scale are seconds each
+    )
+    legacy_base = bench_torch_baseline()
     # the machine-readable headline MUST be the last stdout line and small:
     # the driver tail-captures stdout and json-parses the final line
     sys.stdout.flush()
     print(headline_line(ours, base, legacy, legacy_base, mfu_pct=mfu_pct))
+    if failures:
+        # the failed extra rows are annotated in BENCH_EXTRA.json above;
+        # the run still fails
+        raise SystemExit(
+            f"{len(failures)} extra row(s) failed: "
+            + "; ".join(f"{kw}: {msg}" for kw, msg in failures)
+        )
 
 
 def headline_line(ours, base, legacy, legacy_base, mfu_pct=None):
     """The one driver-parsed stdout line. Compact separators and no
     legacy_metric key (it is the constant
-    ``pna_multihead_train_graphs_per_sec``, documented in BASELINE.md) keep
+    ``pna_multihead_train_graphs_per_sec``) keep
     the line tail-capture safe (<200 chars) with both headlines aboard.
     ``mfu_pct`` is the headline config's measured MFU (XLA-counted FLOPs
     vs the device-kind peak, obs/ledger.PEAK_FLOPS) — the ROADMAP's MFU

@@ -140,14 +140,6 @@ class CompileSentinel:
 
 # ---- transfer guards ------------------------------------------------------
 
-def transfer_guard_available() -> bool:
-    import jax
-
-    return hasattr(jax, "transfer_guard_device_to_host") and hasattr(
-        jax, "transfer_guard"
-    )
-
-
 @contextlib.contextmanager
 def no_host_syncs():
     """Hard-error any IMPLICIT device->host transfer in the region.
@@ -155,14 +147,9 @@ def no_host_syncs():
     Explicit fetches (``jax.device_get``) pass — they are the documented
     once-per-epoch readback. Host->device input transfers are unaffected,
     so a whole ``train_epoch`` (puts included) runs under this guard.
-    Degrades to a no-op on jax builds without the transfer-guard API
-    (tests should skip via :func:`transfer_guard_available`).
     """
     import jax
 
-    if not transfer_guard_available():
-        yield
-        return
     with jax.transfer_guard_device_to_host("disallow"):
         yield
 
@@ -174,9 +161,6 @@ def no_implicit_transfers():
     host-side (a serve dispatch)."""
     import jax
 
-    if not transfer_guard_available():
-        yield
-        return
     with jax.transfer_guard("disallow"):
         yield
 

@@ -399,8 +399,8 @@ def _pack_indices(
     past the nominal batch size — higher device throughput per epoch but
     a DIFFERENT optimization trajectory (fewer, larger steps): measured
     on QM9-at-scale round 4, budget-only packing trained to val ~6-8
-    where batch-capped packing matches the reference-semantics ~3
-    (BASELINE.md). Throughput mode stays available via
+    where batch-capped packing matches the reference-semantics ~3.
+    Throughput mode stays available via
     ``Training.bucket_graph_cap: "budget"``."""
     cap = layout.g_pad - 1  # the padding-graph slot stays reserved
     if batch_size is not None:

@@ -316,6 +316,16 @@ def optimize_concurrent(
     if pool.free is not None:
         max_concurrent = min(max_concurrent, pool.slots(launcher.nnodes))
     max_concurrent = max(1, max_concurrent)
+    if not launcher.use_srun:
+        # local trials share this host (srun gives each its node block)
+        from hydragnn_tpu.parallel.distributed import (
+            require_one_process_per_chip,
+        )
+
+        require_one_process_per_chip(
+            max_concurrent, {**os.environ, **launcher.base_env},
+            "optimize_concurrent",
+        )
 
     with ThreadPoolExecutor(max_workers=max_concurrent) as ex:
         inflight = {}

@@ -221,8 +221,8 @@ def _dimenet_geometry_dense(
     b2 = (jnp.cross(pos_ji[:, :, None, :], pos_ki) ** 2).sum(-1)
     # Legendre needs cos(angle) only: cos(atan2(b, a)) == a / hypot(a, b)
     # exactly, so the atan2+cos transcendental pair on the [N, Ko, Ki]
-    # grid becomes one rsqrt (the geometry is HALF the forward; see
-    # BASELINE.md round 4). eps guards the degenerate a=b=0 pairs
+    # grid becomes one rsqrt (the geometry was half the forward when
+    # profiled). eps guards the degenerate a=b=0 pairs
     # (masked anyway, but NaN would poison the mask multiply).
     cos_t = a * jax.lax.rsqrt(jnp.maximum(a * a + b2, 1e-24))
     cbf = jnp.stack(

@@ -13,7 +13,7 @@ alternative (gather ``w[deg]`` then batched einsum) materializes a per-
 node [in, out] weight matrix — [N, 256, 256] = 1.5 GB at hidden 256 —
 and ran HBM-bound at 65 ms/step (round-3 BENCH_EXTRA); the one-hot form
 spends K x the minimal FLOPs but they are dense matmul FLOPs, which is
-the winning trade on the MXU (see BASELINE.md round 4).
+the winning trade on the MXU.
 """
 
 from typing import Optional

@@ -78,8 +78,7 @@ class PNAConv(nn.Module):
             # as masked K-axis reductions, backward via the reverse list.
             # (A fused banded Pallas variant of this gather+stats pass was
             # built and measured in rounds 3-4 — it lost to XLA's own
-            # fusion at every scale and was deleted; closing A/B in
-            # BASELINE.md round 4.)
+            # fusion at every scale and was deleted.)
             from hydragnn_tpu.ops.dense_agg import (
                 dense_minmax,
                 dense_moments,

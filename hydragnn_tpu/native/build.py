@@ -1,11 +1,15 @@
 """Compile-on-first-use build for the native (C++) runtime components.
 
 No pip/pybind11 in the image, so bindings are ctypes over plain C ABIs and
-the shared objects are built lazily with g++ into ``native/_build/``, keyed
-by source mtime so edits trigger a rebuild.
+the shared objects are built lazily with g++ into ``native/_build/``. The
+file name carries a hash of the sources and flags, so a library is reused
+exactly when it was built from these bytes: a copied tree with reordered
+mtimes, or a stale ``.so`` left by another checkout, is never loaded.
 """
 
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 
@@ -15,16 +19,24 @@ _LOCK = threading.Lock()
 
 
 def build_library(name: str, sources, extra_flags=()) -> str:
-    """Build ``lib<name>.so`` from ``sources`` (paths relative to native/)
-    if missing or stale; returns the .so path."""
-    os.makedirs(_BUILD, exist_ok=True)
-    out = os.path.join(_BUILD, f"lib{name}.so")
+    """Build ``lib<name>-<hash>.so`` from ``sources`` (paths relative to
+    native/) unless that exact build exists; returns the .so path."""
     srcs = [os.path.join(_HERE, s) for s in sources]
+    digest = hashlib.sha256(" ".join(extra_flags).encode())
+    for s in srcs:
+        with open(s, "rb") as f:
+            digest.update(f.read())
+    out = os.path.join(_BUILD, f"lib{name}-{digest.hexdigest()[:16]}.so")
     with _LOCK:
-        if os.path.exists(out) and all(
-            os.path.getmtime(out) >= os.path.getmtime(s) for s in srcs
-        ):
+        if os.path.exists(out):
             return out
+        if shutil.which("g++") is None:
+            raise RuntimeError(
+                f"g++ not found: lib{name}.so is compiled from "
+                f"{', '.join(sources)} on first use and needs a C++17 "
+                "compiler on PATH"
+            )
+        os.makedirs(_BUILD, exist_ok=True)
         # pid-unique tmp + atomic replace: concurrent trainer processes on
         # one host may race to build the same library on a cold cache
         tmp = f"{out}.{os.getpid()}.tmp"

@@ -166,8 +166,8 @@ def mesh_context():
     return _mesh_axes, _mesh_shape
 
 
-def _collective_bytes(compiled) -> Dict[str, float]:
-    """Per-axis collective result bytes of one compiled executable ({}
+def _collective_bytes(hlo_text: str) -> Dict[str, float]:
+    """Per-axis collective result bytes of one compiled module's text ({}
     without a registered mesh or on parse failure — accounting must
     never break a capture)."""
     if _mesh_axes is None or _mesh_shape is None:
@@ -175,9 +175,7 @@ def _collective_bytes(compiled) -> Dict[str, float]:
     try:
         from hydragnn_tpu.parallel.collectives import collective_bytes_by_axis
 
-        return collective_bytes_by_axis(
-            compiled.as_text(), _mesh_axes, _mesh_shape
-        )
+        return collective_bytes_by_axis(hlo_text, _mesh_axes, _mesh_shape)
     except Exception:
         return {}
 
@@ -259,12 +257,16 @@ class InstrumentedJit:
             self._keys_seen.add(key)
             compiled = self._fn.lower(*args, **kwargs).compile()
             cost, mem = analyze_compiled(compiled)
+            hlo_text = compiled.as_text()
             rec = {
                 "name": self._name,
                 "bucket": bucket_label(self._name, key),
                 "cost": cost,
                 "memory": mem,
-                "collectives": _collective_bytes(compiled),
+                "collectives": _collective_bytes(hlo_text),
+                # Pallas/Mosaic kernel call sites in the compiled module:
+                # says whether a kernel or its XLA stand-in executed
+                "kernels": hlo_text.count('custom_call_target="tpu_custom_call"'),
             }
             _record(rec)
             if self._on_capture is not None:

@@ -587,7 +587,8 @@ class RunTelemetry:
             )
         self.emit(
             "compile", name=rec["name"], bucket=bucket, cost=cost,
-            memory=mem, **({"collectives": coll} if coll else {}),
+            memory=mem, kernels=int(rec.get("kernels", 0)),
+            **({"collectives": coll} if coll else {}),
         )
 
     def profile(self, steps: int) -> Dict:
@@ -616,7 +617,7 @@ class RunTelemetry:
             config_hash=_config_hash(config),
             git_rev=_git_rev(),
             world_size=jax.process_count(),
-            device_kind=devices[0].platform if devices else "none",
+            device_kind=devices[0].device_kind if devices else "none",
             device_count=len(devices),
             num_epoch=int(
                 config.get("NeuralNetwork", {})

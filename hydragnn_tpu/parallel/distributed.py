@@ -60,6 +60,36 @@ def setup_distributed() -> Tuple[int, int]:
     return jax.process_count(), jax.process_index()
 
 
+def host_tpu_chips() -> int:
+    """TPU chips of THIS host, counted from their device files (one
+    ``/dev/vfio/<n>`` each on a v5e host): a launcher asks before it
+    spawns, and must not start JAX to find out — a parent that has touched
+    JAX holds the chip its children need."""
+    import glob
+
+    return len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+def require_one_process_per_chip(n_children: int, child_env, what: str):
+    """A chip belongs to one process at a time. A launcher that hands every
+    child the same environment gives each the same view of the host's
+    chips, so of several concurrent children on one TPU host all but the
+    first would fail or hang at start-up — say so and fail here instead.
+    Children pinned to the CPU never ask for a chip."""
+    chips = host_tpu_chips()
+    if n_children < 2 or not chips:
+        return
+    if (child_env.get("JAX_PLATFORMS") or "").split(",")[0] == "cpu":
+        return
+    raise RuntimeError(
+        f"{what}: {n_children} concurrent child processes would each ask "
+        f"for this host's {chips} TPU chip(s); a chip belongs to "
+        "one process at a time, so all but the first would fail or hang at "
+        "start-up. Run one chip-using child per host, or pin the children "
+        "to the CPU (JAX_PLATFORMS=cpu)."
+    )
+
+
 def get_comm_size_and_rank() -> Tuple[int, int]:
     import jax
 

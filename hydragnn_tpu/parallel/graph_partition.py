@@ -565,7 +565,6 @@ def make_partitioned_apply(model, mesh, axis: str = GRAPH_AXIS):
     Graph-head rows come back replicated-identical on every shard; node-head
     rows are per-shard (un-partition with ``PartitionInfo.gather_nodes``).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fwd(variables, batch):
@@ -574,12 +573,12 @@ def make_partitioned_apply(model, mesh, axis: str = GRAPH_AXIS):
         def shard_fn(variables, batch):
             return model.apply(variables, batch, train=False)
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), _batch_spec(batch, axis)),
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )(variables, batch)
 
     return jax.jit(fwd)
@@ -590,12 +589,11 @@ def make_partitioned_train_step(model, tx, mesh, axis: str = GRAPH_AXIS):
     (all_to_all transposes inserted by AD) + grad psum + optimizer update.
 
     The differentiated objective is the per-shard share ``loss / P`` — with
-    ``check_rep=False`` every collective transposes to its true adjoint, so
+    ``check_vma=False`` every collective transposes to its true adjoint, so
     ``psum`` of the per-shard grads reconstructs the exact global gradient
     (asserted against the single-device model in
     ``tests/test_graph_partition.py``).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis_size = int(mesh.shape[axis])
@@ -641,7 +639,7 @@ def make_partitioned_train_step(model, tx, mesh, axis: str = GRAPH_AXIS):
             }
             return new_params, new_bs, new_opt, step_no + 1, metrics
 
-        new_params, new_bs, new_opt, step_no, metrics = shard_map(
+        new_params, new_bs, new_opt, step_no, metrics = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(
@@ -653,7 +651,7 @@ def make_partitioned_train_step(model, tx, mesh, axis: str = GRAPH_AXIS):
                 P(),
             ),
             out_specs=(P(), P(), P(), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(state.params, state.batch_stats, state.opt_state, state.step, batch, rng)
         return (
             state.replace(
@@ -669,7 +667,6 @@ def make_partitioned_train_step(model, tx, mesh, axis: str = GRAPH_AXIS):
 
 
 def make_partitioned_eval_step(model, mesh, axis: str = GRAPH_AXIS):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def eval_step(params, batch_stats, batch):
@@ -687,7 +684,7 @@ def make_partitioned_eval_step(model, mesh, axis: str = GRAPH_AXIS):
                 "outputs": outputs,
             }
 
-        return shard_map(
+        return jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), P(), _batch_spec(batch, axis)),
@@ -698,7 +695,7 @@ def make_partitioned_eval_step(model, mesh, axis: str = GRAPH_AXIS):
                     lambda _: P(axis), tuple(range(model.num_heads))
                 ),
             },
-            check_rep=False,
+            check_vma=False,
         )(params, batch_stats, batch)
 
     return jax.jit(eval_step)

@@ -1054,6 +1054,7 @@ class ServingFleet:
     # -- lifecycle -----------------------------------------------------------
     def start(self, wait_serving: bool = True,
               timeout: Optional[float] = None) -> "ServingFleet":
+        self._require_chips(self.target)
         for sub in (f"{REPLICA}s", "dead", "promote",
                     os.path.join("promote", "active-byname")):
             os.makedirs(os.path.join(self.coord_dir, sub), exist_ok=True)
@@ -1176,6 +1177,7 @@ class ServingFleet:
         n = int(n_replicas)
         if n < 1:
             raise ValueError(f"n_replicas must be >= 1, got {n}")
+        self._require_chips(n)
         grown: List[_ReplicaHandle] = []
         shrunk: List[_ReplicaHandle] = []
         with self._lock:
@@ -1212,6 +1214,17 @@ class ServingFleet:
         return n
 
     # -- spawning ------------------------------------------------------------
+    def _require_chips(self, n_replicas: int):
+        """Every replica inherits one environment, i.e. one view of the
+        host's chips (this supervisor itself never starts JAX)."""
+        from hydragnn_tpu.parallel.distributed import (
+            require_one_process_per_chip,
+        )
+
+        require_one_process_per_chip(
+            n_replicas, {**os.environ, **self.extra_env}, "ServingFleet"
+        )
+
     def _worker_env(self, handle: _ReplicaHandle) -> Dict[str, str]:
         env = dict(os.environ)
         env.update(self.extra_env)

@@ -55,8 +55,8 @@ class PredictMixin:
         nbatch = _nbatch(loader)
 
         # device-resident fast path (single-process): run the whole test
-        # set as ONE scan and do ONE readback — per-batch output fetches
-        # cost a full host round trip each on tunneled backends. Own knob
+        # set as ONE scan and do ONE readback in place of a blocking
+        # output fetch per batch. Own knob
         # (default: follows the training-set flag) because the TEST set +
         # stacked outputs have their own HBM footprint; non-uniform batch
         # shapes or an over-budget stage fall back to streaming.

@@ -68,6 +68,21 @@ def make_samples(num=24, seed=11):
     return out
 
 
+def _start_barrier(barrier_dir, rank, world, timeout_s=120.0):
+    """Best-effort rendezvous: every rank drops a file, then waits until
+    ``world`` files exist. A respawn inside one generation finds its peers'
+    files already there and proceeds at once."""
+    import time
+
+    os.makedirs(barrier_dir, exist_ok=True)
+    open(os.path.join(barrier_dir, str(rank)), "w").close()
+    deadline = time.monotonic() + timeout_s
+    while (
+        len(os.listdir(barrier_dir)) < world and time.monotonic() < deadline
+    ):
+        time.sleep(0.02)
+
+
 def worker_main(workdir):
     # ONE virtual CPU device per process; must happen before backend init
     os.environ["XLA_FLAGS"] = (
@@ -164,21 +179,20 @@ def worker_main(workdir):
         {"NeuralNetwork": {"Training": training}}, LOG_NAME
     )
 
-    # start-aligned epoch 0: the coordination-service barrier (plain RPC,
-    # no XLA collective — works on every backend) removes the multi-second
+    # start-aligned epoch 0: a file rendezvous on the shared workdir (no
+    # XLA collective — works on every backend) removes the multi-second
     # process-startup skew, so a fault at rank K's step N lands while the
     # other ranks are near step N too. On real accelerators the first
     # cross-host collective provides this alignment for free.
     if world > 1:
-        try:
-            from jax._src import distributed as _dist
-
-            if _dist.global_state.client is not None:
-                _dist.global_state.client.wait_at_barrier(
-                    "hydragnn_elastic_start", 120_000
-                )
-        except Exception:
-            pass
+        _start_barrier(
+            # cwd is the workdir (chdir above)
+            os.path.join(
+                "elastic-coord",
+                f"start-gen{os.getenv('HYDRAGNN_ELASTIC_GEN', '0')}",
+            ),
+            rank, world,
+        )
 
     # resume whenever a checkpoint (or an intact rolling fallback) exists:
     # gen 0 restarts and post-resize respawns share this one path

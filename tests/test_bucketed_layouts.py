@@ -93,7 +93,7 @@ def pytest_every_packed_batch_fits_its_layout():
 def pytest_bucket_graph_cap_matches_reference_step_semantics():
     """Default packing caps every batch at batch_size GRAPHS (a reference
     step is batch_size graphs; budget-only packing trains a different
-    trajectory — QM9-at-scale round 4, BASELINE.md). 'budget' mode keeps
+    trajectory — QM9-at-scale, round 4). 'budget' mode keeps
     the pure-throughput fill available."""
     samples = _oc20_shaped(300, seed=3)
     layout = compute_layout([samples], batch_size=8, num_buckets=3)

@@ -140,7 +140,7 @@ def pytest_update_config_pna_degree_histogram():
 
 
 def pytest_auto_dense_aggregation_policy():
-    """The measured-crossover policy (BASELINE.md): scatter-heavy models
+    """The measured-crossover policy (ops/autotune.py): scatter-heavy models
     pick the dense path at MXU widths with NO config flag; SchNet/EGNN
     never do; an explicit flag and partition mode always win."""
     from hydragnn_tpu.data.loaders import needs_dense_neighbors
@@ -159,7 +159,7 @@ def pytest_auto_dense_aggregation_policy():
     # CGCNN's own rule keys on input_dim — its true conv width — and
     # INVERSELY: the dense frame's gather traffic grows with input width
     # while the scatter cost it removes stays flat (round-5 measured
-    # crossover, BASELINE.md). Narrow inputs (the realistic case) go dense.
+    # crossover). Narrow inputs (the realistic case) go dense.
     assert needs_dense_neighbors(
         {"model_type": "CGCNN", "hidden_dim": 64, "input_dim": 4}
     )

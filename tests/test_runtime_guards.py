@@ -26,7 +26,6 @@ from hydragnn_tpu.analysis.guards import (
     CompileSentinel,
     RecompileError,
     no_host_syncs,
-    transfer_guard_available,
 )
 from hydragnn_tpu.graph import collate_graphs, pad_sizes_for
 from hydragnn_tpu.models import create_model_config
@@ -138,8 +137,6 @@ def pytest_train_step_compiles_once_across_two_epochs():
 def _guard_enforces() -> bool:
     """Does this backend actually error on implicit D2H transfers? The
     CPU platform stores arrays host-side — nothing to guard."""
-    if not transfer_guard_available():
-        return False
     x = jax.jit(lambda v: v + 1)(np.ones((), np.float32))
     try:
         with no_host_syncs():
