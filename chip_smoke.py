@@ -251,10 +251,10 @@ def phase_build():
     """The native runtime libraries are built from the tree (their file
     names carry the source hash); a missing g++ fails here, by name."""
     from hydragnn_tpu.data import distdataset
-    from hydragnn_tpu.native import graphpack, regiontimer
+    from hydragnn_tpu.native import graphpack
 
     with phase("build") as r:
-        for mod in (graphpack, regiontimer, distdataset):
+        for mod in (graphpack, distdataset):
             mod._load()
         r["gxx"] = shutil.which("g++")
         r["native"] = sorted(

@@ -17,6 +17,7 @@ import numpy as np
 
 from hydragnn_tpu.data.dataobj import GraphData
 from hydragnn_tpu.graph.batch import _round_up, collate_graphs, pad_sizes_for
+from hydragnn_tpu.utils import tracer as tr
 from hydragnn_tpu.utils.envparse import env_int
 
 
@@ -446,39 +447,44 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
     lists). The ONE layout-aware collation path — the training loader and
     the serving request packer (``hydragnn_tpu/serve``) both route through
     here. ``with_targets=False`` packs inputs only (inference requests
-    carry no labels)."""
-    batch = collate_graphs(
-        samples,
-        layout.n_pad,
-        layout.e_pad,
-        layout.g_pad,
-        head_types=layout.head_types if with_targets else (),
-        head_dims=layout.head_dims if with_targets else (),
-    )
+    carry no labels). Each of its three parts is a span of the recorder
+    (``utils/tracer.py``), on whichever thread collates."""
+    with tr.span("collate_graphs"):
+        batch = collate_graphs(
+            samples,
+            layout.n_pad,
+            layout.e_pad,
+            layout.g_pad,
+            head_types=layout.head_types if with_targets else (),
+            head_dims=layout.head_dims if with_targets else (),
+        )
     if layout.packs_triplets:
         from hydragnn_tpu.graph.batch import pack_triplets
 
-        trips = [
-            _sample_triplets(s) + (s.num_nodes, s.num_edges) for s in samples
-        ]
-        batch = batch.replace(
-            extras=pack_triplets(trips, layout.n_pad, layout.t_pad)
-        )
+        with tr.span("triplets"):
+            trips = [
+                _sample_triplets(s) + (s.num_nodes, s.num_edges)
+                for s in samples
+            ]
+            batch = batch.replace(
+                extras=pack_triplets(trips, layout.n_pad, layout.t_pad)
+            )
     if layout.need_neighbors:
         from hydragnn_tpu.ops.dense_agg import build_neighbor_lists
 
-        nbr = build_neighbor_lists(
-            batch.senders,
-            batch.receivers,
-            batch.edge_mask,
-            layout.n_pad,
-            layout.k_in,
-            layout.k_out,
-            with_slot_tables=layout.need_triplets,
-        )
-        merged = dict(batch.extras or {})
-        merged.update(nbr)
-        batch = batch.replace(extras=merged)
+        with tr.span("neighbor_lists", k_in=layout.k_in, k_out=layout.k_out):
+            nbr = build_neighbor_lists(
+                batch.senders,
+                batch.receivers,
+                batch.edge_mask,
+                layout.n_pad,
+                layout.k_in,
+                layout.k_out,
+                with_slot_tables=layout.need_triplets,
+            )
+            merged = dict(batch.extras or {})
+            merged.update(nbr)
+            batch = batch.replace(extras=merged)
     return batch
 
 
@@ -780,8 +786,23 @@ class GraphLoader:
             yield (self.layout, idx[start : start + self.batch_size])
 
     def _collate_task(self, task):
+        """Fetch and collate one batch: the ``collate`` span, with the
+        counts the padding metrics read (real rows are those of the
+        samples, not of the padding graph that fills the layout)."""
         layout, chunk = task
-        return _collate_with_extras([self.dataset[i] for i in chunk], layout)
+        with tr.span("collate") as span:
+            with tr.span("fetch"):
+                samples = [self.dataset[i] for i in chunk]
+            batch = _collate_with_extras(samples, layout)
+            g = len(samples)
+            span.set(
+                graphs=g,
+                nodes=int(batch.n_node[:g].sum()),
+                edges=int(batch.n_edge[:g].sum()),
+                bucket=int(layout.n_pad),
+                e_pad=int(layout.e_pad),
+            )
+        return batch
 
     def _batches(self):
         for task in self._batch_tasks():
@@ -903,12 +924,20 @@ def prefetch_iter(
     err = []
 
     def _put_stop_aware(item) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
+        try:
+            q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        # only the time blocked on a full queue is a span: the consumer
+        # is the slower side for as long as this lasts
+        with tr.span("queue_put_wait", depth=q.qsize()):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
         return False
 
     def worker():
@@ -932,7 +961,13 @@ def prefetch_iter(
         while True:
             if probe is not None:
                 probe(q.qsize())
-            item = q.get()
+            try:
+                item = q.get_nowait()
+            except queue.Empty:
+                # the twin of ``queue_put_wait``: this thread starved by
+                # the stage called ``name``
+                with tr.span("queue_get_wait", queue=name):
+                    item = q.get()
             if item is sentinel:
                 break
             yield item

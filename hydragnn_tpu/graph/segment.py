@@ -15,8 +15,12 @@ import jax
 import jax.numpy as jnp
 
 _BIG = 1e9  # sentinel for min/max identities; float32-safe
+# every op of a segment reduction (and of its transpose) carries this name
+# in a device trace; a reduction built from another nests the name
+_scope = jax.named_scope("agg_segment")
 
 
+@_scope
 def segment_sum(data, segment_ids, num_segments):
     from hydragnn_tpu.ops import pallas_segments_enabled, segment_sum_onehot
 
@@ -34,6 +38,7 @@ def segment_sum(data, segment_ids, num_segments):
     return out.astype(in_dtype) if out.dtype != in_dtype else out
 
 
+@_scope
 def segment_count(segment_ids, num_segments, weights=None):
     """Number of elements per segment (in-degree when ids are edge receivers)."""
     ones = (
@@ -44,6 +49,7 @@ def segment_count(segment_ids, num_segments, weights=None):
     return jax.ops.segment_sum(ones, segment_ids, num_segments=num_segments)
 
 
+@_scope
 def segment_mean(data, segment_ids, num_segments):
     total = segment_sum(data, segment_ids, num_segments)
     count = segment_count(segment_ids, num_segments)
@@ -51,6 +57,7 @@ def segment_mean(data, segment_ids, num_segments):
     return total / count.reshape((-1,) + (1,) * (data.ndim - 1))
 
 
+@_scope
 def segment_max(data, segment_ids, num_segments, fill=0.0, has=None):
     """Max per segment; empty segments get ``fill`` (reference semantics: padded
     nodes should see 0, not -inf, so downstream matmuls stay finite).
@@ -65,6 +72,7 @@ def segment_max(data, segment_ids, num_segments, fill=0.0, has=None):
     return jnp.where(has, jnp.where(jnp.isfinite(out), out, fill), fill)
 
 
+@_scope
 def segment_min(data, segment_ids, num_segments, fill=0.0, has=None):
     out = jax.ops.segment_min(data, segment_ids, num_segments=num_segments)
     if has is None:
@@ -73,6 +81,7 @@ def segment_min(data, segment_ids, num_segments, fill=0.0, has=None):
     return jnp.where(has, jnp.where(jnp.isfinite(out), out, fill), fill)
 
 
+@_scope
 def segment_minmax_fused(data, segment_ids, num_segments, fill=0.0, has=None):
     """(min, max) per segment from ONE scatter pass.
 
@@ -95,6 +104,7 @@ def segment_minmax_fused(data, segment_ids, num_segments, fill=0.0, has=None):
     return mn, mx
 
 
+@_scope
 def segment_std(data, segment_ids, num_segments, eps=1e-5):
     """Per-segment standard deviation, PNA-style: sqrt(relu(E[x^2]-E[x]^2)+eps).
 
@@ -107,6 +117,7 @@ def segment_std(data, segment_ids, num_segments, eps=1e-5):
     return jnp.sqrt(var + eps)
 
 
+@_scope
 def segment_moments_fused(data, segment_ids, num_segments, weights=None):
     """(sum, count, sum_of_squares) per segment from ONE scatter pass.
 
@@ -127,6 +138,7 @@ def segment_moments_fused(data, segment_ids, num_segments, weights=None):
     return s[:, :d], s[:, -1:], s[:, d : 2 * d]
 
 
+@_scope
 def segment_softmax_unnorm(logits, segment_ids, num_segments, mask=None):
     """Masked, max-shifted ``exp`` — the stable-softmax numerator terms.
 
@@ -147,6 +159,7 @@ def segment_softmax_unnorm(logits, segment_ids, num_segments, mask=None):
     return unnorm
 
 
+@_scope
 def segment_softmax(logits, segment_ids, num_segments, mask=None):
     """Numerically-stable softmax within segments (GAT edge attention).
 

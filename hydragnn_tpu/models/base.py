@@ -255,91 +255,93 @@ class HydraBase(nn.Module):
             x = act(c)
 
         # ---- decoder: multihead (Base.py:205-283,304-327) ---------------
-        x_graph = global_mean_pool(x, batch.node_graph, batch.n_node, batch.num_graphs)
-        if self.partition_axis is not None:
-            # nodes of the (single partitioned) graph live on every shard;
-            # n_node[0] holds the GLOBAL real-node count, so the psum of the
-            # local sums/count yields the exact global mean.
-            x_graph = jax.lax.psum(x_graph, self.partition_axis)
+        with jax.named_scope("heads"):  # the name the device trace reads
+            x_graph = global_mean_pool(x, batch.node_graph, batch.n_node, batch.num_graphs)
+            if self.partition_axis is not None:
+                # nodes of the (single partitioned) graph live on every shard;
+                # n_node[0] holds the GLOBAL real-node count, so the psum of the
+                # local sums/count yields the exact global mean.
+                x_graph = jax.lax.psum(x_graph, self.partition_axis)
 
-        graph_shared = None
-        if "graph" in heads_cfg:
-            dim_shared = heads_cfg["graph"]["dim_sharedlayers"]
-            n_shared = heads_cfg["graph"]["num_sharedlayers"]
-            graph_shared = MLP(
-                [dim_shared] * n_shared,
-                activation=self.activation,
-                final_activation=True,
-                name="graph_shared",
-            )
-
-        outputs = []
-        node_index = None
-        # NLL mode: one extra log-variance channel per head (the reference
-        # reserves the slot the same way, ``Base.py:241``)
-        uq_extra = 1 if self.loss_nll else 0
-        for ihead in range(self.num_heads):
-            head_type = self.output_type[ihead]
-            head_dim = self.output_dim[ihead] + uq_extra
-            if head_type == "graph":
-                num_head_hidden = heads_cfg["graph"]["num_headlayers"]
-                dim_head_hidden = heads_cfg["graph"]["dim_headlayers"]
-                layer_dims = list(dim_head_hidden[:num_head_hidden]) + [head_dim]
-                head_mlp = MLP(
-                    layer_dims,
+            graph_shared = None
+            if "graph" in heads_cfg:
+                dim_shared = heads_cfg["graph"]["dim_sharedlayers"]
+                n_shared = heads_cfg["graph"]["num_sharedlayers"]
+                graph_shared = MLP(
+                    [dim_shared] * n_shared,
                     activation=self.activation,
-                    final_bias_value=self.initial_bias,
-                    name=f"head_{ihead}_graph",
+                    final_activation=True,
+                    name="graph_shared",
                 )
-                outputs.append(head_mlp(graph_shared(x_graph)))
-            elif head_type == "node":
-                node_cfg = heads_cfg["node"]
-                node_type = node_cfg["type"]
-                hidden_dims = tuple(node_cfg["dim_headlayers"])
-                if node_type in ("mlp", "mlp_per_node"):
-                    num_mlp = 1 if node_type == "mlp" else int(self.num_nodes)
-                    if node_index is None:
-                        node_index = self._node_index_in_graph(batch)
-                    head = MLPNode(
-                        input_dim=self.hidden_dim,
-                        output_dim=head_dim,
-                        num_mlp=num_mlp,
-                        hidden_dims=hidden_dims,
+
+            outputs = []
+            node_index = None
+            # NLL mode: one extra log-variance channel per head (the reference
+            # reserves the slot the same way, ``Base.py:241``)
+            uq_extra = 1 if self.loss_nll else 0
+            for ihead in range(self.num_heads):
+                head_type = self.output_type[ihead]
+                head_dim = self.output_dim[ihead] + uq_extra
+                if head_type == "graph":
+                    num_head_hidden = heads_cfg["graph"]["num_headlayers"]
+                    dim_head_hidden = heads_cfg["graph"]["dim_headlayers"]
+                    layer_dims = list(dim_head_hidden[:num_head_hidden]) + [head_dim]
+                    head_mlp = MLP(
+                        layer_dims,
                         activation=self.activation,
-                        name=f"head_{ihead}_node",
+                        final_bias_value=self.initial_bias,
+                        name=f"head_{ihead}_graph",
                     )
-                    out = head(x, node_index)
-                    outputs.append(jnp.where(batch.node_mask[:, None], out, 0.0))
-                elif node_type == "conv":
-                    # shared hidden convs + per-head output conv, BatchNorm +
-                    # activation after every conv incl. the output one
-                    # (Base.py:318-323).
-                    h = x
-                    p = pos
-                    for il, (in_dim, od, bn_dim, kw) in enumerate(
-                        self._node_conv_specs(node_cfg, head_dim)
-                    ):
-                        conv = self.get_conv(
-                            in_dim, od, name=f"head_{ihead}_conv_{il}", **kw
+                    outputs.append(head_mlp(graph_shared(x_graph)))
+                elif head_type == "node":
+                    node_cfg = heads_cfg["node"]
+                    node_type = node_cfg["type"]
+                    hidden_dims = tuple(node_cfg["dim_headlayers"])
+                    if node_type in ("mlp", "mlp_per_node"):
+                        num_mlp = 1 if node_type == "mlp" else int(self.num_nodes)
+                        if node_index is None:
+                            node_index = self._node_index_in_graph(batch)
+                        head = MLPNode(
+                            input_dim=self.hidden_dim,
+                            output_dim=head_dim,
+                            num_mlp=num_mlp,
+                            hidden_dims=hidden_dims,
+                            activation=self.activation,
+                            name=f"head_{ihead}_node",
                         )
-                        c, p = self._apply_conv(conv, h, p, batch, train)
-                        c = MaskedBatchNorm(
-                            bn_dim,
-                            name=f"head_{ihead}_bn_{il}",
-                            axis_name=self.partition_axis,
-                        )(c, batch.node_mask, not train)
-                        h = act(c)
-                    outputs.append(h)
+                        out = head(x, node_index)
+                        outputs.append(jnp.where(batch.node_mask[:, None], out, 0.0))
+                    elif node_type == "conv":
+                        # shared hidden convs + per-head output conv, BatchNorm +
+                        # activation after every conv incl. the output one
+                        # (Base.py:318-323).
+                        h = x
+                        p = pos
+                        for il, (in_dim, od, bn_dim, kw) in enumerate(
+                            self._node_conv_specs(node_cfg, head_dim)
+                        ):
+                            conv = self.get_conv(
+                                in_dim, od, name=f"head_{ihead}_conv_{il}", **kw
+                            )
+                            c, p = self._apply_conv(conv, h, p, batch, train)
+                            c = MaskedBatchNorm(
+                                bn_dim,
+                                name=f"head_{ihead}_bn_{il}",
+                                axis_name=self.partition_axis,
+                            )(c, batch.node_mask, not train)
+                            h = act(c)
+                        outputs.append(h)
+                    else:
+                        raise ValueError(
+                            f"Unknown head NN structure for node features: {node_type};"
+                            " supported: 'mlp', 'mlp_per_node', 'conv'"
+                        )
                 else:
-                    raise ValueError(
-                        f"Unknown head NN structure for node features: {node_type};"
-                        " supported: 'mlp', 'mlp_per_node', 'conv'"
-                    )
-            else:
-                raise ValueError(f"Unknown head type: {head_type}")
+                    raise ValueError(f"Unknown head type: {head_type}")
         return tuple(outputs)
 
     # ---- loss (Base.py:329-373) -----------------------------------------
+    @jax.named_scope("loss")
     def loss(self, outputs, batch: GraphBatch):
         """Weighted multi-task loss; returns (total, per-task list).
 

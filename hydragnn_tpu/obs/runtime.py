@@ -35,6 +35,7 @@ from hydragnn_tpu.obs.metrics import (
     EPOCH_LATENCY_BOUNDS,
     MetricsRegistry,
 )
+from hydragnn_tpu.utils import tracer as _tracer
 
 _active: Optional["RunTelemetry"] = None
 
@@ -307,6 +308,11 @@ class TrainingMetrics:
 
 
 _compile_listener_registered = False
+# jax.monitoring duration events that are part of getting a program ready
+_COMPILE_FAMILY = (
+    "/jax/core/compile/",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
 # process-global backend-compile count: always bumped once the listener is
 # installed, whether or not a telemetry run is active. The recompile
 # sentinel (analysis/guards.py) diffs it around a warmed-up region.
@@ -329,9 +335,18 @@ def _register_compile_listener():
         from jax import monitoring
 
         def _on_duration(event: str, duration: float = 0.0, **kwargs):
+            # every duration of the compile family (jaxpr trace, lowering,
+            # backend compile, persistent-cache load) is a ``compile`` span
+            # of the recorder, under whatever span is open on the thread
+            # that compiled; the counters below stay backend compiles only.
             # '/jax/core/compile/backend_compile_duration' fires once per
             # actual XLA compilation (cache hits don't reach the backend)
             global _compile_events, _compile_seconds
+            if event.startswith(_COMPILE_FAMILY):
+                _tracer.record(
+                    "compile", duration, event=event.rsplit("/", 1)[-1],
+                    seconds=float(duration), fun=kwargs.get("fun_name"),
+                )
             if "backend_compile" in event:
                 _compile_events += 1
                 try:

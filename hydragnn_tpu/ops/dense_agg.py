@@ -29,6 +29,9 @@ import jax
 import jax.numpy as jnp
 
 _BIG = 1e9
+# every gather-and-reduce below, forward and backward, carries this name in
+# a device trace (``jax.named_scope``: a name, no run-time cost)
+_scope = jax.named_scope("agg_dense")
 
 
 def max_degree(senders, receivers, edge_mask=None) -> Tuple[int, int]:
@@ -111,6 +114,7 @@ def build_neighbor_lists(
 
 
 @jax.custom_vjp
+@_scope
 def gather_neighbors(x, nbr_idx, rev_idx, rev_mask):
     """``x[nbr_idx]`` ([N, D] -> [N, K, D]) whose backward pass is a
     gather through the reverse list instead of a scatter-add."""
@@ -121,11 +125,13 @@ def gather_neighbors(x, nbr_idx, rev_idx, rev_mask):
     return x[nbr_idx]
 
 
+@_scope
 def _gather_fwd(x, nbr_idx, rev_idx, rev_mask):
     # numlint: disable=unmasked-gather-id — mirrors the primal above
     return x[nbr_idx], (x.shape, nbr_idx.shape, rev_idx, rev_mask)
 
 
+@_scope
 def _gather_bwd(res, g):
     (n, d), (_, k_in), rev_idx, rev_mask = res
     flat = g.reshape(n * k_in, d)
@@ -141,6 +147,7 @@ gather_neighbors.defvjp(_gather_fwd, _gather_bwd)
 
 
 @jax.custom_vjp
+@_scope
 def group_sum(values, lists, lists_mask, owner_ids, valid):
     """Generic scatter-free segment sum for SINGLE-OWNER groupings.
 
@@ -162,6 +169,7 @@ def group_sum(values, lists, lists_mask, owner_ids, valid):
     return hm.sum(axis=1).astype(values.dtype)
 
 
+@_scope
 def _group_sum_fwd(values, lists, lists_mask, owner_ids, valid):
     return group_sum(values, lists, lists_mask, owner_ids, valid), (
         owner_ids,
@@ -169,6 +177,7 @@ def _group_sum_fwd(values, lists, lists_mask, owner_ids, valid):
     )
 
 
+@_scope
 def _group_sum_bwd(res, g):
     owner_ids, valid = res
     gv = jnp.where(valid[:, None], g[owner_ids], 0.0)
@@ -179,6 +188,7 @@ group_sum.defvjp(_group_sum_fwd, _group_sum_bwd)
 
 
 @jax.custom_vjp
+@_scope
 def gather_rows_to_slots(table, lists, lists_mask, slot_of_row, row_valid):
     """``table[lists]`` ([R, D] -> [G, K, D]) for a SINGLE-OWNER grouping
     (every valid table row appears in exactly one list slot). Backward is
@@ -187,6 +197,7 @@ def gather_rows_to_slots(table, lists, lists_mask, slot_of_row, row_valid):
     return jnp.where(lists_mask[..., None], table[lists], 0.0)
 
 
+@_scope
 def _grs_fwd(table, lists, lists_mask, slot_of_row, row_valid):
     return (
         gather_rows_to_slots(table, lists, lists_mask, slot_of_row, row_valid),
@@ -194,6 +205,7 @@ def _grs_fwd(table, lists, lists_mask, slot_of_row, row_valid):
     )
 
 
+@_scope
 def _grs_bwd(res, g):
     (r, d), (grp, k), slot_of_row, row_valid = res
     gt = g.reshape(grp * k, d)[slot_of_row]
@@ -204,6 +216,7 @@ gather_rows_to_slots.defvjp(_grs_fwd, _grs_bwd)
 
 
 @jax.custom_vjp
+@_scope
 def slots_to_rows(slots, slot_of_row, row_valid, lists, lists_mask):
     """Inverse of :func:`gather_rows_to_slots`: route per-slot values
     ``slots [G, K, D]`` back onto their owning rows -> ``[R, D]``.
@@ -214,6 +227,7 @@ def slots_to_rows(slots, slot_of_row, row_valid, lists, lists_mask):
     return jnp.where(row_valid[:, None], out, 0.0)
 
 
+@_scope
 def _str_fwd(slots, slot_of_row, row_valid, lists, lists_mask):
     return (
         slots_to_rows(slots, slot_of_row, row_valid, lists, lists_mask),
@@ -221,6 +235,7 @@ def _str_fwd(slots, slot_of_row, row_valid, lists, lists_mask):
     )
 
 
+@_scope
 def _str_bwd(res, g):
     lists, lists_mask = res
     gs = jnp.where(lists_mask[..., None], g[lists], 0.0)
@@ -258,6 +273,7 @@ def build_group_lists(
 
 
 @jax.custom_vjp
+@_scope
 def aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     """Sum dense per-edge values ``h [N, K_in, D]`` (keyed by receiver x
     slot) onto their SENDER nodes -> ``[N, D]``, scatter-free.
@@ -276,6 +292,7 @@ def aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     return hm.sum(axis=1).astype(h.dtype)
 
 
+@_scope
 def _agg_send_fwd(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     return (
         aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask),
@@ -283,6 +300,7 @@ def _agg_send_fwd(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     )
 
 
+@_scope
 def _agg_send_bwd(res, g):
     nbr_idx, nbr_mask = res
     gh = g[nbr_idx]  # [N, K_in, D]
@@ -293,6 +311,7 @@ def _agg_send_bwd(res, g):
 aggregate_to_senders.defvjp(_agg_send_fwd, _agg_send_bwd)
 
 
+@_scope
 def dense_moments(h, nbr_mask):
     """(mean, std, deg, has) over the K axis of masked messages
     ``h [N, K, D]`` — PNA's count/mean/std statistics without a scatter.
@@ -314,6 +333,7 @@ def dense_moments(h, nbr_mask):
     )
 
 
+@_scope
 def dense_minmax(h, nbr_mask, has, fill=0.0):
     """(min, max) over the K axis; empty receivers -> ``fill`` (segment
     fill semantics so padded nodes stay finite)."""
@@ -325,6 +345,7 @@ def dense_minmax(h, nbr_mask, has, fill=0.0):
     return mn, mx
 
 
+@_scope
 def dense_sum(h, nbr_mask):
     # masked K-axis sum in f32, result at the message dtype (no-op for
     # f32 inputs; the guard the bf16 dense path needs)

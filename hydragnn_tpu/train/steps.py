@@ -170,8 +170,9 @@ def build_steps(
         (loss, (tasks, new_bs)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = state.replace(
             params=new_params,
             batch_stats=new_bs,

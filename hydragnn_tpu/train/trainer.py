@@ -17,7 +17,6 @@ module re-exports the public names so existing imports keep working.
 """
 
 import os
-import time
 from typing import Optional
 
 import jax
@@ -294,59 +293,53 @@ class Trainer(PredictMixin):
         — the reference's per-rank DataLoader semantics
         (``preprocess/load_data.py:237-245``) with XLA owning the transport.
         """
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from hydragnn_tpu.parallel.mesh import DATA_AXIS
-
-            if self._batch_sharding is None:
-                self._batch_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
-            if jax.process_count() > 1:
-                batch = _offset_local_shard(batch, jax.process_index())
-                return jax.tree_util.tree_map(
-                    lambda a: jax.make_array_from_process_local_data(
-                        self._batch_sharding, np.asarray(a)
-                    ),
-                    batch,
-                )
-            return jax.tree_util.tree_map(
-                lambda a: jax.device_put(jnp.asarray(a), self._batch_sharding),
-                self._compact_for_transfer(batch, allow_pos_placeholder=False),
-            )
-        return jax.tree_util.tree_map(
-            jnp.asarray, self._compact_for_transfer(batch)
-        )
+        return self._put(batch, stacked=False)
 
     def put_batch_stacked(self, stacked: GraphBatch) -> GraphBatch:
         """Like :meth:`put_batch` for a ``stack_batches`` result: the scan
         axis stays unsharded, each microbatch's leading axis shards over
         ``data``."""
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        return self._put(stacked, stacked=True)
 
-            from hydragnn_tpu.parallel.mesh import DATA_AXIS
+    def _put(self, batch: GraphBatch, stacked: bool) -> GraphBatch:
+        """The one transfer path, two spans of the recorder: ``compact``,
+        the host-side shaping of the wire format (the multi-host path
+        offsets its local shard there instead), and ``h2d``, the
+        ``device_put`` tree-map. ``h2d`` measures the HOST's side of the
+        put — staging and enqueue; the transfer itself is the device's and
+        shows in a profiler trace, not here."""
+        with tr.span("compact"):
+            if self.mesh is None:
+                host, put = self._compact_for_transfer(batch), jnp.asarray
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            if self._stacked_sharding is None:
-                self._stacked_sharding = NamedSharding(
-                    self.mesh, P(None, DATA_AXIS)
+                from hydragnn_tpu.parallel.mesh import DATA_AXIS
+
+                if self._batch_sharding is None:
+                    self._batch_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
+                    self._stacked_sharding = NamedSharding(
+                        self.mesh, P(None, DATA_AXIS)
+                    )
+                sharding = (
+                    self._stacked_sharding if stacked else self._batch_sharding
                 )
-            if jax.process_count() > 1:
-                stacked = _offset_local_shard(stacked, jax.process_index())
-                return jax.tree_util.tree_map(
-                    lambda a: jax.make_array_from_process_local_data(
-                        self._stacked_sharding, np.asarray(a)
-                    ),
-                    stacked,
-                )
-            return jax.tree_util.tree_map(
-                lambda a: jax.device_put(jnp.asarray(a), self._stacked_sharding),
-                self._compact_for_transfer(
-                    stacked, allow_pos_placeholder=False
-                ),
-            )
-        return jax.tree_util.tree_map(
-            jnp.asarray, self._compact_for_transfer(stacked)
-        )
+                if jax.process_count() > 1:
+                    host = _offset_local_shard(batch, jax.process_index())
+
+                    def put(a):
+                        return jax.make_array_from_process_local_data(
+                            sharding, np.asarray(a)
+                        )
+                else:
+                    host = self._compact_for_transfer(
+                        batch, allow_pos_placeholder=False
+                    )
+
+                    def put(a):
+                        return jax.device_put(jnp.asarray(a), sharding)
+        with tr.span("h2d"):
+            return jax.tree_util.tree_map(put, host)
 
     # ---- compiled steps ------------------------------------------------
     def _build_steps(self):
@@ -563,24 +556,29 @@ class Trainer(PredictMixin):
                     break
                 yield batch
 
+        # the ``dataload`` span's clock is the ledger's: one reading
         if depth <= 0:
             for batch in limited():
-                tr.start("dataload")
-                t0 = time.perf_counter() if _ledger is not None else 0.0
+                wait = tr.start("dataload")
                 dev = put(batch)
+                wait.stop()
                 if _ledger is not None:
-                    _ledger.data_wait(time.perf_counter() - t0)
-                tr.stop("dataload")
+                    _ledger.data_wait(wait.seconds)
                 yield dev
             return
         from hydragnn_tpu.data.loaders import prefetch_iter
 
+        found = [0]  # ready items ahead of the consumer at its last get
+
+        def note_depth(n):
+            found[0] = n
+
         it = prefetch_iter(
-            limited(), depth, fn=put, name="hydragnn-device-prefetch"
+            limited(), depth, fn=put, name="hydragnn-device-prefetch",
+            probe=note_depth,
         )
         while True:
-            tr.start("dataload")  # time spent WAITING on the transfer stage
-            t0 = time.perf_counter() if _ledger is not None else 0.0
+            wait = tr.start("dataload")  # time spent WAITING on the transfer stage
             try:
                 try:
                     item = next(it)
@@ -588,10 +586,10 @@ class Trainer(PredictMixin):
                     return
             finally:
                 # a worker-side error re-raised by next(it) must not leave
-                # the dataload timer running for the rest of the process
+                # the dataload span open for the rest of the process
+                wait.stop(queue_depth=found[0])
                 if _ledger is not None:
-                    _ledger.data_wait(time.perf_counter() - t0)
-                tr.stop("dataload")
+                    _ledger.data_wait(wait.seconds)
             yield item
 
     @staticmethod
@@ -635,11 +633,20 @@ class Trainer(PredictMixin):
         """Transfer stage: a group becomes (device_payload, count). Runs on
         the prefetch thread when ``device_prefetch > 0`` — so stacked
         multi-step transfers double-buffer exactly like single batches."""
-        if len(group) > 1:
-            from hydragnn_tpu.graph.batch import stack_batches
+        with tr.span("put_group", batches=len(group)) as span:
+            if len(group) > 1:
+                from hydragnn_tpu.graph.batch import stack_batches
 
-            return self.put_batch_stacked(stack_batches(group)), len(group)
-        return self.put_batch(group[0]), 1
+                with tr.span("stack_batches"):
+                    stacked = stack_batches(group)
+                dev = self.put_batch_stacked(stacked)
+            else:
+                dev = self.put_batch(group[0])
+            # what device_put was handed: the arrays keep dtype and shape
+            span.set(bytes=sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(dev)
+            ))
+        return dev, len(group)
 
     def train_epoch(self, state, loader, rng):
         from hydragnn_tpu.train import elastic
@@ -665,22 +672,29 @@ class Trainer(PredictMixin):
             if count > 1:
                 subs = jax.random.split(rng, count + 1)
                 rng = subs[0]
-                tr.start("train_step")
-                t0 = time.perf_counter() if _telemetry is not None else 0.0
-                # straggler injection INSIDE the timed window (after t0):
-                # the delay must reach on_step -> flight recorder, or the
-                # stall detection the fault exists to exercise never sees
-                # it. Every step id the K-group covers gets its check,
-                # same as the kill loop below.
+                step = tr.start(
+                    "train_step", steps=count, program="train_multi",
+                    bucket=dev.x.shape[-2],
+                )
+                # straggler injection INSIDE the span: the delay must
+                # reach on_step -> flight recorder, or the stall
+                # detection the fault exists to exercise never sees it.
+                # Every step id the K-group covers gets its check, same
+                # as the kill loop below.
                 for s in range(self._host_step, self._host_step + count):
                     faults.slow_step(s)
                 state, metrics = self._train_multi(state, dev, subs[1:])
+                step.stop()
                 if _telemetry is not None:
                     # the full per-step hook: metrics + flight recorder
-                    # (stall alerts) + on-demand trace-capture ticks
-                    _telemetry.on_step(time.perf_counter() - t0, count)
-                tr.stop("train_step")
-                acc = self._acc_add(acc, metrics, multi=True)
+                    # (stall alerts) + on-demand trace-capture ticks; the
+                    # span's seconds are the dispatch's
+                    _telemetry.on_step(step.seconds, count)
+                # its eager ops read the step's outputs: on the chip they
+                # wait for the dispatch, so this is where a host that runs
+                # ahead of the device is held (PERF.md section 5)
+                with tr.span("acc_add"):
+                    acc = self._acc_add(acc, metrics, multi=True)
                 first = self._host_step
                 self._host_step += count
                 elastic.note_step(self._host_step)
@@ -692,14 +706,16 @@ class Trainer(PredictMixin):
                     dev = dev.replace(x=dev.x * jnp.nan)
                 prev = None if guard is None else guard.snapshot(state)
                 rng, sub = jax.random.split(rng)
-                tr.start("train_step")
-                t0 = time.perf_counter() if _telemetry is not None else 0.0
-                # inside the timed window — see the multi-step branch
+                step = tr.start(
+                    "train_step", steps=1, program="train_step",
+                    bucket=dev.x.shape[-2],
+                )
+                # inside the span — see the multi-step branch
                 faults.slow_step(self._host_step)
                 state, metrics = self._train_step(state, dev, sub)
+                step.stop()
                 if _telemetry is not None:
-                    _telemetry.on_step(time.perf_counter() - t0)
-                tr.stop("train_step")
+                    _telemetry.on_step(step.seconds)
                 # the guard's documented cost: ONE scalar fetch per step to
                 # learn whether the update was finite — opt-in, and there is
                 # no async way to branch host control flow on a device value
@@ -713,12 +729,14 @@ class Trainer(PredictMixin):
                 else:
                     if guard is not None:
                         guard.bad_streak = 0
-                    acc = self._acc_add(acc, metrics, multi=False)
+                    with tr.span("acc_add"):
+                        acc = self._acc_add(acc, metrics, multi=False)
                 faults.kill_at_step(self._host_step)
                 faults.lose_host_at_step(self._host_step)
                 self._host_step += 1
                 elastic.note_step(self._host_step)
-        loss, tasks = self._acc_read(acc)  # the epoch's one readback
+        with tr.span("epoch_readback", dispatches=len(acc or ())):
+            loss, tasks = self._acc_read(acc)  # the epoch's one readback
         tr.stop("train")
         return state, rng, loss, tasks
 
@@ -745,4 +763,5 @@ class Trainer(PredictMixin):
                     state.params, state.batch_stats, dev
                 )
                 acc = self._acc_add(acc, metrics, multi=False)
-        return self._acc_read(acc)
+        with tr.span("epoch_readback", dispatches=len(acc or ())):
+            return self._acc_read(acc)

@@ -1,128 +1,226 @@
-"""Tracer facade — HPC-style nested region timers.
+"""The span recorder of the training path.
 
-Parity with ``hydragnn/utils/tracer.py:18-171`` (GPTL/Score-P facade with a
-registry, enable/disable, optional device sync for honest attribution, and a
-``@profile`` decorator). Backends:
+One implementation behind the region facade of the reference
+(``hydragnn/utils/tracer.py:18-171``: ``initialize / start / stop / profile
+/ save / reset / enable / disable``) and behind ``span(name, **counts)``,
+which any thread may use. A :class:`Span` keeps its name, the name of the
+thread that opened it, start and end on ``time.perf_counter_ns()``, its own
+id, the id of the span that was open ON THE SAME THREAD when it started (0
+for a root; every thread has its own stack) and a small dict of counts
+given at open or close.
 
-  * ``timer``  — pure-Python region timers with per-host summaries (GPTL
-    analog; a C++ backend drops in behind the same interface, see
-    ``native/``).
-  * ``jax``    — forwards regions to ``jax.profiler.TraceAnnotation`` so they
-    appear in TensorBoard/perfetto traces (Score-P analog).
+Closed spans go to a bounded in-memory ring and to running totals keyed by
+call-tree path (``train/train_step``); nothing is written before
+:func:`save`. The ring holds ``RING_SPANS`` = 65,536 records: the PNA cell
+of the benchmark closes about 130 spans an epoch of 1.46 s, so 60 s are some
+5,400 records and the ring spans about ten minutes of it. Ring and totals
+are module state: they outlive a telemetry run and ``jax.clear_caches()``,
+and only :func:`reset` empties them.
 
-``HYDRAGNN_TRACE_LEVEL=1`` inserts a device sync (``block_until_ready``
-analog of the reference's cudasync+barrier, ``tracer.py:110-131``) at region
-boundaries.
+While the recorder is live every span is also a
+``jax.profiler.TraceAnnotation(name, id=..., parent=...)``, so any profiler
+session (``TraceCapture``, ``/profile?steps=N``, ``HYDRAGNN_PROFILE_AT_STEP``,
+a benchmark's own) holds the program's spans on the device trace's clock
+without anyone asking; with no session running that is a flag check (0.6 us
+a span, sandbox CPU). The anchor pair ``(time.time_ns(),
+time.perf_counter_ns())`` taken at the first :func:`initialize` converts
+ring times to the wall clock; a profiler trace counts from its session's
+start on that clock.
+
+``HYDRAGNN_TRACE_LEVEL=1``, read once at :func:`initialize`, makes
+``start`` / ``stop`` (not ``span``) wait for the device at the region's
+boundaries: the ``block_until_ready`` analog of the reference's
+cudasync+barrier (``tracer.py:110-131``), for honest attribution of work
+dispatched asynchronously. ``span`` never waits: it marks host-side stages,
+and a producer thread that waited for the device at each boundary would
+serialise the pipeline it measures.
+
+A span always reads the clock, live or not, so that callers can hand its
+``seconds`` on (the goodput ledger's ``data_wait`` and ``on_step``) without
+a second pair of clock reads.
 """
 
+import collections
+import itertools
+import json
 import os
+import threading
 import time
-from collections import defaultdict
 from functools import wraps
-from typing import Dict
+from typing import Dict, NamedTuple
 
-_tracers: Dict[str, object] = {}
-_enabled = True
-
-
-class TimerTracer:
-    def __init__(self):
-        self.acc = defaultdict(float)
-        self.count = defaultdict(int)
-        self._start = {}
-
-    def start(self, name):
-        self._start[name] = time.perf_counter()
-
-    def stop(self, name):
-        if name in self._start:
-            self.acc[name] += time.perf_counter() - self._start.pop(name)
-            self.count[name] += 1
-
-    def reset(self):
-        self.acc.clear()
-        self.count.clear()
-        self._start.clear()
-
-    def pr_file(self, filename):
-        os.makedirs(os.path.dirname(filename) or ".", exist_ok=True)
-        with open(filename, "w") as f:
-            f.write(f"{'region':<30}{'calls':>10}{'total_s':>14}{'avg_ms':>12}\n")
-            for name in sorted(self.acc):
-                c = self.count[name]
-                t = self.acc[name]
-                f.write(
-                    f"{name:<30}{c:>10}{t:>14.4f}{(t / max(c, 1)) * 1e3:>12.3f}\n"
-                )
+RING_SPANS = 1 << 16
 
 
-class JaxProfilerTracer:
-    """Regions as jax.profiler trace annotations."""
+class SpanLog(NamedTuple):
+    """What :func:`spans` returns: ``anchor`` is ``(time.time_ns(),
+    time.perf_counter_ns())`` read together, ``records`` the closed spans
+    in closing order."""
 
-    def __init__(self):
-        self._spans = {}
-
-    def start(self, name):
-        import jax.profiler
-
-        span = jax.profiler.TraceAnnotation(name)
-        span.__enter__()
-        self._spans.setdefault(name, []).append(span)
-
-    def stop(self, name):
-        spans = self._spans.get(name)
-        if spans:
-            spans.pop().__exit__(None, None, None)
-
-    def reset(self):
-        self._spans.clear()
-
-    def pr_file(self, filename):
-        pass
+    anchor: tuple
+    records: list
 
 
-def initialize(trace_backends=("native",), verbosity: int = 0):
-    for b in trace_backends:
-        if b == "timer":
-            _tracers["timer"] = TimerTracer()
-        elif b == "jax":
-            _tracers["jax"] = JaxProfilerTracer()
-        elif b == "native":
-            # C++ region timer (GPTL analog) with call-tree attribution and
-            # chrome-trace export; falls back to the Python timer if the
-            # toolchain is unavailable.
-            try:
-                from hydragnn_tpu.native.regiontimer import NativeRegionTimer
+class _State:
+    """The recorder's module state (one per process)."""
 
-                _tracers["native"] = NativeRegionTimer()
-            except Exception:
-                _tracers["timer"] = TimerTracer()
-    return list(_tracers)
+    def __init__(self, maxlen=RING_SPANS):
+        self.enabled = True
+        self.sync = False  # HYDRAGNN_TRACE_LEVEL=1 at initialize
+        self.anchor = (time.time_ns(), time.perf_counter_ns())
+        self.annotate = None  # jax.profiler.TraceAnnotation once initialized
+        self.ring = collections.deque(maxlen=maxlen)
+        self.totals = {}  # path -> [calls, total_ns, min_ns, max_ns]
+        self.lock = threading.Lock()  # ring and totals
+        self.ids = itertools.count(1)
+        self.local = threading.local()  # .stack, .thread per thread
+
+    @property
+    def live(self):
+        """Recording: initialized and not disabled."""
+        return self.enabled and self.annotate is not None
+
+    def stack(self):
+        local = self.local
+        try:
+            return local.stack
+        except AttributeError:
+            local.stack = []
+            local.thread = threading.current_thread().name
+            return local.stack
+
+    def close(self, span):
+        path = span.path
+        dur = span.end_ns - span.start_ns
+        with self.lock:
+            self.ring.append(span)
+            stat = self.totals.get(path)
+            if stat is None:
+                self.totals[path] = [1, dur, dur, dur]
+            else:
+                stat[0] += 1
+                stat[1] += dur
+                if dur < stat[2]:
+                    stat[2] = dur
+                if dur > stat[3]:
+                    stat[3] = dur
 
 
-def has(name):
-    return name in _tracers
+_state = _State()
+
+
+class Span:
+    """One timed interval on one thread. A context manager; ``start``
+    returns one already open, to be closed by :meth:`stop`."""
+
+    __slots__ = ("name", "thread", "start_ns", "end_ns", "id", "parent",
+                 "attrs", "path", "_sync", "_live", "_annotation")
+
+    def __init__(self, name, attrs=None, sync=False):
+        self.name = name
+        self.attrs = attrs or None
+        self._sync = sync
+        self.end_ns = None
+
+    def _place(self, st, stack):
+        """Its identity in the thread's call tree."""
+        self.thread = st.local.thread
+        self._live = st.live
+        if stack:
+            top = stack[-1]
+            self.parent = top.id
+            self.path = top.path + "/" + self.name
+        else:
+            self.parent = 0
+            self.path = self.name
+        self.id = next(st.ids)
+
+    def __enter__(self):
+        st = _state
+        stack = st.stack()
+        self._place(st, stack)
+        if self._live:
+            self._annotation = st.annotate(
+                self.name, id=self.id, parent=self.parent
+            )
+            self._annotation.__enter__()
+        stack.append(self)
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+    def set(self, **counts):
+        """Add counts to the record (any time before the ring is read)."""
+        if self.attrs is None:
+            self.attrs = counts
+        else:
+            self.attrs.update(counts)
+
+    def stop(self, **counts):
+        """Close the span; spans opened above it on this thread and never
+        closed are dropped (a missed stop must not re-parent what follows)."""
+        if self.end_ns is not None:
+            return self
+        if self._sync:
+            _device_sync()
+        self.end_ns = time.perf_counter_ns()
+        if counts:
+            self.set(**counts)
+        stack = _state.stack()
+        if self in stack:
+            top = None
+            while top is not self:
+                top = stack.pop()
+                if top._live:
+                    top._annotation.__exit__(None, None, None)
+                    top._annotation = None
+        if self._live:
+            _state.close(self)
+        return self
+
+    @property
+    def seconds(self) -> float:
+        end = self.end_ns if self.end_ns is not None else time.perf_counter_ns()
+        return (end - self.start_ns) * 1e-9
+
+
+def initialize(trace_backends=(), verbosity: int = 0):
+    """Switch the recorder on. ``trace_backends`` is accepted for the
+    callers that name the former backends (``("native",)``, ``("timer",)``,
+    ``("jax",)``): every call gives the same recorder, and a second call
+    keeps ring, totals and anchor."""
+    from jax.profiler import TraceAnnotation
+
+    st = _state
+    if st.annotate is None:
+        st.anchor = (time.time_ns(), time.perf_counter_ns())
+        st.annotate = TraceAnnotation
+    st.sync = os.getenv("HYDRAGNN_TRACE_LEVEL", "0") == "1"
+    return ["spans"]
 
 
 def enable():
-    global _enabled
-    _enabled = True
+    _state.enabled = True
 
 
 def disable():
-    global _enabled
-    _enabled = False
+    _state.enabled = False
 
 
 def reset():
-    for t in _tracers.values():
-        t.reset()
+    """Empty ring and totals (open spans stay open)."""
+    with _state.lock:
+        _state.ring.clear()
+        _state.totals.clear()
 
 
 _sync_fn = None
 
 
-def _sync():
+def _device_sync():
     """Block until in-flight device computation finishes (trace level 1's
     "honest attribution" contract). ``jax.effects_barrier()`` is NOT that —
     it only waits for ordered side effects and returns immediately with
@@ -130,34 +228,55 @@ def _sync():
     either, transfers bypass the execution stream. Dispatching a trivial
     jitted program and blocking on it does: executions are ordered per
     device, so its completion implies everything enqueued before it ran."""
-    if os.getenv("HYDRAGNN_TRACE_LEVEL", "0") == "1":
-        global _sync_fn
-        try:
-            import jax
+    global _sync_fn
+    import jax
 
-            if _sync_fn is None:
-                import jax.numpy as jnp
+    if _sync_fn is None:
+        import jax.numpy as jnp
 
-                _sync_fn = jax.jit(lambda: jnp.zeros(()))
-            _sync_fn().block_until_ready()
-        except Exception:
-            pass
+        _sync_fn = jax.jit(lambda: jnp.zeros(()))
+    _sync_fn().block_until_ready()
 
 
-def start(name):
-    if not _enabled or not _tracers:
-        return
-    _sync()
-    for t in _tracers.values():
-        t.start(name)
+def span(name, **counts) -> Span:
+    """``with span("collate", graphs=n) as s: ...; s.set(edges=m)``: a
+    host-side stage on whichever thread runs it. Never waits for the
+    device."""
+    return Span(name, counts)
 
 
-def stop(name):
-    if not _enabled or not _tracers:
-        return
-    _sync()
-    for t in _tracers.values():
-        t.stop(name)
+def start(name, **counts) -> Span:
+    """Open a region (device sync first at trace level 1) and return it;
+    close it with its ``stop()`` or with ``stop(name)``."""
+    sync = _state.sync and _state.live
+    if sync:
+        _device_sync()
+    return Span(name, counts, sync).__enter__()
+
+
+def stop(name, **counts):
+    """Close the innermost open region called ``name`` on this thread;
+    returns it, or None where there is none (tolerates a missed start, like
+    GPTL)."""
+    for open_span in reversed(_state.stack()):
+        if open_span.name == name:
+            return open_span.stop(**counts)
+    return None
+
+
+def record(name, duration_s: float, **counts):
+    """A span of ``duration_s`` seconds that ends now, under whatever is
+    open on this thread: for work whose duration is reported after the fact (the
+    compile listener). Not annotated: a trace cannot be written backwards."""
+    st = _state
+    if not st.live:
+        return None
+    s = Span(name, counts)
+    s._place(st, st.stack())
+    s.end_ns = time.perf_counter_ns()
+    s.start_ns = s.end_ns - int(max(float(duration_s), 0.0) * 1e9)
+    st.close(s)
+    return s
 
 
 def profile(name):
@@ -166,51 +285,68 @@ def profile(name):
     def deco(fn):
         @wraps(fn)
         def wrapper(*args, **kwargs):
-            start(name)
+            region = start(name)
             try:
                 return fn(*args, **kwargs)
             finally:
-                stop(name)
+                region.stop()
 
         return wrapper
 
     return deco
 
 
+def spans() -> SpanLog:
+    """The anchor pair and the ring's records (closed spans, oldest
+    first)."""
+    with _state.lock:
+        return SpanLog(_state.anchor, list(_state.ring))
+
+
 def totals() -> Dict[str, float]:
-    """Accumulated seconds per region from ONE accumulating backend —
-    preferring native over the Python timer (the jax backend only
-    annotates device traces). Every registered backend times the same
-    region boundaries, so summing across them would double-count; native
-    regions additionally come back as call-tree paths
-    ("train/train_step"). Feeds the telemetry layer's
+    """Accumulated seconds per call-tree path ("train/train_step"), over
+    every thread, since the last :func:`reset`: running sums, so a run
+    longer than the ring loses nothing. Feeds the telemetry layer's
     ``ScalarWriter.add_regions`` / ``tracer_totals`` run event."""
-    for name in ("native", "timer"):
-        t = _tracers.get(name)
-        if t is None:
-            continue
-        if hasattr(t, "totals"):
-            try:
-                return {k: float(v) for k, v in t.totals().items()}
-            except Exception:
-                continue  # an old cached .so without the export
-        acc = getattr(t, "acc", None)
-        if acc:
-            return {k: float(v) for k, v in acc.items()}
-    return {}
+    with _state.lock:
+        return {path: stat[1] * 1e-9 for path, stat in _state.totals.items()}
 
 
 def save(prefix: str = "./logs/trace"):
-    """Per-host region dump (GPTL ``gp.pr_file`` analog). The native backend
-    additionally writes a chrome://tracing JSON (`<prefix>.<rank>.trace.json`,
-    loadable in perfetto)."""
+    """Per-host dump at run end (GPTL ``gp.pr_file`` analog):
+    ``<prefix>.<rank>``, the call tree with calls / total / avg / min / max
+    per path, and ``<prefix>.<rank>.trace.json``, the ring as
+    chrome://tracing "X" events (loadable in perfetto), one ``tid`` per
+    thread, times in us since the anchor."""
     from hydragnn_tpu.parallel.distributed import get_comm_size_and_rank
 
     _, rank = get_comm_size_and_rank()
-    for name, t in _tracers.items():
-        # with several file-writing backends registered, each gets its own
-        # file so one dump cannot clobber another
-        tag = f".{name}" if len(_tracers) > 1 else ""
-        t.pr_file(f"{prefix}{tag}.{rank}")
-        if hasattr(t, "chrome_trace"):
-            t.chrome_trace(f"{prefix}{tag}.{rank}.trace.json", pid=rank)
+    os.makedirs(os.path.dirname(prefix) or ".", exist_ok=True)
+    with _state.lock:
+        stats = sorted((p, list(s)) for p, s in _state.totals.items())
+    log = spans()
+    with open(f"{prefix}.{rank}", "w") as f:
+        f.write(f"{'region':<44} {'calls':>10} {'total_s':>14} {'avg_ms':>12}"
+                f" {'min_ms':>12} {'max_ms':>12}\n")
+        for path, (calls, total, lo, hi) in stats:
+            depth = path.count("/")
+            label = "  " * depth + path.rsplit("/", 1)[-1]
+            f.write(f"{label:<44} {calls:>10} {total * 1e-9:>14.4f}"
+                    f" {total * 1e-6 / calls:>12.3f} {lo * 1e-6:>12.3f}"
+                    f" {hi * 1e-6:>12.3f}\n")
+    tids, events = {}, []
+    for s in log.records:
+        tid = tids.setdefault(s.thread, len(tids))
+        args = dict(s.attrs or {}, id=s.id, parent=s.parent)
+        events.append({
+            "name": s.name, "ph": "X", "pid": rank, "tid": tid,
+            "ts": (s.start_ns - log.anchor[1]) * 1e-3,
+            "dur": (s.end_ns - s.start_ns) * 1e-3, "args": args,
+        })
+    events.extend(
+        {"name": "thread_name", "ph": "M", "pid": rank, "tid": tid,
+         "args": {"name": thread}}
+        for thread, tid in tids.items()
+    )
+    with open(f"{prefix}.{rank}.trace.json", "w") as f:
+        json.dump(events, f, default=str)

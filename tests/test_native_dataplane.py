@@ -328,34 +328,45 @@ def pytest_diststore_subgroup_multiprocess():
 
 
 def pytest_region_timer_calltree():
-    from hydragnn_tpu.native.regiontimer import NativeRegionTimer
-
-    t = NativeRegionTimer()
-    for _ in range(2):
-        t.start("train")
-        t.start("forward")
-        time.sleep(0.002)
-        t.stop("forward")
-        t.stop("train")
-    assert t.count("train") == 2
-    assert t.count("train/forward") == 2
-    assert t.total("train") >= t.total("train/forward") > 0
-    with tempfile.TemporaryDirectory() as tmp:
-        t.pr_file(os.path.join(tmp, "trace.0"))
-        text = open(os.path.join(tmp, "trace.0")).read()
-        assert "forward" in text and "train" in text
-        t.chrome_trace(os.path.join(tmp, "trace.json"))
-        events = json.load(open(os.path.join(tmp, "trace.json")))
-        assert len(events) == 4
-        assert all(e["ph"] == "X" for e in events)
-
-
-def pytest_tracer_facade_native_backend():
+    """The GPTL analog, once the C++ region timer, now the span recorder
+    (``utils/tracer.py``): nested regions accumulate per call-tree path,
+    the per-rank table and the chrome trace come from ``save()``."""
     from hydragnn_tpu.utils import tracer as tr
 
     tr.initialize(("native",))
+    tr.reset()
+    for _ in range(2):
+        tr.start("train")
+        tr.start("forward")
+        time.sleep(0.002)
+        tr.stop("forward")
+        tr.stop("train")
+    paths = [s.path for s in tr.spans().records]
+    assert paths.count("train") == 2
+    assert paths.count("train/forward") == 2
+    totals = tr.totals()
+    assert totals["train"] >= totals["train/forward"] > 0
+    with tempfile.TemporaryDirectory() as tmp:
+        tr.save(os.path.join(tmp, "trace"))
+        text = open(os.path.join(tmp, "trace.0")).read()
+        assert "forward" in text and "train" in text
+        events = json.load(open(os.path.join(tmp, "trace.0.trace.json")))
+        spans = [e for e in events if e["ph"] == "X"]
+        assert len(spans) == 4
+        assert {e["ph"] for e in events} == {"X", "M"}
+    tr.reset()
+
+
+def pytest_tracer_facade_native_backend():
+    """Every former backend name gives the one recorder."""
+    from hydragnn_tpu.utils import tracer as tr
+
+    for backends in (("native",), ("timer",), ("jax",), ()):
+        assert tr.initialize(backends) == tr.initialize()
+    tr.reset()
     tr.start("epoch")
     tr.stop("epoch")
+    assert list(tr.totals()) == ["epoch"]
     with tempfile.TemporaryDirectory() as tmp:
         tr.save(os.path.join(tmp, "t"))
         assert os.path.exists(os.path.join(tmp, "t.0"))
