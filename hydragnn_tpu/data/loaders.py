@@ -97,6 +97,40 @@ def _sample_triplets(data: GraphData):
     return data.extras["triplets"]
 
 
+def _cached_neighbor_slots(data: GraphData):
+    """The sample's cached slots, or None on a miss (no entry, or one that
+    no longer matches the sample's edge count)."""
+    slots = data.extras.get("neighbor_slots")
+    if slots is None or slots.shape[1] != data.num_edges:
+        return None
+    return slots
+
+
+def _sample_neighbor_slots(data: GraphData):
+    """``[2, num_edges]`` dense-list slots of the sample's edges (rank of
+    each edge among those with its receiver / its sender, in edge order:
+    ``ops/dense_agg.py:edge_slots``), cached in ``data.extras`` like the
+    triplets. A sample's edges stay contiguous and in order in every batch
+    it joins, so the slots hold in every batch, bucket and epoch."""
+    slots = _cached_neighbor_slots(data)
+    if slots is None:
+        from hydragnn_tpu.ops.dense_agg import edge_slots
+
+        slots = edge_slots(data.edge_index[0], data.edge_index[1])
+        data.extras["neighbor_slots"] = slots
+    return slots
+
+
+def _sample_degrees(data: GraphData) -> Tuple[int, int]:
+    """(max in-degree, max out-degree) of the sample, (0, 0) without edges:
+    the widths its dense lists need. Read off its slots, so the pass that
+    sizes a layout also fills the cache collation reads."""
+    if not data.num_edges:
+        return 0, 0
+    k_in, k_out = _sample_neighbor_slots(data).max(axis=1)
+    return int(k_in) + 1, int(k_out) + 1
+
+
 def _lcm(a, b):
     import math
 
@@ -184,10 +218,10 @@ def _sample_stats(datasets, need_triplets, need_neighbors):
             if need_triplets and not need_neighbors:
                 trips = _sample_triplets(d)
                 t = trips[0].shape[0]
-            if need_neighbors and d.num_edges:
-                from hydragnn_tpu.ops.dense_agg import max_degree
-
-                ki, ko = max_degree(d.edge_index[0], d.edge_index[1])
+            if need_neighbors:
+                # and the slot cache is filled: here on the main thread,
+                # for every split
+                ki, ko = _sample_degrees(d)
             trips_n.append(t)
             kis.append(ki)
             kos.append(ko)
@@ -470,18 +504,30 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
                 extras=pack_triplets(trips, layout.n_pad, layout.t_pad)
             )
     if layout.need_neighbors:
-        from hydragnn_tpu.ops.dense_agg import build_neighbor_lists
+        from hydragnn_tpu.ops.dense_agg import assemble_neighbor_lists
 
-        with tr.span("neighbor_lists", k_in=layout.k_in, k_out=layout.k_out):
-            nbr = build_neighbor_lists(
-                batch.senders,
-                batch.receivers,
-                batch.edge_mask,
+        with tr.span(
+            "neighbor_lists", k_in=layout.k_in, k_out=layout.k_out
+        ) as span:
+            # slots are the samples' own (cached at layout time; a serving
+            # request or an unretained streamed sample computes its own
+            # here): the batch is never sorted
+            built = sum(_cached_neighbor_slots(s) is None for s in samples)
+            slots = np.concatenate(
+                [_sample_neighbor_slots(s) for s in samples], axis=1
+            )
+            real = slots.shape[1]  # collate lays real edges down first
+            nbr = assemble_neighbor_lists(
+                batch.senders[:real],
+                batch.receivers[:real],
+                slots,
                 layout.n_pad,
+                layout.e_pad,
                 layout.k_in,
                 layout.k_out,
                 with_slot_tables=layout.need_triplets,
             )
+            span.set(slots_cached=len(samples) - built, slots_built=built)
             merged = dict(batch.extras or {})
             merged.update(nbr)
             batch = batch.replace(extras=merged)

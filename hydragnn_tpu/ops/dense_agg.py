@@ -19,6 +19,17 @@ Numerics are identical to the segment path (same masking, same empty-
 segment fill); see ``tests/test_dense_agg.py`` for the parity proof.
 The lists live in ``batch.extras`` and are built by the loader when the
 architecture opts in (``dense_aggregation: true``).
+
+Host side, a list is a SLOT per edge plus one assembly. An edge's slot in
+its receiver's (sender's) list is its rank among the edges with that
+receiver (sender), in edge-row order (:func:`edge_slots`) — a property of
+the graph alone, because collation lays a sample's edges down contiguously
+and in the sample's own order. So the loader computes slots once per
+SAMPLE and caches them (``data/loaders.py:_sample_neighbor_slots``), and
+per batch only concatenates them and runs :func:`assemble_neighbor_lists`:
+direct indexed writes, no sort. Callers that hold only an edge list
+(:func:`build_neighbor_lists`) compute slots over those edges and run the
+same assembly — one implementation, identical arrays.
 """
 
 from typing import Optional, Tuple
@@ -49,6 +60,98 @@ def max_degree(senders, receivers, edge_mask=None) -> Tuple[int, int]:
     return max(k_in, 1), max(k_out, 1)
 
 
+def _rank_in_group(owner_ids: np.ndarray) -> np.ndarray:
+    """Rank of every row among the rows with its owner, in row order — the
+    ONE slot-assignment rule (neighbour lists and DimeNet's triplet
+    groupings both place row ``r`` at ``lists[owner[r], rank[r]]``)."""
+    order = np.argsort(owner_ids, kind="stable")
+    o_sorted = owner_ids[order]
+    rank = np.empty(owner_ids.shape[0], np.intp)
+    rank[order] = np.arange(o_sorted.shape[0]) - np.searchsorted(
+        o_sorted, o_sorted, side="left"
+    )
+    return rank
+
+
+def _check_slots(slots: np.ndarray, k: int, label: str):
+    if slots.size and int(slots.max()) >= k:
+        raise ValueError(
+            f"group size exceeds layout {label}={k}; recompute the layout"
+        )
+
+
+def edge_slots(senders, receivers) -> np.ndarray:
+    """``[2, E]`` slots of a graph's (all real) edges: row 0 the rank of
+    each edge among the edges with its RECEIVER, row 1 among those with its
+    SENDER, in edge-row order. Stored at the smallest unsigned dtype that
+    holds them (uint8 up to degree 256: two bytes an edge). What the
+    loader caches per sample; its row maxima + 1 (widened first: a uint8
+    wraps) are the sample's (max in-degree, max out-degree)."""
+    slots = np.stack(
+        [
+            _rank_in_group(np.asarray(receivers)),
+            _rank_in_group(np.asarray(senders)),
+        ]
+    )
+    widest = int(slots.max()) if slots.size else 0
+    return slots.astype(np.min_scalar_type(widest))
+
+
+def assemble_neighbor_lists(
+    senders: np.ndarray,
+    receivers: np.ndarray,
+    slots: np.ndarray,
+    num_nodes: int,
+    num_edge_rows: int,
+    k_in: int,
+    k_out: int,
+    with_slot_tables: bool = False,
+    rows: Optional[np.ndarray] = None,
+):
+    """The extras dict of :func:`build_neighbor_lists` from known per-edge
+    ``slots [2, E]`` (:func:`edge_slots`), by direct indexed writes into
+    zero-initialised arrays — no sort. ``senders``/``receivers``/``slots``
+    cover the REAL edges only; ``rows`` are their rows in the edge table of
+    ``num_edge_rows`` rows (None: the first ``E`` rows, the collate
+    contract). Padded slots hold index 0, mask False."""
+    _check_slots(slots[0], k_in, "k_in")
+    _check_slots(slots[1], k_out, "k_out")
+    if rows is None:
+        rows = np.arange(senders.shape[0], dtype=np.int32)
+    # flat [N*K_in] / [N*K_out] dense slot of every real edge
+    flat_in = receivers.astype(np.intp) * k_in + slots[0]
+    flat_out = senders.astype(np.intp) * k_out + slots[1]
+    nbr_idx = np.zeros((num_nodes, k_in), np.int32)
+    nbr_edge = np.zeros((num_nodes, k_in), np.int32)
+    nbr_mask = np.zeros((num_nodes, k_in), bool)
+    rev_idx = np.zeros((num_nodes, k_out), np.int32)
+    rev_mask = np.zeros((num_nodes, k_out), bool)
+    nbr_idx.reshape(-1)[flat_in] = senders
+    nbr_edge.reshape(-1)[flat_in] = rows
+    nbr_mask.reshape(-1)[flat_in] = True
+    rev_idx.reshape(-1)[flat_out] = flat_in
+    rev_mask.reshape(-1)[flat_out] = True
+    out = {
+        "nbr_idx": nbr_idx,
+        "nbr_edge": nbr_edge,
+        "nbr_mask": nbr_mask,
+        "rev_idx": rev_idx,
+        "rev_mask": rev_mask,
+    }
+    if with_slot_tables:
+        # out_slot is the inverse permutation of out_edge — the bmm-triplet
+        # path routes per-(sender, out-slot) results back onto the edge
+        # table with it
+        out_edge = np.zeros((num_nodes, k_out), np.int32)
+        out_edge.reshape(-1)[flat_out] = rows
+        edge_slot = np.zeros(num_edge_rows, np.int32)
+        out_slot = np.zeros(num_edge_rows, np.int32)
+        edge_slot[rows] = flat_in
+        out_slot[rows] = flat_out
+        out.update(out_edge=out_edge, edge_slot=edge_slot, out_slot=out_slot)
+    return out
+
+
 def build_neighbor_lists(
     senders: np.ndarray,
     receivers: np.ndarray,
@@ -74,43 +177,32 @@ def build_neighbor_lists(
       ``out_slot  [E]``        flat (sender*K_out + slot) of each edge
     (the out-slot validity mask is ``rev_mask`` — same grouping).
     Real edges only (``edge_mask`` False rows are padding and excluded).
-    Built on :func:`build_group_lists` (one slot-assignment implementation
-    for every single-owner grouping).
+
+    The entry for callers that hold only an edge list (a whole batch, a
+    partition shard): slots come from :func:`edge_slots` over these edges,
+    the arrays from :func:`assemble_neighbor_lists`. The loader shares that
+    assembly but takes its slots from the per-sample cache
+    (``data/loaders.py:collate_for_layout``), so it sorts nothing per
+    batch; the two routes give identical arrays.
     """
-    senders = np.asarray(senders, np.int64)
-    # incoming lists: edges grouped by receiver; sender per slot
-    nbr_edge, nbr_mask = build_group_lists(
-        receivers, edge_mask, num_nodes, k_in, label="k_in"
+    senders = np.asarray(senders)
+    receivers = np.asarray(receivers)
+    num_edge_rows = senders.shape[0]
+    rows = None
+    if edge_mask is not None:
+        rows = np.flatnonzero(np.asarray(edge_mask, bool)).astype(np.int32)
+        senders, receivers = senders[rows], receivers[rows]
+    return assemble_neighbor_lists(
+        senders,
+        receivers,
+        edge_slots(senders, receivers),
+        num_nodes,
+        num_edge_rows,
+        k_in,
+        k_out,
+        with_slot_tables=with_slot_tables,
+        rows=rows,
     )
-    nbr_idx = np.where(nbr_mask, senders[nbr_edge], 0).astype(np.int32)
-    # flat [N*K_in] dense slot of every edge row
-    flat_of_edge = np.zeros(senders.shape[0], np.int64)
-    rr, ss = np.nonzero(nbr_mask)
-    flat_of_edge[nbr_edge[rr, ss]] = rr * k_in + ss
-    # reverse lists: edges grouped by sender; flat slot per entry
-    out_edge, rev_mask = build_group_lists(
-        senders, edge_mask, num_nodes, k_out, label="k_out"
-    )
-    rev_idx = np.where(rev_mask, flat_of_edge[out_edge], 0).astype(np.int32)
-    out = {
-        "nbr_idx": nbr_idx,
-        "nbr_edge": nbr_edge,
-        "nbr_mask": nbr_mask,
-        "rev_idx": rev_idx,
-        "rev_mask": rev_mask,
-    }
-    if with_slot_tables:
-        # inverse permutation of out_edge — the bmm-triplet path routes
-        # per-(sender, out-slot) results back onto the edge table with it
-        slot_out_of_edge = np.zeros(senders.shape[0], np.int64)
-        rr, ss = np.nonzero(rev_mask)
-        slot_out_of_edge[out_edge[rr, ss]] = rr * k_out + ss
-        out.update(
-            out_edge=out_edge,
-            edge_slot=flat_of_edge.astype(np.int32),
-            out_slot=slot_out_of_edge.astype(np.int32),
-        )
-    return out
 
 
 @jax.custom_vjp
@@ -258,17 +350,10 @@ def build_group_lists(
         owner_ids, rows = owner_ids[keep], rows[keep]
     lists = np.zeros((num_groups, k), np.int32)
     mask = np.zeros((num_groups, k), bool)
-    order = np.argsort(owner_ids, kind="stable")
-    o_sorted = owner_ids[order]
-    slot = np.arange(o_sorted.shape[0]) - np.searchsorted(
-        o_sorted, o_sorted, side="left"
-    )
-    if o_sorted.size and np.any(slot >= k):
-        raise ValueError(
-            f"group size exceeds layout {label}={k}; recompute the layout"
-        )
-    lists[o_sorted, slot] = rows[order]
-    mask[o_sorted, slot] = True
+    slot = _rank_in_group(owner_ids)
+    _check_slots(slot, k, label)
+    lists[owner_ids, slot] = rows
+    mask[owner_ids, slot] = True
     return lists, mask
 
 

@@ -31,6 +31,7 @@ from hydragnn_tpu.data.loaders import (
     _layout_from_maxima,
     _lcm,
     _partition_node_bounds,
+    _sample_degrees,
     _sample_triplets,
     collate_for_layout,
 )
@@ -186,14 +187,8 @@ def plan_from_samples(
             [_sample_triplets(s)[0].shape[0] for s in samples]
         )
     if need_neighbors:
-        from hydragnn_tpu.ops.dense_agg import max_degree
-
-        deg = [
-            max_degree(s.edge_index[0], s.edge_index[1])
-            if s.num_edges
-            else (1, 1)
-            for s in samples
-        ]
+        # (0, 0) for an edgeless sample: the layout keeps widths >= 1
+        deg = [_sample_degrees(s) for s in samples]
         kis = np.asarray([d[0] for d in deg])
         kos = np.asarray([d[1] for d in deg])
     try:
