@@ -178,7 +178,7 @@ def pytest_bucketed_training_matches_reference_ceiling():
     from the reference CI matrix must still hold (bucketing changes batch
     composition, not semantics). The synthetic BCC dataset has graph sizes
     {2, 4, 8}, so three real buckets form."""
-    from tests.test_graphs import unittest_train_model
+    from e2e_train import unittest_train_model
 
     unittest_train_model(
         "PNA",

@@ -1,164 +1,16 @@
-"""End-to-end accuracy tests through the full public API.
+"""End-to-end accuracy, single head (helper and strategy: ``tests/e2e_train.py``).
 
-Mirrors the reference's core test strategy (``tests/test_graphs.py:25-189``):
-train each model on the deterministic synthetic dataset via
-``hydragnn_tpu.run_training``, reload + predict via ``run_prediction``, and
-assert per-head RMSE and sample MAE against per-model ceilings.
+Default-tier e2e coverage over this file and its ``test_graphs_*.py``
+siblings: every model trains to the accuracy ceilings at least once —
+GIN+MFC (conv head) and PNA+SchNet+SAGE+DimeNet here, PNA and GAT
+(multihead, a file each), CGCNN (lengths), EGNN (equivariant). Which case
+lives in which file, and in what order, is the scheduler's business:
+``conftest.py``, "CI tiers".
 """
 
-import json
-import os
-import sys
-import tempfile
-
-import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-import hydragnn_tpu
-from hydragnn_tpu.utils.config import merge_config
-from synthetic import deterministic_graph_data
-
-# same ceilings as the reference CI (tests/test_graphs.py:139-156)
-THRESHOLDS = {
-    "SAGE": [0.20, 0.20],
-    "PNA": [0.20, 0.20],
-    "MFC": [0.20, 0.20],
-    "GIN": [0.25, 0.20],
-    "GAT": [0.60, 0.70],
-    "CGCNN": [0.50, 0.40],
-    "SchNet": [0.20, 0.20],
-    "DimeNet": [0.50, 0.50],
-    "EGNN": [0.20, 0.20],
-}
-
-_WORKDIR = None
-
-
-def _workdir():
-    global _WORKDIR
-    if _WORKDIR is None:
-        _WORKDIR = tempfile.mkdtemp(prefix="hydragnn_tpu_ci_")
-    return _WORKDIR
-
-
-def unittest_train_model(
-    model_type, ci_input, use_lengths, overwrite_config=None, num_samples_tot=500
-):
-    workdir = _workdir()
-    os.environ["SERIALIZED_DATA_PATH"] = workdir
-    cwd = os.getcwd()
-    os.chdir(workdir)
-    try:
-        config_file = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "inputs", ci_input
-        )
-        with open(config_file, "r") as f:
-            config = json.load(f)
-        config["NeuralNetwork"]["Architecture"]["model_type"] = model_type
-        if overwrite_config:
-            config = merge_config(config, overwrite_config)
-        if use_lengths:
-            config["NeuralNetwork"]["Architecture"]["edge_features"] = ["lengths"]
-        # MFC favors graph-level over node-level heads in the multihead CI run
-        if model_type == "MFC" and ci_input == "ci_multihead.json":
-            config["NeuralNetwork"]["Architecture"]["task_weights"][0] = 2
-
-        perc_train = config["NeuralNetwork"]["Training"]["perc_train"]
-        for name, rel in config["Dataset"]["path"].items():
-            if name == "total":
-                num = num_samples_tot
-            elif name == "train":
-                num = int(num_samples_tot * perc_train)
-            else:
-                num = int(num_samples_tot * (1 - perc_train) * 0.5)
-            # key the cached dataset dir by its size: tests with different
-            # num_samples_tot must not silently share (and therefore train
-            # on whichever size generated first)
-            data_path = os.path.join(workdir, f"{rel}_{num}")
-            config["Dataset"]["path"][name] = data_path
-            if not os.path.exists(data_path) or not os.listdir(data_path):
-                deterministic_graph_data(data_path, number_configurations=num)
-
-        import copy
-
-        hydragnn_tpu.run_training(copy.deepcopy(config))
-        error, error_rmse_task, true_values, predicted_values = (
-            hydragnn_tpu.run_prediction(copy.deepcopy(config))
-        )
-
-        thresholds = dict(THRESHOLDS)
-        if use_lengths and "vector" not in ci_input:
-            thresholds["CGCNN"] = [0.175, 0.175]
-            thresholds["PNA"] = [0.10, 0.10]
-        if use_lengths and "vector" in ci_input:
-            thresholds["PNA"] = [0.2, 0.15]
-        if ci_input == "ci_conv_head.json":
-            thresholds["GIN"] = [0.25, 0.40]
-
-        for ihead in range(len(true_values)):
-            assert (
-                error_rmse_task[ihead] < thresholds[model_type][0]
-            ), f"head {ihead} RMSE {error_rmse_task[ihead]} for {model_type}"
-            mae = float(
-                np.abs(
-                    np.asarray(true_values[ihead])
-                    - np.asarray(predicted_values[ihead])
-                ).mean()
-            )
-            assert (
-                mae < thresholds[model_type][1]
-            ), f"head {ihead} sample MAE {mae} for {model_type}"
-        assert error < thresholds[model_type][0], f"total error {error}"
-    finally:
-        os.chdir(cwd)
-
-
-ALL_MODELS = ["SAGE", "GIN", "GAT", "MFC", "PNA", "CGCNN", "SchNet", "DimeNet", "EGNN"]
-FULL = int(os.getenv("HYDRAGNN_FULL_TEST", "0")) == 1
-
-# Default CI keeps one run per feature axis + the fast models; set
-# HYDRAGNN_FULL_TEST=1 for the reference's full 33-run matrix
-# (tests/test_graphs.py:193-224).
-# Default-tier e2e coverage: every model trains to the accuracy ceilings
-# at least once — PNA+SchNet (singlehead), PNA+GAT (multihead), CGCNN
-# (lengths), EGNN (equivariant), GIN+MFC (conv head), SAGE+DimeNet
-# (singlehead additions below).
-_DEFAULT_SINGLEHEAD = ["PNA", "SchNet", "SAGE", "DimeNet"]
-_DEFAULT_MULTIHEAD = ["PNA", "GAT"]
-
-
-@pytest.mark.parametrize(
-    "model_type", ALL_MODELS if FULL else _DEFAULT_SINGLEHEAD
-)
-def pytest_train_model(model_type):
-    unittest_train_model(model_type, "ci.json", False)
-
-
-@pytest.mark.parametrize(
-    "model_type", ALL_MODELS if FULL else _DEFAULT_MULTIHEAD
-)
-def pytest_train_model_multihead(model_type):
-    unittest_train_model(model_type, "ci_multihead.json", False)
-
-
-@pytest.mark.parametrize(
-    "model_type",
-    ["PNA", "CGCNN", "SchNet", "EGNN"] if FULL else ["PNA", "CGCNN"],
-)
-def pytest_train_model_lengths(model_type):
-    unittest_train_model(model_type, "ci.json", True)
-
-
-@pytest.mark.parametrize("model_type", ["EGNN", "SchNet"] if FULL else ["EGNN"])
-def pytest_train_equivariant_model(model_type):
-    unittest_train_model(model_type, "ci_equivariant.json", False)
-
-
-@pytest.mark.parametrize("model_type", ["PNA"])
-def pytest_train_model_vectoroutput(model_type):
-    unittest_train_model(model_type, "ci_vectoroutput.json", True)
+from e2e_train import ALL_MODELS, FULL, unittest_train_model
 
 
 @pytest.mark.parametrize(
@@ -171,114 +23,8 @@ def pytest_train_model_conv_head(model_type):
     unittest_train_model(model_type, "ci_conv_head.json", False)
 
 
-@pytest.mark.parametrize("model_type", ["PNA"])
-def pytest_train_model_multistep_dispatch(model_type):
-    """steps_per_dispatch (scan multi-step) through the public API must hit
-    the same accuracy ceilings as the per-batch streaming path."""
-    unittest_train_model(
-        model_type,
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {"Training": {"steps_per_dispatch": 4}}
-        },
-        num_samples_tot=300,
-    )
-
-
-@pytest.mark.parametrize("model_type", ["PNA", "DimeNet"])
-def pytest_train_model_dense_aggregation(model_type):
-    """Scatter-free dense neighbor-list aggregation (dense_aggregation:
-    true) through the public API must hit the same accuracy ceilings as
-    the segment path — it is the performance mode for MXU-scale configs
-    (ops/dense_agg.py). DimeNet's dense mode is the bmm-triplet path
-    (models/dimenet.py): no T axis, no host-side compute_triplets."""
-    unittest_train_model(
-        model_type,
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {"Architecture": {"dense_aggregation": True}}
-        },
-        num_samples_tot=300,
-    )
-
-
-@pytest.mark.skipif(not FULL, reason="auto-dense e2e: FULL tier")
-def pytest_train_model_auto_dense_no_flag():
-    """At MXU widths the aggregation path is chosen AUTOMATICALLY (no
-    dense_aggregation key anywhere): the measured-crossover policy must
-    route this hidden-96 MFC run onto the dense path and still hit the
-    reference ceilings through the public API."""
-    unittest_train_model(
-        "MFC",
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {"Architecture": {"hidden_dim": 96}}
-        },
-        num_samples_tot=300,
-    )
-
-
-@pytest.mark.parametrize("model_type", ["PNA"])
-def pytest_train_model_nll_loss(model_type):
-    """Uncertainty-weighted NLL multi-task loss (the mode the reference
-    leaves unfinished): heads grow a log-variance channel, training through
-    the public API still hits the reference accuracy ceilings."""
-    unittest_train_model(
-        model_type,
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {"Architecture": {"ilossweights_nll": 1}}
-        },
-        num_samples_tot=300,
-    )
-
-
-@pytest.mark.parametrize("model_type", ["PNA"])
-def pytest_train_model_whole_training_dispatch(model_type):
-    """Device-resident + chunked whole-training dispatch (fit_staged) must
-    hit the same accuracy ceilings through the public run_training API."""
-    unittest_train_model(
-        model_type,
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {
-                "Training": {
-                    "device_resident_dataset": True,
-                    "fit_chunk_epochs": 10,
-                }
-            }
-        },
-        num_samples_tot=300,
-    )
-
-
-@pytest.mark.skipif(not FULL, reason="cross-mode matrix: FULL tier")
 @pytest.mark.parametrize(
-    "training_overwrite",
-    [
-        {"device_resident_dataset": True, "fit_chunk_epochs": 10},
-        {"steps_per_dispatch": 4},
-    ],
-    ids=["whole_training", "multistep"],
+    "model_type", ALL_MODELS if FULL else ["PNA", "SchNet", "SAGE", "DimeNet"]
 )
-def pytest_train_model_dense_cross_modes(training_overwrite):
-    """dense_aggregation composes with the whole-training and multi-step
-    dispatch modes (the extras ride stage_batches/stack_batches): same
-    reference ceilings through the public API."""
-    unittest_train_model(
-        "PNA",
-        "ci.json",
-        False,
-        overwrite_config={
-            "NeuralNetwork": {
-                "Architecture": {"dense_aggregation": True},
-                "Training": training_overwrite,
-            }
-        },
-        num_samples_tot=300,
-    )
+def pytest_train_model(model_type):
+    unittest_train_model(model_type, "ci.json", False)

@@ -2,16 +2,11 @@
 
 No reference counterpart — HydraGNN trains pure f32. The bf16 path must
 still clear the SAME accuracy ceilings as f32 training
-(``tests/test_graphs.py`` / reference ``tests/test_graphs.py:139-156``),
+(``tests/e2e_train.py`` / reference ``tests/test_graphs.py:139-156``),
 otherwise it would be a perf knob that silently costs accuracy.
 """
 
-import os
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from test_graphs import unittest_train_model
+from e2e_train import unittest_train_model
 
 
 def pytest_mixed_precision_pna_multihead():

@@ -3,17 +3,13 @@
 ``Architecture.partition_axis`` routes ``run_training`` to the partitioned
 trainer: every sample becomes one graph sharded node-wise over all 8 virtual
 devices. Numerics match the unpartitioned model exactly, so the SAME
-accuracy ceilings as ``tests/test_graphs.py`` must hold.
+accuracy ceilings as ``tests/test_graphs*.py`` (``tests/e2e_train.py``) must
+hold.
 """
-
-import os
-import sys
 
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from test_graphs import FULL, unittest_train_model
+from e2e_train import FULL, unittest_train_model
 
 _OVERWRITE = {"NeuralNetwork": {"Architecture": {"partition_axis": "graph"}}}
 
