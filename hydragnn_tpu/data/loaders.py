@@ -35,6 +35,12 @@ class BatchLayout:
     need_neighbors: bool = False
     k_in: int = 0
     k_out: int = 0
+    # the largest graph a batch of this layout may hold, when its builder
+    # knew it (0: no statement). Collation lays a graph's nodes down
+    # contiguously, so every neighbour of a row lies within this many rows
+    # of it; dense-list batches carry it on as ``extras["nbr_reach"]`` and
+    # the neighbour gather reads it (``ops/dense_agg.py gather_neighbors``)
+    nbr_reach: int = 0
 
     @property
     def packs_triplets(self) -> bool:
@@ -300,6 +306,7 @@ def _layout_from_maxima(
         need_neighbors=need_neighbors,
         k_in=max(int(k_in), 1),
         k_out=max(int(k_out), 1),
+        nbr_reach=int(max_nodes) if need_neighbors else 0,
     )
 
 
@@ -347,6 +354,7 @@ def budget_bucket_layout(
         need_neighbors=need_neighbors,
         k_in=max(int(k_in), 1),
         k_out=max(int(k_out), 1),
+        nbr_reach=int(nodes.max()) if need_neighbors else 0,
     )
 
 
@@ -530,6 +538,18 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
             span.set(slots_cached=len(samples) - built, slots_built=built)
             merged = dict(batch.extras or {})
             merged.update(nbr)
+            if layout.nbr_reach:
+                # the statement the neighbour gather selects its block-
+                # local product by, carried in a SHAPE (as nbr_idx's
+                # carries k_in) so that a trace specialises on it
+                largest = max(s.num_nodes for s in samples)
+                if largest > layout.nbr_reach:
+                    raise ValueError(
+                        f"a graph of {largest} nodes exceeds the layout's "
+                        f"stated nbr_reach={layout.nbr_reach}; recompute "
+                        "the layout"
+                    )
+                merged["nbr_reach"] = np.zeros(layout.nbr_reach, np.int8)
             batch = batch.replace(extras=merged)
     return batch
 

@@ -26,11 +26,9 @@ class CGConv(nn.Module):
         extras = batch.extras or {}
         dense = "nbr_idx" in extras
         if dense:  # dense scatter-free path (ops/dense_agg.py)
-            from hydragnn_tpu.ops.dense_agg import dense_sum, gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import dense_sum, neighbor_rows
 
-            x_j = gather_neighbors(
-                x, extras["nbr_idx"], extras["rev_idx"], extras["rev_mask"]
-            )
+            x_j = neighbor_rows(x, extras)
             parts = [jnp.broadcast_to(x[:, None, :], x_j.shape), x_j]
             if self.edge_dim and self.edge_dim > 0:
                 parts.append(batch.edge_attr[extras["nbr_edge"]])

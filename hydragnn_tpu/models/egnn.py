@@ -156,17 +156,14 @@ class E_GCL(nn.Module):
         if dense:
             # dense scatter-free frame: per-edge values live as [N, K, *]
             # keyed by (receiver, slot); j = sender, i = receiver
-            from hydragnn_tpu.ops.dense_agg import gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import neighbor_rows
 
             nmask = extras["nbr_mask"]
             emask_nd = nmask[..., None]
             # ONE fused gather for projected-features+positions (halves the
             # gather / reverse-gather traffic — the dominant dense-mode cost)
-            both_j = gather_neighbors(
-                jnp.concatenate([y_snd, pos], axis=-1),
-                extras["nbr_idx"],
-                extras["rev_idx"],
-                extras["rev_mask"],
+            both_j = neighbor_rows(
+                jnp.concatenate([y_snd, pos], axis=-1), extras
             )
             y_j, pos_j = both_j[..., : self.hidden_dim], both_j[..., self.hidden_dim :]
             coord_diff = pos_j - pos[:, None, :]

@@ -53,15 +53,12 @@ class GATv2Conv(nn.Module):
             # as separate [N, H, C] terms), and the weighted-message sum
             # contracts the K axis with a dot instead of re-reading a
             # broadcast product.
-            from hydragnn_tpu.ops.dense_agg import gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import neighbor_rows
 
             nmask = extras["nbr_mask"]  # [N, K]
-            xl_j = gather_neighbors(
-                x_l.reshape(n, h * c),
-                extras["nbr_idx"],
-                extras["rev_idx"],
-                extras["rev_mask"],
-            ).reshape(n, -1, h, c)  # [N, K, H, C]
+            xl_j = neighbor_rows(x_l.reshape(n, h * c), extras).reshape(
+                n, -1, h, c
+            )  # [N, K, H, C]
             k = xl_j.shape[1]
             alpha_n = (
                 jax.nn.leaky_relu(xl_j + x_r[:, None], self.negative_slope)

@@ -21,11 +21,9 @@ class GINConv(nn.Module):
         eps = self.param("eps", nn.initializers.constant(self.eps_init), ())
         extras = batch.extras or {}
         if "nbr_idx" in extras:  # dense scatter-free path (ops/dense_agg.py)
-            from hydragnn_tpu.ops.dense_agg import dense_sum, gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import dense_sum, neighbor_rows
 
-            x_j = gather_neighbors(
-                x, extras["nbr_idx"], extras["rev_idx"], extras["rev_mask"]
-            )
+            x_j = neighbor_rows(x, extras)
             aggr = dense_sum(x_j, extras["nbr_mask"])
         else:
             # gather+mask+reduce through the one shared helper: XLA

@@ -49,12 +49,10 @@ class MFConv(nn.Module):
 
         extras = batch.extras or {}
         if "nbr_idx" in extras:  # dense scatter-free path (ops/dense_agg.py)
-            from hydragnn_tpu.ops.dense_agg import dense_sum, gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import dense_sum, neighbor_rows
 
             nmask = extras["nbr_mask"]
-            x_j = gather_neighbors(
-                x, extras["nbr_idx"], extras["rev_idx"], extras["rev_mask"]
-            )
+            x_j = neighbor_rows(x, extras)
             h = dense_sum(x_j, nmask)
             deg = nmask.sum(axis=1).astype(jnp.float32)
         else:

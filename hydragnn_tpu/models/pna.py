@@ -78,18 +78,17 @@ class PNAConv(nn.Module):
             # as masked K-axis reductions, backward via the reverse list.
             # (A fused banded Pallas variant of this gather+stats pass was
             # built and measured in rounds 3-4 — it lost to XLA's own
-            # fusion at every scale and was deleted.)
+            # fusion at every scale and was deleted. The gather ALONE is a
+            # block-local MXU product where the batch states locality:
+            # ops/dense_agg.py gather_neighbors.)
             from hydragnn_tpu.ops.dense_agg import (
                 dense_minmax,
                 dense_moments,
-                gather_neighbors,
+                neighbor_rows,
             )
 
             nbr_mask = extras["nbr_mask"]
-            nbr_idx = extras["nbr_idx"]
-            z = gather_neighbors(
-                yj, nbr_idx, extras["rev_idx"], extras["rev_mask"]
-            )  # [N, K, D]
+            z = neighbor_rows(yj, extras)  # [N, K, D]
             if ze is not None:
                 z = z + ze[extras["nbr_edge"]]
             z = jnp.where(nbr_mask[..., None], z, 0.0)

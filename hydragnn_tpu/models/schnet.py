@@ -80,11 +80,11 @@ class CFConv(nn.Module):
             # quantity lives as [N, K, *]; pos gathers go through the
             # custom-VJP gather so the equivariant backward stays
             # scatter-free too
-            from hydragnn_tpu.ops.dense_agg import gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import neighbor_rows
 
             nbr, nmask = extras["nbr_idx"], extras["nbr_mask"]
             rev, rmask = extras["rev_idx"], extras["rev_mask"]
-            pos_j = gather_neighbors(pos, nbr, rev, rmask)
+            pos_j = neighbor_rows(pos, extras)
             pos_i = jnp.broadcast_to(pos[:, None, :], pos_j.shape)
             if self.use_edge_attr:
                 edge_weight = jnp.linalg.norm(
@@ -177,9 +177,9 @@ class CFConv(nn.Module):
             pos = pos + agg / jnp.maximum(cnt, 1.0)[:, None]
 
         if dense:
-            from hydragnn_tpu.ops.dense_agg import dense_sum, gather_neighbors
+            from hydragnn_tpu.ops.dense_agg import dense_sum, neighbor_rows
 
-            h_j = gather_neighbors(h, nbr, rev, rmask)
+            h_j = neighbor_rows(h, extras)
             aggr = dense_sum(h_j * w, nmask)
         else:
             # continuous-filter aggregation through the shared helper: XLA

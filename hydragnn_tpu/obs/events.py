@@ -66,7 +66,9 @@ EVENT_FIELDS: Dict[str, tuple] = {
     # bucket layout uses and why — source is env|cache|measured, layout
     # (the family the batch layout committed to, models/base.py) or guard
     # (a requested kernel the VMEM guard sent to XLA); optional timings_ms
-    # carries the measured candidate times
+    # carries the measured candidate times. source "operands" is the dense
+    # path's neighbour gather (ops/dense_agg.py): gather = choice =
+    # onehot|xla, h the window's halo in blocks
     "agg_choice": ("bucket", "choice", "source"),
     # on a TPU an autotune probe that fails to compile or run is an error;
     # this records the compiler's message before it propagates

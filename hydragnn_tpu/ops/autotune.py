@@ -285,13 +285,16 @@ def record_choice(signature: str, choice: str, timings_ms: Optional[Dict],
 # ---------------------------------------------------------------------------
 
 def emit_choice(signature: str, choice: str, source: str,
-                timings_ms: Optional[Dict] = None):
+                timings_ms: Optional[Dict] = None, **extra):
     """One ``agg_choice`` event + ``aggregation_kernel`` gauge per novel
     (signature, choice, source) PER TELEMETRY RUN — deduplicated so
     per-trace re-decisions don't spam the stream. The dedup set lives ON
     the active RunTelemetry (not process-global, and not keyed by id() —
     a GC'd run's address gets reused), so every run's events.jsonl
-    stands alone; with no run active there is nothing to emit."""
+    stands alone; with no run active there is nothing to emit. ``extra``
+    fields ride on the event (the neighbour gather's ``gather`` / ``h``,
+    ``ops/dense_agg.py``); a choice outside ``CHOICES`` names no kernel
+    family and sets no gauge."""
     from hydragnn_tpu.obs import runtime as obs_rt
 
     run = obs_rt.active()
@@ -311,7 +314,9 @@ def emit_choice(signature: str, choice: str, source: str,
             fields["timings_ms"] = {
                 k: round(float(v), 4) for k, v in timings_ms.items()
             }
-        obs_rt.emit("agg_choice", **fields)
+        obs_rt.emit("agg_choice", **fields, **extra)
+        if choice not in CHOICES:
+            return
         # exactly ONE choice label reads 1 per bucket: a re-decision
         # (env override after a measured pass, fused->segment VMEM
         # fallback) must zero the previously-active label or dashboards

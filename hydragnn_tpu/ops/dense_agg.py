@@ -32,6 +32,7 @@ direct indexed writes, no sort. Callers that hold only an edge list
 same assembly — one implementation, identical arrays.
 """
 
+import functools
 from typing import Optional, Tuple
 
 import numpy as np
@@ -205,11 +206,62 @@ def build_neighbor_lists(
     )
 
 
+def _backend() -> str:
+    """The platform the program is traced for (one name, so a test that
+    runs the kernels in the interpreter can say ``tpu`` here)."""
+    return jax.default_backend()
+
+
+def gather_neighbors(
+    x, nbr_idx, rev_idx, rev_mask, nbr_mask=None, nbr_reach=None
+):
+    """``x[nbr_idx]`` ([N, D] -> [N, K, D]) whose backward pass is no
+    scatter-add. The ONE neighbour gather of the dense path, in one of
+    two implementations chosen at trace time from the operands alone
+    (``ops/local_gather.py window_halo``: table dtype, backend, what the
+    collate states about locality, the window's size):
+
+    - ``xla``: the indexed read, backward a gather through the reverse
+      list. What every caller gets that states no locality (``nbr_reach``
+      None: lists built from a bare edge list), bit for bit as before;
+    - ``onehot``: block-local one-hot products on the MXU in both
+      directions, for a batch whose collate states (``extras["nbr_reach"]``,
+      ``data/loaders.py collate_for_layout``) that every sender lies
+      within ``nbr_reach.shape[-1]`` rows of its receiver. Real slots
+      equal the indexed read bit for bit; padded slots read zero instead
+      of row 0 (every consumer masks them with ``nbr_mask``).
+
+    The choice is reported as an ``agg_choice`` event (``gather``, ``h``).
+    """
+    from hydragnn_tpu.ops.autotune import emit_choice
+    from hydragnn_tpu.ops.local_gather import window_halo
+
+    (n, d), k_in = x.shape, nbr_idx.shape[1]
+    h = None
+    if nbr_mask is not None and nbr_reach is not None:
+        h = window_halo(x.dtype, nbr_reach.shape[-1], k_in, d, _backend())
+    impl = "xla" if h is None else "onehot"
+    emit_choice(
+        f"gather/n{n}/k{k_in}/d{d}/{x.dtype.name}", impl, "operands",
+        gather=impl, **({} if h is None else {"h": h}),
+    )
+    if h is None:
+        return _gather_xla(x, nbr_idx, rev_idx, rev_mask)
+    return _gather_onehot(h, x, nbr_idx, nbr_mask)
+
+
+def neighbor_rows(x, extras):
+    """:func:`gather_neighbors` through a dense-list batch's ``extras``,
+    with everything they state (the convs' one call)."""
+    return gather_neighbors(
+        x, extras["nbr_idx"], extras["rev_idx"], extras["rev_mask"],
+        extras["nbr_mask"], extras.get("nbr_reach"),
+    )
+
+
 @jax.custom_vjp
 @_scope
-def gather_neighbors(x, nbr_idx, rev_idx, rev_mask):
-    """``x[nbr_idx]`` ([N, D] -> [N, K, D]) whose backward pass is a
-    gather through the reverse list instead of a scatter-add."""
+def _gather_xla(x, nbr_idx, rev_idx, rev_mask):
     # host-built lists: padded slots hold index 0 (always in range);
     # every consumer masks the gathered rows with nbr_mask before
     # accumulating, so the raw gather is the masking contract's input
@@ -235,7 +287,34 @@ def _gather_bwd(res, g):
     return gx, None, None, None
 
 
-gather_neighbors.defvjp(_gather_fwd, _gather_bwd)
+_gather_xla.defvjp(_gather_fwd, _gather_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+@_scope
+def _gather_onehot(h, x, nbr_idx, nbr_mask):
+    from hydragnn_tpu.ops.local_gather import gather_product
+
+    # the kernels work slot-major ([K, N, D]: whole tiles per slot); the
+    # transposes are layout choices XLA folds into the fusions beside them
+    return gather_product(x, nbr_idx, h).transpose(1, 0, 2)
+
+
+@_scope
+def _gather_onehot_fwd(h, x, nbr_idx, nbr_mask):
+    return _gather_onehot(h, x, nbr_idx, nbr_mask), (nbr_idx, nbr_mask)
+
+
+@_scope
+def _gather_onehot_bwd(h, res, g):
+    from hydragnn_tpu.ops.local_gather import scatter_product
+
+    nbr_idx, nbr_mask = res
+    gx = scatter_product(g.transpose(1, 0, 2), nbr_idx, nbr_mask, h)
+    return gx, None, None
+
+
+_gather_onehot.defvjp(_gather_onehot_fwd, _gather_onehot_bwd)
 
 
 @jax.custom_vjp

@@ -268,6 +268,10 @@ def plan_from_layout(
         cap_nodes = (
             min(bounds[i], lay.n_pad - 1) if i < len(bounds) else lay.n_pad - 1
         )
+        if lay.nbr_reach:
+            # a dense-list layout states its largest graph; collate refuses
+            # a larger one, so admission does first
+            cap_nodes = min(cap_nodes, lay.nbr_reach)
         capacities.append(
             BucketCapacity(
                 max_nodes=cap_nodes,

@@ -324,6 +324,15 @@ class Trainer(PredictMixin):
                 sharding = (
                     self._stacked_sharding if stacked else self._batch_sharding
                 )
+                if batch.extras and "nbr_reach" in batch.extras:
+                    # the collate's locality statement describes ONE array
+                    # of rows; here they are split over the data axis (a
+                    # neighbour may lie on another device), so the sharded
+                    # batch makes none and keeps XLA's gather
+                    batch = batch.replace(extras={
+                        k: v for k, v in batch.extras.items()
+                        if k != "nbr_reach"
+                    })
                 if jax.process_count() > 1:
                     host = _offset_local_shard(batch, jax.process_index())
 

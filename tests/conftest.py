@@ -23,6 +23,7 @@ if "xla_force_host_platform_device_count" not in flags:
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -63,6 +64,20 @@ if int(os.getenv("HYDRAGNN_FAST_TEST", "0")) == 1:
         "test_model_loadpred.py",  # train+reload e2e runs
         "test_hpo.py",  # HPO trial loops
     ]
+
+
+@pytest.fixture(autouse=True)
+def _no_span_left_open():
+    """Files share a worker's process and the span recorder's per-thread
+    stack (``utils/tracer.py``; ``reset()`` leaves open spans open). An
+    epoch that raises, as ``test_resilience.py``'s unbounded divergence
+    does by design, leaves ``train`` open, and whichever file the worker
+    takes next would record its spans under ``train/``: which file that
+    is changes whenever a file gains a case."""
+    yield
+    tracer = sys.modules.get("hydragnn_tpu.utils.tracer")
+    if tracer is not None:
+        tracer._state.stack().clear()
 
 
 def pytest_terminal_summary(terminalreporter, config):

@@ -23,7 +23,9 @@ import numpy as np
 import pytest
 
 import chip_smoke as cs
+from hydragnn_tpu.ops import dense_agg as da
 from hydragnn_tpu.ops import fused_mp as fm
+from hydragnn_tpu.ops import local_gather as lg
 from hydragnn_tpu.ops import pallas_segment as ps
 
 V5E_HBM_BYTES = 16 * 1024**3
@@ -179,6 +181,45 @@ def pytest_fused_kernel_compiles_at_guard_max(
     assert not fm.fused_mp_enabled(SMOKE_NODES, SMOKE_NODES, td, od, tdb)
 
 
+# ---- block-local neighbour gather (ops/local_gather.py) ---------------------
+
+
+@pytest.mark.parametrize(
+    "n,reach,k_in,dim",
+    [
+        (88648, 225, 12, 256),  # the PNA cell's largest bucket: h = 2
+        (39432, 129, 12, 1),  # its first layer: one feature, padded to 128
+        (4104, 257, 19, 512),  # the rule's limit: 20 tiles, blocks at the budget
+        (4104, 1153, 12, 1),  # the farthest reach it admits: h = 9, 19 tiles
+        (4104, 129, 100, 128),  # a hundred slots: the forward's 0/1 matrix at the budget
+    ],
+)
+def pytest_local_gather_compiles_where_the_rule_selects_it(
+    chip, monkeypatch, n, reach, k_in, dim
+):
+    monkeypatch.setattr(da, "_backend", lambda: "tpu")
+    h = lg.window_halo(jnp.bfloat16, reach, k_in, dim, "tpu")
+    assert h == -(-(reach - 1) // lg.BLOCK)
+    # one more slot, or one more block of reach, and the rule keeps XLA's
+    if (n, dim) == (4104, 512):
+        assert lg.window_halo(jnp.bfloat16, reach, k_in + 1, dim, "tpu") is None
+        assert lg.window_halo(jnp.bfloat16, reach + 1, k_in, dim, "tpu") is None
+    lists = lambda dt, k: _shape(chip, (n, k), dt)
+    text = _compile_fwd_and_grad(
+        lambda x, idx, rev, rmask, mask, stated: da.gather_neighbors(
+            x, idx, rev, rmask, mask, stated
+        ),
+        1,
+        (
+            _shape(chip, (n, dim), jnp.bfloat16),
+            lists(jnp.int32, k_in), lists(jnp.int32, 21),
+            lists(jnp.bool_, 21), lists(jnp.bool_, k_in),
+            _shape(chip, (reach,), jnp.int8),
+        ),
+    )
+    assert text.count(KERNEL) == 2
+
+
 # ---- the whole jitted train step of chip_smoke.py ---------------------------
 
 
@@ -215,16 +256,24 @@ def _compile_train_step(chip, trainer, state, batch):
     ).compile()
 
 
-def pytest_smoke_train_step_compiles_full_width(chip, tmp_path, monkeypatch):
+@pytest.mark.parametrize("backend,kernels", [("cpu", 0), ("tpu", 2)])
+def pytest_smoke_train_step_compiles_full_width(
+    chip, tmp_path, monkeypatch, backend, kernels
+):
     """Headline width and batch (PNA h256 bf16, 64 slabs): the policy lays
-    the batch out dense, so XLA runs the whole step — no kernel expected —
-    and the program fits the chip's 16 GB with room to spare."""
+    the batch out dense and its collate states locality. Traced for the
+    CPU this process runs on, XLA runs the whole step; traced as on the
+    chip, the neighbour gather of the (depth-cut) conv is the block-local
+    product, forward and backward. Either fits the chip's 16 GB with room
+    to spare."""
+    monkeypatch.setattr(da, "_backend", lambda: backend)
     trainer, state, batch = _smoke_train_step(
         tmp_path, monkeypatch, dict(train_graphs=64)
     )
     assert "nbr_idx" in batch.extras and batch.x.shape[0] > 5000
+    assert 80 <= batch.extras["nbr_reach"].shape[0] <= 90  # its bucket's largest slab
     compiled = _compile_train_step(chip, trainer, state, batch)
-    assert KERNEL not in compiled.as_text()
+    assert compiled.as_text().count(KERNEL) == kernels
     mem = compiled.memory_analysis()
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes
