@@ -284,7 +284,7 @@ def _extra_configs():
         # CGCNN crossover vs INPUT width (its convs run at input_dim —
         # round-4 verdict item 8): segment/dense pairs at the two anchor
         # widths of the measured INVERSE crossover (dense wins narrow,
-        # loses wide; ops/autotune.py DENSE_AUTO_MAX_INPUT_DIM)
+        # loses wide; ops/agg_policy.py DENSE_AUTO_MAX_INPUT_DIM)
         dict(model_type="CGCNN", hidden=64, input_dim=4, **oc20),
         dict(model_type="CGCNN", hidden=64, input_dim=4, dense=True,
              bf16=True, **oc20),
@@ -359,11 +359,7 @@ def bench_extra_rows(start: int = 0, ages: dict = None):
         return [], 0, []
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from benchmarks.model_bench import bench_model
-    from hydragnn_tpu.ops.autotune import (
-        bucket_signature,
-        cached_choice,
-        static_aggregation_choice,
-    )
+    from hydragnn_tpu.ops.agg_policy import static_aggregation_choice
 
     configs = _extra_configs()
     start = start % len(configs)
@@ -390,30 +386,15 @@ def bench_extra_rows(start: int = 0, ages: dict = None):
             # 8 iters/row (was 12): the per-row cost cut that, with the
             # oldest-first refresh, holds max staleness at <=2 runs
             row = bench_model(**kw, iters=8)
-            # what the autotuner would pick for this (model, width) —
-            # a cached measured decision for the row's bucket when one
-            # exists (ops/autotune.py), else the static policy tier —
-            # so the table shows the auto choice against the measured
-            # per-path winners
-            from hydragnn_tpu.graph import pad_sizes_for
-
-            n_pad, e_pad, _ = pad_sizes_for(
-                kw["nodes"], kw["nodes"] * kw["degree"], kw["num_graphs"]
-            )
-            sig = bucket_signature(
-                kw["model_type"], n_pad, e_pad, kw["hidden"]
-            )
-            cached = cached_choice(sig)
-            row["auto_choice"] = (
-                cached["choice"]
-                if cached is not None
-                else static_aggregation_choice(
-                    {
-                        "model_type": kw["model_type"],
-                        "hidden_dim": kw["hidden"],
-                        "input_dim": kw.get("input_dim", 1),
-                    }
-                )
+            # what the width tables pick for this (model, width), so the
+            # table shows the auto choice against the measured per-path
+            # winners
+            row["auto_choice"] = static_aggregation_choice(
+                {
+                    "model_type": kw["model_type"],
+                    "hidden_dim": kw["hidden"],
+                    "input_dim": kw.get("input_dim", 1),
+                }
             )
             rows.append(row)
         except Exception as e:

@@ -1,9 +1,8 @@
 """Where does the dense PNA step's time go? Times the fused-algebra
 aggregation op (gather + 4 masked K-axis statistics, fwd+grad) alone at
 OC20 scale vs a matmul floor — each as ONE dispatch of a chained
-lax.fori_loop (per-dispatch host cost otherwise swamps an op this small;
-see segment_bench). Sizes the Pallas fusion opportunity
-(round-3 verdict item 1)."""
+lax.fori_loop (per-dispatch host cost otherwise swamps an op this small).
+Sizes the Pallas fusion opportunity (round-3 verdict item 1)."""
 import sys, os, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np, jax, jax.numpy as jnp

@@ -93,8 +93,8 @@ def run(samples, batch_size, num_buckets, hidden, epochs, k_dispatch=1,
 
 def run_device(samples, batch_size, num_buckets, hidden, iters=20):
     """DEVICE time per epoch: per distinct batch shape, enqueue ``iters``
-    dispatches of the compiled step and block once (the segment_bench
-    methodology), then sum step-time x batch-count. Isolates compute from
+    dispatches of the compiled step and block once (as
+    ``benchmarks/model_bench.py`` does), then sum step-time x batch-count. Isolates compute from
     the host's loader/dispatch overheads."""
     import jax
 

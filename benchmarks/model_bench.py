@@ -11,7 +11,7 @@ step (``.lower(...).compile().cost_analysis()``), not a hand count.
 Timing discipline: ``iters`` dispatches of the SAME program are enqueued
 (the device executes them back-to-back) and the clock stops after one
 ``jax.block_until_ready`` on the last result, so elapsed/iters is device
-step time (same methodology as ``benchmarks/segment_bench.py``).
+step time.
 
 Usage: ``python benchmarks/model_bench.py --model=PNA --hidden=256
 --graphs=64 --nodes=90 [--bf16] [--iters=20]`` or import

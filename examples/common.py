@@ -160,7 +160,7 @@ def train_with_loaders(config, trainset, valset, testset, log_name, seed=0):
     print_utils.setup_log(log_name)
 
     training = config["NeuralNetwork"]["Training"]
-    from hydragnn_tpu.data.loaders import (
+    from hydragnn_tpu.ops.agg_policy import (
         arch_for_auto_policy,
         needs_dense_neighbors,
     )

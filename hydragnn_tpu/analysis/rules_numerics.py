@@ -38,8 +38,9 @@ _NUMERIC_PATTERNS = (
     "hydragnn_tpu/graph/*", "graph/*", "*/graph/*",
     "hydragnn_tpu/ops/*", "ops/*", "*/ops/*",
 )
-# the padded-edge kernels: gathers here must honor fused_mp's masking
-# contract (_safe_gather / explicit where-mask of every padded slot)
+# the padded-edge kernels: gathers here must honor the padded-edge masking
+# contract (clip / explicit where-mask of every padded slot, as
+# ops/dense_agg.py gather_neighbors does)
 _OPS_PATTERNS = (
     "hydragnn_tpu/ops/*", "ops/*", "*/ops/*",
 )
@@ -755,8 +756,8 @@ class UnmaskedGatherId(Rule):
     suite = "numerics"
     description = (
         "gather/segment op in ops/ whose index operand is not provably "
-        "routed through the padded-edge masking contract (fused_mp's "
-        "_safe_gather / clip+where) — a padded or stale id reads (or "
+        "routed through the padded-edge masking contract (clip+where, "
+        "as dense_agg.gather_neighbors) — a padded or stale id reads (or "
         "scatters) out of contract silently; mask the ids or the result"
     )
 
@@ -866,9 +867,9 @@ class PallasVmemUnbounded(Rule):
     suite = "numerics"
     description = (
         "pl.pallas_call in a module with no *_enabled VMEM-budget gate "
-        "— fused_mp.fused_mp_enabled sizes the working set against "
-        "_VMEM_BUDGET before fusing; an ungated kernel OOMs VMEM at a "
-        "shape the CPU tests never see"
+        "— local_gather.window_halo sizes the working set against "
+        "_VMEM_BLOCK_BUDGET before dispatching; an ungated kernel OOMs "
+        "VMEM at a shape the CPU tests never see"
     )
 
     def check(self, module: ModuleInfo) -> Iterable[Finding]:
@@ -902,7 +903,7 @@ class PallasVmemUnbounded(Rule):
                 "pallas_call with no module-level *_enabled gate "
                 "referencing a VMEM/BUDGET constant — size the "
                 "kernel's working set against a budget (see "
-                "ops/fused_mp.fused_mp_enabled) before dispatching",
+                "ops/local_gather.window_halo) before dispatching",
             )
             for node in calls
         ]

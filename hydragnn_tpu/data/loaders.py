@@ -17,6 +17,10 @@ import numpy as np
 
 from hydragnn_tpu.data.dataobj import GraphData
 from hydragnn_tpu.graph.batch import _round_up, collate_graphs, pad_sizes_for
+from hydragnn_tpu.ops.agg_policy import (
+    arch_for_auto_policy,
+    needs_dense_neighbors,
+)
 from hydragnn_tpu.utils import tracer as tr
 from hydragnn_tpu.utils.envparse import env_int
 
@@ -141,70 +145,6 @@ def _lcm(a, b):
     import math
 
     return a * b // math.gcd(a, b)
-
-
-# The measured dense/segment crossover tables and the policy function were
-# promoted to ops/autotune.py (the per-bucket aggregation autotuner owns
-# every choice tier now); the loader keeps the historical import surface.
-from hydragnn_tpu.ops.autotune import (  # noqa: F401  (re-exports)
-    DENSE_AUTO_MAX_INPUT_DIM as _DENSE_AUTO_MAX_INPUT_DIM,
-    DENSE_AUTO_MIN_HIDDEN as _DENSE_AUTO_MIN_HIDDEN,
-    auto_dense_aggregation,
-)
-
-
-def arch_for_auto_policy(nn_config: dict) -> dict:
-    """Architecture dict enriched with ``input_dim`` (CGCNN's crossover
-    key) derived from ``Variables_of_interest.input_node_features`` when
-    the config predates ``update_config`` — ONE derivation shared by every
-    entry point so their dense/segment decisions cannot diverge."""
-    arch = nn_config["Architecture"]
-    feats = nn_config.get("Variables_of_interest", {}).get(
-        "input_node_features"
-    )
-    if feats and "input_dim" not in arch:
-        return dict(arch, input_dim=len(feats))
-    return arch
-
-
-def needs_dense_neighbors(arch_config: dict) -> bool:
-    """Single rule for dense scatter-free aggregation in the BATCH-collate
-    path. ``HYDRAGNN_AGG`` (the autotuner's family force) wins over
-    everything; then an explicit ``dense_aggregation`` true/false; then
-    AUTO (the measured-crossover policy picks the winning path per
-    model x width). Off under graph partitioning — there the partitioner
-    builds per-shard lists itself (``partition_graph(need_neighbors=True)``,
-    wired by the driver)."""
-    if arch_config.get("partition_axis"):
-        return False
-    from hydragnn_tpu.ops.autotune import (
-        DENSE_AUTO_MAX_INPUT_DIM,
-        cached_model_choice,
-        env_force,
-    )
-
-    forced = env_force()
-    if forced is not None:
-        return forced == "dense"
-    flag = arch_config.get("dense_aggregation")
-    if flag is not None:
-        return bool(flag)
-    # AUTO: a measured autotuner decision for this model AT THIS WIDTH
-    # beats the static crossover tables — this is where a cached "dense"
-    # win is actually ENACTED (the layout is where dense happens). The
-    # width key mirrors the static policy's: input_dim for the
-    # constant-width stacks (CGCNN), hidden_dim for the rest.
-    mt = arch_config.get("model_type") or ""
-    width = (
-        arch_config.get("input_dim")
-        if mt in DENSE_AUTO_MAX_INPUT_DIM
-        else arch_config.get("hidden_dim")
-    )
-    if width:
-        cached = cached_model_choice(mt, int(width))
-        if cached is not None:
-            return cached == "dense"
-    return auto_dense_aggregation(arch_config)
 
 
 def _sample_stats(datasets, need_triplets, need_neighbors):

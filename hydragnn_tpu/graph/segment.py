@@ -22,19 +22,13 @@ _scope = jax.named_scope("agg_segment")
 
 @_scope
 def segment_sum(data, segment_ids, num_segments):
-    from hydragnn_tpu.ops import pallas_segments_enabled, segment_sum_onehot
-
     # scatter-adds in sub-f32 dtypes are pathologically slow on TPU (measured
     # 14x on v5e under bf16 mixed precision) AND lose accumulation precision;
-    # run the reduction in f32, hand back the caller's dtype. Upcast BEFORE
-    # the pallas dispatch — its kernel and custom VJP are f32-only.
+    # run the reduction in f32, hand back the caller's dtype
     in_dtype = data.dtype
     if in_dtype in (jnp.bfloat16, jnp.float16):
         data = data.astype(jnp.float32)
-    if data.ndim == 2 and pallas_segments_enabled(num_segments, data.shape[1]):
-        out = segment_sum_onehot(data, segment_ids, num_segments)
-    else:
-        out = jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+    out = jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
     return out.astype(in_dtype) if out.dtype != in_dtype else out
 
 
@@ -121,10 +115,10 @@ def segment_std(data, segment_ids, num_segments, eps=1e-5):
 def segment_moments_fused(data, segment_ids, num_segments, weights=None):
     """(sum, count, sum_of_squares) per segment from ONE scatter pass.
 
-    XLA fallback counterpart of the pallas ``segment_moments`` kernel: packs
-    data / data^2 / count-weights on the feature axis so a single segment
-    scatter produces all three statistics (scatter passes, not flops, are
-    the hot cost at small-graph scale — measured on v5e, bench.py).
+    Packs data / data^2 / count-weights on the feature axis so a single
+    segment scatter produces all three statistics (scatter passes, not
+    flops, are the hot cost at small-graph scale — measured on v5e,
+    bench.py).
     ``weights``: optional [E] count weights (e.g. an edge mask).
     """
     d = data.shape[1]

@@ -235,7 +235,7 @@ class HydraBase(nn.Module):
         act = get_activation(self.activation)
         heads_cfg = self.config_heads or {}
         batch = self._prepare_batch(batch)
-        from hydragnn_tpu.ops.autotune import emit_layout_choice
+        from hydragnn_tpu.ops.agg_policy import emit_layout_choice
 
         emit_layout_choice(self, batch)
         x = batch.x

@@ -279,51 +279,17 @@ class MLP(nn.Module):
         return x
 
 
-def fused_site(model_key: str, num_nodes: int, num_edges: int,
-               table_dim: int, out_dim: int, table_dim_b: int = 0) -> bool:
-    """Trace-time check: should this aggregation site run the fused Pallas
-    message-passing kernel (``ops/fused_mp.py``)? ONE funnel over the
-    autotuner/env decision (``ops/autotune.py``) so every model stack opts
-    in the same way — no per-model enablement forks."""
-    from hydragnn_tpu.ops.autotune import use_fused
-
-    return use_fused(
-        model_key, num_nodes, num_edges, table_dim, out_dim,
-        table_dim_b=table_dim_b,
-    )
-
-
-def gather_segment_sum(x, senders, receivers, num_segments, edge_mask,
-                       model_key: str = "generic"):
+def gather_segment_sum(x, senders, receivers, num_segments, edge_mask):
     """``segment_sum(where(mask, x[senders], 0), receivers)`` — the
-    sum-aggregation conv primitive (GIN et al) behind ONE helper: the
-    fused gather->reduce Pallas kernel when the autotuner/env picks it,
-    else the XLA gather + segment-sum path. Identical numerics either way
-    (f32 accumulation; result in ``x.dtype``)."""
-    e = senders.shape[0]
-    if fused_site(model_key, x.shape[0], e, x.shape[-1], x.shape[-1]):
-        from hydragnn_tpu.ops import fused_gather_sum
-
-        return fused_gather_sum(
-            x, senders, receivers, num_segments, edge_mask
-        ).astype(x.dtype)
+    sum-aggregation conv primitive (GIN et al): f32 accumulation, result
+    in ``x.dtype``."""
     msg = jnp.where(edge_mask[:, None], x[senders], 0.0)
     return segment_sum(msg, receivers, num_segments)
 
 
-def gather_segment_mean(x, senders, receivers, num_segments, edge_mask,
-                        model_key: str = "generic"):
-    """Masked mean over real incoming edges (SAGE's aggregator): sum and
-    real in-degree from one fused reduction, or the XLA two-scatter
-    fallback. Returns ``[S, D]`` in ``x.dtype``."""
-    e = senders.shape[0]
-    if fused_site(model_key, x.shape[0], e, x.shape[-1], x.shape[-1] + 1):
-        from hydragnn_tpu.ops import fused_gather_mean
-
-        mean, _deg = fused_gather_mean(
-            x, senders, receivers, num_segments, edge_mask
-        )
-        return mean.astype(x.dtype)
+def gather_segment_mean(x, senders, receivers, num_segments, edge_mask):
+    """Masked mean over real incoming edges (SAGE's aggregator): the sum
+    over the real in-degree (f32), ``[S, D]``."""
     from hydragnn_tpu.graph import segment_count
 
     msg = jnp.where(edge_mask[:, None], x[senders], 0.0)
@@ -334,18 +300,9 @@ def gather_segment_mean(x, senders, receivers, num_segments, edge_mask,
     return total / jnp.maximum(deg, 1.0)[:, None]
 
 
-def gather_weighted_segment_sum(h, w, senders, receivers, num_segments,
-                                model_key: str = "generic"):
+def gather_weighted_segment_sum(h, w, senders, receivers, num_segments):
     """``segment_sum(h[senders] * w, receivers)`` (SchNet's CFConv
-    aggregation; ``w`` pre-masked ``[E, F]``) — fused kernel or the XLA
-    gather-multiply-scatter, same numerics."""
-    if fused_site(model_key, h.shape[0], senders.shape[0], h.shape[-1],
-                  h.shape[-1]):
-        from hydragnn_tpu.ops import fused_gather_weighted_sum
-
-        return fused_gather_weighted_sum(
-            h, w, senders, receivers, num_segments
-        ).astype(h.dtype)
+    aggregation; ``w`` pre-masked ``[E, F]``)."""
     return segment_sum(h[senders] * w, receivers, num_segments)
 
 

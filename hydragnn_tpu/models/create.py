@@ -195,7 +195,7 @@ def resolve_precision(model, training_config: dict) -> dict:
     """
     import os
 
-    from hydragnn_tpu.ops.autotune import model_key_for
+    from hydragnn_tpu.ops.agg_policy import model_key_for
 
     env = os.getenv("HYDRAGNN_MIXED_PRECISION")
     if env is not None and env.strip() != "":

@@ -19,7 +19,7 @@ from flax import linen as nn
 
 from hydragnn_tpu.graph import segment_sum
 from hydragnn_tpu.models.base import HydraBase
-from hydragnn_tpu.models.common import SplitLinear, TorchLinear, fused_site
+from hydragnn_tpu.models.common import SplitLinear, TorchLinear
 
 
 def _safe_sqrt(x):
@@ -95,63 +95,6 @@ class E_GCL(nn.Module):
         y_snd = pre.piece(x, 0)  # sender-side contribution [N, H]
         y_rcv = pre.piece(x, in_dim) + pre.bias  # receiver side + bias
         w_rad = pre.kernel[2 * in_dim]  # [H] radial row
-
-        # ---- fully fused edge phase (ops/fused_mp.py, autotuner/env
-        # opt-in): radial + two-layer edge MLP (+ the equivariant coord
-        # update) + the packed sender-side aggregation run as ONE Pallas
-        # kernel — the [E, H] edge intermediate never exists in HBM.
-        # Parameters are declared through SplitLinear under the SAME
-        # names/shapes/init as the unfused TorchLinear path, so
-        # checkpoints and seeded trajectories are unchanged.
-        if (
-            not dense
-            and self.partition_axis is None
-            and fused_site(
-                "EGNN",
-                n,
-                row.shape[0],
-                self.hidden_dim + 3,
-                self.hidden_dim + (4 if self.equivariant else 1),
-                table_dim_b=self.hidden_dim + 3,
-            )
-        ):
-            from hydragnn_tpu.ops import fused_egnn_edge_phase
-
-            lin1 = SplitLinear(
-                features=self.hidden_dim, fan_in=self.hidden_dim,
-                name="edge_mlp_1",
-            )
-            edge_params = [w_rad, lin1.kernel, lin1.bias]
-            if self.equivariant:
-                cm0 = SplitLinear(
-                    features=self.hidden_dim, fan_in=self.hidden_dim,
-                    name="coord_mlp_0",
-                )
-                small = nn.initializers.variance_scaling(
-                    0.001 * 0.001 / 3.0, "fan_avg", "uniform"
-                )
-                cm1 = self.param(
-                    "coord_mlp_1", small, (self.hidden_dim, 1)
-                )
-                edge_params += [cm0.kernel, cm0.bias, cm1]
-            ze = (
-                pre.piece(batch.edge_attr, 2 * in_dim + 1)
-                if self.edge_attr_dim > 0
-                else None
-            )
-            out = fused_egnn_edge_phase(
-                y_snd, y_rcv, pos, edge_params, row, col, n,
-                batch.edge_mask, ze=ze,
-            )
-            agg = out[:, : self.hidden_dim].astype(x.dtype)
-            if self.equivariant:
-                coord_agg = out[:, self.hidden_dim : self.hidden_dim + 3]
-                cnt = out[:, -1]
-                pos = pos + coord_agg / jnp.maximum(cnt, 1.0)[:, None]
-            h = jnp.concatenate([x, agg], axis=-1)
-            h = jax.nn.relu(TorchLinear(self.hidden_dim, name="node_mlp_0")(h))
-            h = TorchLinear(self.out_dim, name="node_mlp_1")(h)
-            return h, pos
 
         if dense:
             # dense scatter-free frame: per-edge values live as [N, K, *]

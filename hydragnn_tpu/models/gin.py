@@ -26,11 +26,9 @@ class GINConv(nn.Module):
             x_j = neighbor_rows(x, extras)
             aggr = dense_sum(x_j, extras["nbr_mask"])
         else:
-            # gather+mask+reduce through the one shared helper: XLA
-            # segment path or the fused Pallas kernel (autotuner/env)
             aggr = gather_segment_sum(
                 x, batch.senders, batch.receivers, x.shape[0],
-                batch.edge_mask, model_key="GIN",
+                batch.edge_mask,
             )
         h = (1.0 + eps) * x + aggr
         h = TorchLinear(self.out_dim, name="mlp_0")(h)

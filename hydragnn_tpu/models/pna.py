@@ -20,7 +20,7 @@ from flax import linen as nn
 
 from hydragnn_tpu.graph import segment_minmax_fused, segment_moments_fused
 from hydragnn_tpu.models.base import HydraBase
-from hydragnn_tpu.models.common import SplitLinear, TorchLinear, fused_site
+from hydragnn_tpu.models.common import SplitLinear, TorchLinear
 
 
 def pna_degree_averages(deg_histogram) -> Tuple[float, float]:
@@ -94,54 +94,18 @@ class PNAConv(nn.Module):
             z = jnp.where(nbr_mask[..., None], z, 0.0)
             mean_z, std, deg, has = dense_moments(z, nbr_mask)
             mn_z, mx_z = dense_minmax(z, nbr_mask, has)
-        elif fused_site(
-            "PNA", n, batch.senders.shape[0], self.in_dim,
-            2 * self.in_dim + 1,
-        ):
-            # fully fused statistics pass (ops/fused_mp.py, autotuner/env
-            # opt-in): gather yj at senders, add the encoded edge, mask,
-            # and reduce (sum, count, sum-of-squares) at receivers in ONE
-            # kernel; the per-edge z comes back from the same pass so the
-            # min/max scatter below needs no second gather
-            from hydragnn_tpu.ops import fused_gather_moments
-
-            s, cnt, sq, z = fused_gather_moments(
-                yj, batch.senders, batch.receivers, n, batch.edge_mask,
-                ze=ze,
-            )
-            # back to the caller's dtype (the kernel accumulates f32):
-            # under bf16 mixed precision the downstream scalers/concat
-            # must not silently promote the whole conv to f32 — cnt
-            # included, or deg drags mean_z/std (and the concat tail)
-            # back up to f32
-            s, cnt, sq, z = (a.astype(yj.dtype) for a in (s, cnt, sq, z))
-            has = cnt > 0
-            deg = jnp.maximum(cnt, 1.0)
-            mean_z = s / deg
-            std = jnp.sqrt(
-                jnp.maximum(sq / deg - mean_z * mean_z, 0.0) + 1e-5
-            )
-            mn_z, mx_z = segment_minmax_fused(z, batch.receivers, n, has=has)
         else:
             z = yj[batch.senders]  # [E, D]
             if ze is not None:
                 z = z + ze
             z = jnp.where(batch.edge_mask[:, None], z, 0.0)
 
-            from hydragnn_tpu.ops import (
-                pallas_segments_enabled,
-                segment_moments,
+            # mean/std/degree from ONE packed scatter over z (padded edges
+            # target the padding node / carry zero weight, so real-node
+            # stats are untouched)
+            s, cnt, sq = segment_moments_fused(
+                z, batch.receivers, n, weights=batch.edge_mask
             )
-
-            # mean/std/degree from ONE pass over z — pallas kernel or the
-            # packed-scatter XLA fallback (padded edges target the padding
-            # node / carry zero weight, so real-node stats are untouched)
-            if pallas_segments_enabled(n, z.shape[1], n_outputs=2):
-                s, cnt, sq = segment_moments(z, batch.receivers, n)
-            else:
-                s, cnt, sq = segment_moments_fused(
-                    z, batch.receivers, n, weights=batch.edge_mask
-                )
             has = cnt > 0
             deg = jnp.maximum(cnt, 1.0)
             mean_z = s / deg

@@ -26,12 +26,10 @@ class SAGEConv(nn.Module):
             deg = nmask.sum(axis=1).astype(x.dtype)
             aggr = dense_sum(x_j, nmask) / jnp.maximum(deg, 1.0)[:, None]
         else:
-            # mean over real incoming edges only (sum / real degree),
-            # through the shared helper: XLA segment path or the fused
-            # Pallas kernel (autotuner/env decision)
+            # mean over real incoming edges only (sum / real degree)
             aggr = gather_segment_mean(
                 x, batch.senders, batch.receivers, x.shape[0],
-                batch.edge_mask, model_key="SAGE",
+                batch.edge_mask,
             )
         out = TorchLinear(self.out_dim, name="lin_l")(aggr) + TorchLinear(
             self.out_dim, use_bias=False, name="lin_r"

@@ -182,12 +182,8 @@ class CFConv(nn.Module):
             h_j = neighbor_rows(h, extras)
             aggr = dense_sum(h_j * w, nmask)
         else:
-            # continuous-filter aggregation through the shared helper: XLA
-            # gather-multiply-scatter or the fused Pallas kernel
-            # (autotuner/env decision); w is already edge-masked above
-            aggr = gather_weighted_segment_sum(
-                h, w, send, recv, n, model_key="SchNet"
-            )
+            # continuous-filter aggregation; w is already edge-masked above
+            aggr = gather_weighted_segment_sum(h, w, send, recv, n)
         lin2 = self.param("lin2", glorot, (self.num_filters, self.out_dim))
         bias2 = self.param("bias2", nn.initializers.zeros, (self.out_dim,))
         out = aggr @ lin2 + bias2

@@ -62,17 +62,12 @@ EVENT_FIELDS: Dict[str, tuple] = {
     # blocked past the threshold; threads carries every thread's held
     # locks + stack at the moment of the dump
     "deadlock_suspect": ("lock", "waited_s", "threads"),
-    # aggregation autotuner (ops/autotune.py): which kernel family one
-    # bucket layout uses and why — source is env|cache|measured, layout
-    # (the family the batch layout committed to, models/base.py) or guard
-    # (a requested kernel the VMEM guard sent to XLA); optional timings_ms
-    # carries the measured candidate times. source "operands" is the dense
-    # path's neighbour gather (ops/dense_agg.py): gather = choice =
-    # onehot|xla, h the window's halo in blocks
+    # aggregation reporting (ops/agg_policy.py): source "layout" is the
+    # family (segment|dense) the batch layout committed one bucket to
+    # (models/base.py); source "operands" is the dense path's neighbour
+    # gather (ops/dense_agg.py): gather = choice = onehot|xla, h the
+    # window's halo in blocks
     "agg_choice": ("bucket", "choice", "source"),
-    # on a TPU an autotune probe that fails to compile or run is an error;
-    # this records the compiler's message before it propagates
-    "agg_probe_failed": ("bucket", "candidate", "error"),
     # elastic training (train/elastic.py): a peer's heartbeat lease
     # expired — emitted by the detecting watchdog just before it breaks
     # the survivors out of the hung collective

@@ -156,7 +156,7 @@ class TrainingMetrics:
             "hbm_peak_bytes",
             "Compiled-program peak memory (arg+out+temp-aliased)",
         )
-        # aggregation autotuner (ops/autotune.py): 1 on the (bucket,
+        # aggregation family (ops/agg_policy.py): 1 on the (bucket,
         # choice) label set each bucket actually uses
         r.labeled_gauge(
             "aggregation_kernel",

@@ -1,0 +1,199 @@
+"""The aggregation family rule, and the reporting of what ran.
+
+Two aggregation families serve the message-passing hot path:
+
+- **segment**: ``jax.ops.segment_*`` scatters over the edge list
+  (``graph/segment.py``; XLA fuses them with the surrounding elementwise
+  work);
+- **dense**: host-built fixed-width neighbor lists, scatter-free masked
+  K-axis reductions (``ops/dense_agg.py``), whose neighbour gather is a
+  block-local one-hot product where the operands allow it
+  (``ops/local_gather.py window_halo``).
+
+The family is a LAYOUT decision (the loader builds neighbor lists or it
+does not), made once per run by :func:`needs_dense_neighbors`: partitioned
+-> segment; an explicit ``Architecture.dense_aggregation`` -> that; else
+the static width tables below. Nothing else steers it: no environment
+name, no file.
+
+What ran is emitted as ``agg_choice`` obs events (schema in
+``obs/events.py``) and an ``aggregation_kernel`` labeled gauge: the family
+the batch layout committed to (source ``layout``, emitted by
+``models/base.py`` at trace time) and the neighbour gather's
+implementation (source ``operands``, ``ops/dense_agg.py``).
+"""
+
+CHOICES = ("segment", "dense")
+
+# Dense/segment crossovers measured on a v5e before the current tree
+# (2026-07/08, same-session A/Bs at deg ~12; not re-measured since, nor
+# after PR 27 changed the dense path's cost — ROADMAP D11): minimum
+# hidden_dim at which the dense scatter-free path beats segment reductions
+# for each model. Scatter-heavy models (PNA's 4 aggregators, GAT's edge
+# softmax, MFC's degree banks, DimeNet's triplet axis) cross early;
+# GIN/SAGE only win mildly at MXU widths; SchNet and EGNN never do (one
+# already-fused scatter per layer).
+DENSE_AUTO_MIN_HIDDEN = {
+    "PNA": 96,
+    "GAT": 96,
+    "MFC": 96,
+    "DimeNet": 96,
+    "GIN": 192,
+    "SAGE": 192,
+    # CGCNN absent from THIS table: its convs run at input_dim width
+    # (constant-width CGConv), so hidden_dim says nothing about where it
+    # sits relative to the crossover — it gets its own rule below.
+}
+
+# CGCNN's crossover keyed on its TRUE conv width (round-4 verdict item 8,
+# measured round 5 at OC20 shape): INVERSE to the hidden-width table —
+# dense gathers [N, K, input_dim] blocks, so gather traffic grows with
+# input width while the segment scatter cost stays flat. Maximum input_dim
+# at which the dense path is picked automatically.
+DENSE_AUTO_MAX_INPUT_DIM = {
+    "CGCNN": 64,
+}
+
+
+def auto_dense_aggregation(arch_config: dict) -> bool:
+    """The measured-crossover policy: dense iff the (model type, width)
+    point sits on the dense-winning side of the tables above. Width is
+    hidden_dim for most stacks; CGCNN's constant-width convs key on
+    input_dim instead — and inversely. Absent/0 input_dim stays
+    conservative: segment."""
+    mt = arch_config.get("model_type")
+    th_in = DENSE_AUTO_MAX_INPUT_DIM.get(mt)
+    if th_in is not None:
+        dim = int(arch_config.get("input_dim") or 0)
+        return 1 <= dim <= th_in
+    th = DENSE_AUTO_MIN_HIDDEN.get(mt)
+    return th is not None and int(arch_config.get("hidden_dim") or 0) >= th
+
+
+def static_aggregation_choice(arch_config: dict) -> str:
+    """The tables' choice for a model config: what bench.py records as
+    ``auto_choice``."""
+    return "dense" if auto_dense_aggregation(arch_config) else "segment"
+
+
+def arch_for_auto_policy(nn_config: dict) -> dict:
+    """Architecture dict enriched with ``input_dim`` (CGCNN's crossover
+    key) derived from ``Variables_of_interest.input_node_features`` when
+    the config predates ``update_config`` — ONE derivation shared by every
+    entry point so their dense/segment decisions cannot diverge."""
+    arch = nn_config["Architecture"]
+    feats = nn_config.get("Variables_of_interest", {}).get(
+        "input_node_features"
+    )
+    if feats and "input_dim" not in arch:
+        return dict(arch, input_dim=len(feats))
+    return arch
+
+
+def needs_dense_neighbors(arch_config: dict) -> bool:
+    """Single rule for dense scatter-free aggregation in the BATCH-collate
+    path: an explicit ``dense_aggregation`` true/false, else the width
+    tables. Off under graph partitioning — there the partitioner builds
+    per-shard lists itself (``partition_graph(need_neighbors=True)``,
+    wired by the driver)."""
+    if arch_config.get("partition_axis"):
+        return False
+    flag = arch_config.get("dense_aggregation")
+    if flag is not None:
+        return bool(flag)
+    return auto_dense_aggregation(arch_config)
+
+
+# ---------------------------------------------------------------------------
+# bucket signatures
+# ---------------------------------------------------------------------------
+
+_STACK_KEYS = {
+    "PNAStack": "PNA",
+    "GINStack": "GIN",
+    "GATStack": "GAT",
+    "MFCStack": "MFC",
+    "SAGEStack": "SAGE",
+    "CGCNNStack": "CGCNN",
+    "SCFStack": "SchNet",
+    "EGCLStack": "EGNN",
+    "DIMEStack": "DimeNet",
+}
+
+
+def model_key_for(model) -> str:
+    """Short model key ("PNA", "SchNet", ...) from a stack instance."""
+    name = type(model).__name__
+    return _STACK_KEYS.get(name, name.replace("Stack", ""))
+
+
+def bucket_signature(model_key: str, num_nodes: int, num_edges: int,
+                     dim: int) -> str:
+    """One bucket layout's identity: padded node/edge counts + feature
+    width + model: exactly the statics a compiled program is specialized
+    on."""
+    return f"{model_key}/n{int(num_nodes)}/e{int(num_edges)}/d{int(dim)}"
+
+
+# ---------------------------------------------------------------------------
+# observability
+# ---------------------------------------------------------------------------
+
+def emit_choice(signature: str, choice: str, source: str, **extra):
+    """One ``agg_choice`` event + ``aggregation_kernel`` gauge per novel
+    (signature, choice, source) PER TELEMETRY RUN — deduplicated so
+    per-trace re-decisions don't spam the stream. The dedup set lives ON
+    the active RunTelemetry (not process-global, and not keyed by id() —
+    a GC'd run's address gets reused), so every run's events.jsonl
+    stands alone; with no run active there is nothing to emit. ``extra``
+    fields ride on the event (the neighbour gather's ``gather`` / ``h``,
+    ``ops/dense_agg.py``); a choice outside ``CHOICES`` names no kernel
+    family and sets no gauge."""
+    from hydragnn_tpu.obs import runtime as obs_rt
+
+    run = obs_rt.active()
+    if run is None:
+        return
+    emitted = getattr(run, "_agg_choice_emitted", None)
+    if emitted is None:
+        emitted = set()
+        run._agg_choice_emitted = emitted
+    key = (signature, choice, source)
+    if key in emitted:
+        return
+    emitted.add(key)
+    try:
+        obs_rt.emit(
+            "agg_choice", bucket=signature, choice=choice, source=source,
+            **extra,
+        )
+        if choice not in CHOICES:
+            return
+        # exactly ONE choice label reads 1 per bucket
+        for c in CHOICES:
+            run.metrics.registry.set_labeled(
+                "aggregation_kernel",
+                1.0 if c == choice else 0.0,
+                bucket=signature,
+                choice=c,
+            )
+    except Exception:
+        pass
+
+
+def emit_layout_choice(model, batch):
+    """Report the aggregation family the batch LAYOUT committed this
+    bucket to: ``dense`` when the loader built neighbor lists (every
+    dense-capable conv then takes its scatter-free branch), else
+    ``segment``. Called once per traced forward (``models/base.py``), so
+    the decision enacted at layout time — before any telemetry run
+    exists — still shows up as the path that ran."""
+    dense = "nbr_idx" in (batch.extras or {})
+    emit_choice(
+        bucket_signature(
+            model_key_for(model), batch.x.shape[0], batch.senders.shape[0],
+            model.hidden_dim,
+        ),
+        "dense" if dense else "segment",
+        "layout",
+    )

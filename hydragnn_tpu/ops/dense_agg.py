@@ -233,7 +233,7 @@ def gather_neighbors(
 
     The choice is reported as an ``agg_choice`` event (``gather``, ``h``).
     """
-    from hydragnn_tpu.ops.autotune import emit_choice
+    from hydragnn_tpu.ops.agg_policy import emit_choice
     from hydragnn_tpu.ops.local_gather import window_halo
 
     (n, d), k_in = x.shape, nbr_idx.shape[1]

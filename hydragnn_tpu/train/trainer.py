@@ -163,13 +163,6 @@ class Trainer(PredictMixin):
             # only on shapes and the seed, so every process derives identical
             # values (flax init cannot trace non-addressable global shards)
             init_batch = jax.tree_util.tree_map(jnp.asarray, example_batch)
-        # aggregation autotune warmup (ops/autotune.py, opt-in via
-        # HYDRAGNN_AUTOTUNE / Training.autotune_aggregation): measure the
-        # example bucket's candidates BEFORE anything traces, so the
-        # models' trace-time choice reads a warm cache
-        from hydragnn_tpu.ops.autotune import maybe_autotune
-
-        maybe_autotune(self.model, example_batch, self.training_config)
         variables = init_model_params(self.model, init_batch, seed=seed)
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})

@@ -49,15 +49,13 @@ def update_config(config, train_loader, val_loader, test_loader):
     else:
         arch["pna_deg"] = None
     if "dense_aggregation" not in arch and not arch.get("partition_axis"):
-        # record the AUTO aggregation-path decision so the saved config
-        # and downstream consumers see the value THE RUN ACTUALLY USES —
-        # needs_dense_neighbors resolves every tier (HYDRAGNN_AGG env
-        # force > autotuner cache > measured-crossover static policy), so
-        # a resume without the env var cannot silently flip the layout
-        # mid-experiment; an explicit true/false in the input config
-        # always wins, and partition mode keeps its own explicit opt-in
-        # (per-shard lists change the memory equation)
-        from hydragnn_tpu.data.loaders import needs_dense_neighbors
+        # record the aggregation-family decision so the saved config and
+        # downstream consumers see the value THE RUN ACTUALLY USES. The
+        # rule has two steps: an explicit true/false in the input config
+        # wins, else the width tables decide (ops/agg_policy.py). Partition
+        # mode keeps its own explicit opt-in (per-shard lists change the
+        # memory equation)
+        from hydragnn_tpu.ops.agg_policy import needs_dense_neighbors
 
         arch["dense_aggregation"] = needs_dense_neighbors(arch)
     if arch["model_type"] == "MFC":

@@ -1,10 +1,11 @@
 """Ask the TPU's compiler before spending chip time.
 
 The v5e compiler is installed here and compiles for a chip that is
-DESCRIBED, not attached (``topologies.get_topology_desc``): every Pallas
-kernel of ``ops/`` (forward and grad) at the largest shape its VMEM guard
-admits, and the whole jitted train step of ``chip_smoke.py`` from shapes.
-A guard that admits a shape the compiler refuses is a bug in the guard.
+DESCRIBED, not attached (``topologies.get_topology_desc``): the Pallas
+kernels of ``ops/local_gather.py`` (forward and grad) at the edges of what
+``window_halo`` admits, and the whole jitted train step of
+``chip_smoke.py`` from shapes. A rule that admits a shape the compiler
+refuses is a bug in the rule.
 Nothing runs, so this says nothing about results or times. Skipped where
 the topology cannot be described.
 
@@ -24,14 +25,10 @@ import pytest
 
 import chip_smoke as cs
 from hydragnn_tpu.ops import dense_agg as da
-from hydragnn_tpu.ops import fused_mp as fm
 from hydragnn_tpu.ops import local_gather as lg
-from hydragnn_tpu.ops import pallas_segment as ps
 
 V5E_HBM_BYTES = 16 * 1024**3
 KERNEL = 'custom_call_target="tpu_custom_call"'
-# the smoke's own buckets (64 slabs of 80-90 atoms, in-degree 12)
-SMOKE_NODES, SMOKE_DIM = 5624, 256
 
 
 @pytest.fixture(scope="module")
@@ -57,14 +54,6 @@ def chip():
     cc.reset_cache()
 
 
-@pytest.fixture
-def compiled_kernels(monkeypatch):
-    """The ops ask ``jax.devices()`` (the CPU here) whether to interpret;
-    the compile must steer ``interpret=False`` itself."""
-    monkeypatch.setattr(ps, "_interpret", lambda requested: False)
-    monkeypatch.setattr(fm, "_interpret", lambda requested: False)
-
-
 def _shape(chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
@@ -80,105 +69,6 @@ def _compile_fwd_and_grad(fn, n_diff, args):
 
     step = jax.value_and_grad(loss, argnums=tuple(range(n_diff)))
     return jax.jit(step).lower(*args).compile().as_text()
-
-
-def _largest_admitted(admits):
-    """Largest multiple of 8 for which ``admits(n)`` holds."""
-    lo, hi = 8, 1 << 20
-    assert admits(lo) and not admits(hi)
-    while hi - lo > 8:
-        mid = (lo + hi) // 16 * 8
-        lo, hi = (mid, hi) if admits(mid) else (lo, mid)
-    return lo
-
-
-# ---- one-hot segment kernels (ops/pallas_segment.py) ------------------------
-
-
-@pytest.mark.parametrize("kernel,n_outputs", [("sum", 1), ("moments", 2)])
-def pytest_segment_kernel_compiles_at_guard_max(
-    chip, compiled_kernels, monkeypatch, kernel, n_outputs
-):
-    monkeypatch.setenv("HYDRAGNN_PALLAS", "1")
-    dim = SMOKE_DIM
-    n = _largest_admitted(
-        lambda n: ps.pallas_segments_enabled(n, dim, n_outputs)
-    )
-    op = ps.segment_sum_onehot if kernel == "sum" else ps.segment_moments
-    text = _compile_fwd_and_grad(
-        lambda data, ids: op(data, ids, n),
-        1,
-        (_shape(chip, (12 * n, dim)), _shape(chip, (12 * n,), jnp.int32)),
-    )
-    assert KERNEL in text
-    # the shape the v5e compiler refuses for segment_moments (scoped VMEM
-    # 17.08M > 16M at 5760 segments x 256) and the smoke's own buckets lie
-    # outside the guard by construction: they run on XLA
-    assert not ps.pallas_segments_enabled(5760, 256, n_outputs=2)
-    assert not ps.pallas_segments_enabled(SMOKE_NODES, dim, n_outputs)
-
-
-# ---- fused message-passing kernels (ops/fused_mp.py) ------------------------
-
-
-def _fused_case(chip, op, n, dim):
-    """(fn, number of differentiable args, arg shapes) for one wrapper."""
-    e = 12 * n
-    ids = _shape(chip, (e,), jnp.int32)
-    mask = _shape(chip, (e,), jnp.bool_)
-    table = _shape(chip, (n, dim))
-    if op == "sum":
-        return (lambda x, s, r, m: fm.fused_gather_sum(x, s, r, n, m),
-                1, (table, ids, ids, mask))
-    if op == "mean":
-        return (lambda x, s, r, m: fm.fused_gather_mean(x, s, r, n, m),
-                1, (table, ids, ids, mask))
-    if op == "weighted_sum":
-        return (lambda h, w, s, r: fm.fused_gather_weighted_sum(h, w, s, r, n),
-                2, (table, _shape(chip, (e, dim)), ids, ids))
-    if op == "moments":  # with the encoded-edge term: ef is [E, D + 1]
-        return (lambda y, z, s, r, m: fm.fused_gather_moments(
-                    y, s, r, n, m, ze=z),
-                2, (table, _shape(chip, (e, dim)), ids, ids, mask))
-    assert op == "egnn"  # equivariant: all six edge-MLP parameters
-    params = tuple(
-        _shape(chip, s) for s in
-        ((1, dim), (dim, dim), (dim,), (dim, dim), (dim,), (dim, 1))
-    )
-
-    def egnn(ys, yr, pos, *rest):
-        *p, s, r, m = rest
-        return fm.fused_egnn_edge_phase(ys, yr, pos, p, s, r, n, m)
-
-    return (egnn, 3 + len(params),
-            (table, table, _shape(chip, (n, 3)), *params, ids, ids, mask))
-
-
-# (table_dim, out_dim, table_dim_b) of each wrapper, as the models pass them
-_FUSED_DIMS = {
-    "sum": lambda d: (d, d, 0),
-    "mean": lambda d: (d, d + 1, 0),
-    "weighted_sum": lambda d: (d, d, 0),
-    "moments": lambda d: (d, 2 * d + 1, 0),
-    "egnn": lambda d: (d + 3, d + 4, d + 3),
-}
-
-
-@pytest.mark.parametrize(
-    "op,dim",
-    [(op, SMOKE_DIM) for op in _FUSED_DIMS]
-    # the two packings whose widths are not lane multiples, narrow too
-    + [("moments", 64), ("egnn", 64)],
-)
-def pytest_fused_kernel_compiles_at_guard_max(
-    chip, compiled_kernels, op, dim
-):
-    td, od, tdb = _FUSED_DIMS[op](dim)
-    n = _largest_admitted(lambda n: fm.fused_mp_enabled(n, n, td, od, tdb))
-    fn, n_diff, args = _fused_case(chip, op, n, dim)
-    assert KERNEL in _compile_fwd_and_grad(fn, n_diff, args)
-    # the smoke's buckets are past every fused guard: XLA runs them
-    assert not fm.fused_mp_enabled(SMOKE_NODES, SMOKE_NODES, td, od, tdb)
 
 
 # ---- block-local neighbour gather (ops/local_gather.py) ---------------------
@@ -279,22 +169,6 @@ def pytest_smoke_train_step_compiles_full_width(
         mem.argument_size_in_bytes + mem.output_size_in_bytes
         + mem.temp_size_in_bytes
     ) < V5E_HBM_BYTES // 4
-
-
-def pytest_smoke_train_step_compiles_with_fused_kernels(
-    chip, compiled_kernels, tmp_path, monkeypatch
-):
-    """Same width, forced onto the fused family at a bucket its guard
-    admits (16 slabs): the kernels compile inside the complete step."""
-    monkeypatch.setenv("HYDRAGNN_AGG", "fused")
-    trainer, state, batch = _smoke_train_step(
-        tmp_path, monkeypatch, dict(train_graphs=16, batch=16)
-    )
-    n = batch.x.shape[0]
-    assert "nbr_idx" not in (batch.extras or {})
-    assert fm.fused_mp_enabled(n, n, 256, 513)
-    compiled = _compile_train_step(chip, trainer, state, batch)
-    assert KERNEL in compiled.as_text()
 
 
 # ---- start-up path, on the CPU ----------------------------------------------

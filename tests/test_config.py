@@ -140,10 +140,10 @@ def pytest_update_config_pna_degree_histogram():
 
 
 def pytest_auto_dense_aggregation_policy():
-    """The measured-crossover policy (ops/autotune.py): scatter-heavy models
+    """The measured-crossover policy (ops/agg_policy.py): scatter-heavy models
     pick the dense path at MXU widths with NO config flag; SchNet/EGNN
     never do; an explicit flag and partition mode always win."""
-    from hydragnn_tpu.data.loaders import needs_dense_neighbors
+    from hydragnn_tpu.ops.agg_policy import needs_dense_neighbors
 
     for m in ("PNA", "GAT", "MFC", "DimeNet"):
         assert needs_dense_neighbors({"model_type": m, "hidden_dim": 256})

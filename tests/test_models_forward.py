@@ -217,3 +217,33 @@ def pytest_egnn_fused_dense_edge_attr_matches_segment():
     h_dense, pos_dense = conv.apply(variables, x, pos, dense_batch)
     np.testing.assert_allclose(h_dense, h_seg, atol=2e-5, rtol=1e-5)
     np.testing.assert_allclose(pos_dense, pos_seg, atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "model_type,equivariant,n_leaves,names,abs_sum",
+    [
+        ("PNA", False, 32, "7a1d9d911af1ab86", 180.57219943185555),
+        ("EGNN", False, 32, "810e152fad1155af", 162.90563737403136),
+        ("EGNN", True, 35, "fa507d4a665d0d9f", 176.556326065358),
+    ],
+)
+def pytest_parameter_tree_as_recorded_at_72f8b5d(
+    model_type, equivariant, n_leaves, names, abs_sum
+):
+    """Leaf paths, shapes and seeded values of the two stacks that lost a
+    kernel branch in PR 28, against what commit 72f8b5d initialised from
+    the same seed: checkpoints and seeded trajectories carry over."""
+    import hashlib
+
+    cfg = arch_config(model_type)
+    cfg["equivariance"] = equivariant
+    params = init_model_params(create_model_config(cfg), make_batch())["params"]
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    listed = "\n".join(
+        f"{jax.tree_util.keystr(path)} {tuple(leaf.shape)}"
+        for path, leaf in leaves
+    )
+    assert len(leaves) == n_leaves
+    assert hashlib.sha256(listed.encode()).hexdigest()[:16] == names, listed
+    total = sum(np.abs(np.asarray(a, np.float64)).sum() for _, a in leaves)
+    np.testing.assert_allclose(total, abs_sum, rtol=1e-9)

@@ -443,7 +443,7 @@ def pytest_list_rules_includes_numerics(capsys):
 def pytest_merged_tree_is_clean_for_numerics_suite():
     """`--suite=numerics` exits 0 on the committed tree: every true
     positive (unclamped exp in schnet, bare sqrt in dimenet/common,
-    bf16-reachable accumulations in dense_agg/fused_mp) was FIXED, the
+    bf16-reachable accumulations in dense_agg) was FIXED, the
     two deliberate raw gathers carry justified suppressions, and the
     committed baseline is EMPTY."""
     paths = [
