@@ -5,7 +5,8 @@ backward, on one TPU chip. The reading ``MAX_WINDOW_TILES`` was set from
 
     python benchmarks/gather_crossover.py [--out chiprun_out/gather_crossover.jsonl]
 
-Two sweeps, every line a JSON object on stdout (and in ``--out``):
+Three sweeps, every line a JSON object on stdout (and in ``--out``;
+``--sweep`` runs some of them):
 
 - ``bucket``: the four buckets of the benchmark's PNA cell (padded rows,
   largest graph), 12 slots a row, at the widths its three layers gather
@@ -13,6 +14,14 @@ Two sweeps, every line a JSON object on stdout (and in ``--out``):
   interpreter: real slots equal bit for bit, cotangents within a bf16 ulp.
 - ``window``: the product alone at one bucket's rows for halos 1-4 and
   widths 128-512: its cost per index and per [128 x 128] tile of window.
+- ``egnn``: the four buckets of the benchmark's EGNN cell laid out dense,
+  16 slots a row: E_GCL's gather (128 bf16 columns + 3 f32 positions: 137
+  through the product, the positions as pieces, against XLA's gather of
+  the f32 table of 131) and its sender sum (128 + 3 f32 translations + the
+  count: 140 against 132), forward and backward, ns an index. With
+  ``benchmarks/egnn_family_ab.py`` (the whole step) the reading behind
+  ``ops/agg_policy.py DENSE_AUTO_MIN_HIDDEN["EGNN"]`` (PERF.md section 6,
+  PR 29).
 
 Fails off a TPU: a CPU timing of either side says nothing.
 """
@@ -38,11 +47,14 @@ K_IN = 12
 TABLE = jnp.bfloat16
 # (padded rows, largest graph) of pna_h256_train_oc20's buckets
 BUCKETS = [(24488, 61), (39432, 93), (57592, 137), (88648, 225)]
+# the same of egnn_h128x7_train_mptrj under dense lists, 16 slots a row
+EGNN_BUCKETS = [(7480, 29), (14896, 51), (24984, 89), (44336, 200)]
+EGNN_K, EGNN_HIDDEN = 16, 128
 
 
-def block_diagonal_lists(n, reach, rng):
+def block_diagonal_lists(n, reach, rng, k=K_IN):
     """Dense lists of a collated batch: graphs of reach/3..reach rows laid
-    down contiguously, every row with ``K_IN`` senders from its own graph
+    down contiguously, every row with ``k`` senders from its own graph
     (the cell's degree cap binds almost everywhere)."""
     sizes = [reach]  # the bound is met
     while sum(sizes) < n - 1 - reach:
@@ -51,7 +63,7 @@ def block_diagonal_lists(n, reach, rng):
     size_of = np.repeat(sizes, sizes)
     first_of = np.repeat(start, sizes)
     rows = first_of.shape[0]
-    recv = np.repeat(np.arange(rows), K_IN)
+    recv = np.repeat(np.arange(rows), k)
     send = first_of[recv] + rng.integers(0, 2**31, recv.shape[0]) % size_of[recv]
     k_in, k_out = da.max_degree(send, recv)
     return da.build_neighbor_lists(send, recv, None, n, k_in, k_out)
@@ -107,6 +119,66 @@ def bucket_reading(n, reach, dim, rng):
     return line
 
 
+def egnn_reading(n, reach, rng):
+    """E_GCL's two calls on one bucket, through ``dense_agg``'s own entry
+    points: the batch stating its reach (products) against the same batch
+    stating nothing (XLA's gathers)."""
+    lists = block_diagonal_lists(n, reach, rng, EGNN_K)
+    silent = {k: jnp.asarray(v) for k, v in lists.items()}
+    stated = dict(silent, nbr_reach=jnp.zeros(reach, jnp.int8))
+    m = np.asarray(silent["nbr_mask"])[..., None]
+    d = EGNN_HIDDEN
+    y = jnp.asarray(rng.standard_normal((n, d)), TABLE)
+    pos = jnp.asarray(rng.standard_normal((n, 3)) * 20, jnp.float32)
+    e = jnp.asarray(rng.standard_normal((n, EGNN_K, d)) * m, TABLE)
+    trans = jnp.asarray(rng.standard_normal((n, EGNN_K, 4)) * m, jnp.float32)
+    g_rows = (e, trans[..., :3])  # cotangents of the gather's two results
+    g_sums = (e[:, 0], trans[:, 0])  # and of the sender sum's
+
+    def calls(ex):
+        gather = lambda t, p: da.neighbor_rows(t, ex, exact=p)  # noqa: E731
+        sums = lambda a, t: da.sender_sums(a, ex, exact=t)  # noqa: E731
+        return {
+            "gather_fwd": (jax.jit(gather), (y, pos)),
+            "gather_bwd": (jax.jit(lambda t, p, g: jax.vjp(gather, t, p)[1](g)),
+                           (y, pos, g_rows)),
+            "sum_fwd": (jax.jit(sums), (e, trans)),
+            "sum_bwd": (jax.jit(lambda a, t, g: jax.vjp(sums, a, t)[1](g)),
+                        (e, trans, g_sums)),
+        }
+
+    xla, one = calls(silent), calls(stated)
+    out = {k: (xla[k][0](*xla[k][1]), one[k][0](*one[k][1])) for k in xla}
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    gap = lambda a, b: float(np.max(  # noqa: E731
+        np.abs(f32(a) - f32(b)) / np.maximum(np.abs(f32(b)), 1.0)))
+    (ry, rp), (oy, op) = out["gather_fwd"]
+    line = {
+        "sweep": "egnn", "n": n, "reach": reach,
+        "h": lg._cdiv(reach - 1, lg.BLOCK), "k_in": EGNN_K,
+        "k_out": int(silent["rev_idx"].shape[1]),
+        "widths": {"gather": [d + 9, d + 3], "sum": [d + 12, d + 4]},
+        "rows_equal_on_real_slots": bool(
+            np.array_equal(np.where(m, f32(oy), 0), np.where(m, f32(ry), 0))),
+        "positions_bit_equal_on_real_slots": bool(
+            np.array_equal(np.where(m, f32(op), 0), np.where(m, f32(rp), 0))),
+        "gather_bwd_gap_ulps": gap(out["gather_bwd"][1][0], out["gather_bwd"][0][0]) * 2**7,
+        "gather_bwd_f32_gap": gap(out["gather_bwd"][1][1], out["gather_bwd"][0][1]),
+        "sum_gap_ulps": gap(out["sum_fwd"][1][0], out["sum_fwd"][0][0]) * 2**7,
+        "sum_f32_gap": gap(out["sum_fwd"][1][1], out["sum_fwd"][0][1]),
+        "sum_bwd_equal": bool(all(
+            np.array_equal(f32(a), f32(b))
+            for a, b in zip(out["sum_bwd"][1], out["sum_bwd"][0]))),
+    }
+    for side, table in (("xla", xla), ("onehot", one)):
+        for name, (fn, args) in table.items():
+            ms = device_ms(fn, *args)
+            line[f"{side}_{name}_ms"] = round(ms, 4)
+            line[f"{side}_{name}_ns_per_index"] = round(
+                ms * 1e6 / (n * EGNN_K), 3)
+    return line
+
+
 def window_reading(n, h, dim, rng):
     """The product alone; lists of reach 100 are valid for every halo."""
     ex = {k: jnp.asarray(v) for k, v in block_diagonal_lists(n, 100, rng).items()}
@@ -158,6 +230,8 @@ def einsum_reading(n, reach, dim, rng):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
+    ap.add_argument("--sweep", nargs="+", default=["bucket", "window", "egnn"],
+                    choices=["bucket", "window", "egnn"])
     args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -171,13 +245,18 @@ def main():
         lines.append(line)
         print(json.dumps(line), flush=True)
 
-    for n, reach in BUCKETS:
-        for dim in (256, 1):
-            emit(bucket_reading(n, reach, dim, rng))
-    emit(einsum_reading(*BUCKETS[1], 256, rng))
-    for dim, halos in ((128, (1, 2, 3, 4)), (256, (1, 2, 3, 4)), (512, (1, 2))):
-        for h in halos:
-            emit(window_reading(BUCKETS[1][0], h, dim, rng))
+    if "bucket" in args.sweep:
+        for n, reach in BUCKETS:
+            for dim in (256, 1):
+                emit(bucket_reading(n, reach, dim, rng))
+        emit(einsum_reading(*BUCKETS[1], 256, rng))
+    if "window" in args.sweep:
+        for dim, halos in ((128, (1, 2, 3, 4)), (256, (1, 2, 3, 4)), (512, (1, 2))):
+            for h in halos:
+                emit(window_reading(BUCKETS[1][0], h, dim, rng))
+    if "egnn" in args.sweep:
+        for n, reach in EGNN_BUCKETS:
+            emit(egnn_reading(n, reach, rng))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:

@@ -179,7 +179,18 @@ BF16_AUTO_MIN_HIDDEN = {
 
 
 def resolve_precision(model, training_config: dict) -> dict:
-    """The ONE mixed-precision decision point (steps.py consumes it).
+    """:func:`precision_for` a built stack (steps.py consumes it)."""
+    from hydragnn_tpu.ops.agg_policy import model_key_for
+
+    return precision_for(
+        model_key_for(model), getattr(model, "hidden_dim", 0), training_config
+    )
+
+
+def precision_for(model_key: str, hidden_dim, training_config: dict) -> dict:
+    """The ONE mixed-precision decision point, from what a config states
+    (the family rule of ``ops/agg_policy.py`` asks it before a model is
+    built: EGNN's dense side wins only in bf16).
 
     Master params always stay f32 for the optimizer; this resolves whether
     the forward/backward COMPUTE runs in bf16. Order:
@@ -195,19 +206,14 @@ def resolve_precision(model, training_config: dict) -> dict:
     """
     import os
 
-    from hydragnn_tpu.ops.agg_policy import model_key_for
-
     env = os.getenv("HYDRAGNN_MIXED_PRECISION")
     if env is not None and env.strip() != "":
         off = env.strip().lower() in ("0", "false", "no", "off")
         return {"mixed": not off, "source": "env"}
     flag = training_config.get("mixed_precision", False)
     if isinstance(flag, str) and flag.strip().lower() == "auto":
-        key = model_key_for(model)
-        th = BF16_AUTO_MIN_HIDDEN.get(key)
-        mixed = th is not None and int(
-            getattr(model, "hidden_dim", 0) or 0
-        ) >= th
+        th = BF16_AUTO_MIN_HIDDEN.get(model_key)
+        mixed = th is not None and int(hidden_dim or 0) >= th
         return {"mixed": mixed, "source": "policy"}
     return {
         "mixed": bool(flag),

@@ -33,6 +33,34 @@ def _safe_sqrt(x):
     return jnp.where(nonzero, jnp.sqrt(safe), 0.0)
 
 
+# The dense frame's geometry, one [N, K] plane a component: the chip lays a
+# [N, K, 3] array down with 3 of its 128 lanes in use, and every pass over
+# it costs what a pass over the messages costs. jit-wrapped, so the seven
+# layers trace (and differentiate) each body once.
+
+
+@jax.jit
+def _slot_geometry(pos_j, pos):
+    """``(radial [N, K], the three planes of (pos_j - pos_i) / (|.| + 1))``
+    of gathered sender positions ``pos_j [N, K, 3]``."""
+    diff = [pos_j[..., c] - pos[:, None, c] for c in range(3)]
+    radial = sum(d * d for d in diff)
+    norm = _safe_sqrt(radial) + 1.0  # norm_diff=True
+    return radial, tuple(d / norm for d in diff)
+
+
+@jax.jit
+def _slot_moves(coord_diff, cw, nmask):
+    """``[N, K, 4]`` f32: a slot's bounded translation (three planes
+    ``coord_diff`` times the weight ``cw [N, K]``) and its count, zero on
+    padded slots: what the sender sum takes beside the messages."""
+    planes = [
+        jnp.where(nmask, jnp.clip(d * cw, -100.0, 100.0), 0.0)
+        for d in coord_diff
+    ]
+    return jnp.stack(planes + [nmask.astype(planes[0].dtype)], axis=-1)
+
+
 class E_GCL(nn.Module):
     in_dim: int
     out_dim: int
@@ -52,23 +80,24 @@ class E_GCL(nn.Module):
             out = halo_reduce(out, batch.extras["halo_send"], self.partition_axis)
         return out
 
-    def _sender_sum_dense(self, data, extras, batch):
-        """Dense-frame sender aggregation: reverse-list sum
-        (ops/dense_agg.py), plus the partition halo fold."""
-        from hydragnn_tpu.ops.dense_agg import aggregate_to_senders
+    def _sender_sum_dense(self, data, exact, extras, batch):
+        """Dense-frame sender aggregation (``ops/dense_agg.py``): messages
+        ``data`` at their own dtype and, beside them, the f32 columns
+        ``exact`` (None on the last layer) summed in f32; plus the
+        partition halo fold, ONE for both."""
+        from hydragnn_tpu.ops.dense_agg import sender_sums
 
-        out = aggregate_to_senders(
-            data,
-            extras["nbr_idx"],
-            extras["nbr_mask"],
-            extras["rev_idx"],
-            extras["rev_mask"],
-        )
+        sums = sender_sums(data, extras, exact=exact)
+        out, moved = (sums, None) if exact is None else sums
         if self.partition_axis is not None:
             from hydragnn_tpu.parallel.graph_partition import halo_reduce
 
-            out = halo_reduce(out, batch.extras["halo_send"], self.partition_axis)
-        return out
+            d = out.shape[-1]
+            both = out if moved is None else jnp.concatenate([out, moved], -1)
+            both = halo_reduce(both, batch.extras["halo_send"], self.partition_axis)
+            out = both[:, :d].astype(out.dtype)
+            moved = None if moved is None else both[:, d:]
+        return out, moved
 
     @nn.compact
     def __call__(self, x, pos, batch, train: bool = False):
@@ -103,17 +132,17 @@ class E_GCL(nn.Module):
 
             nmask = extras["nbr_mask"]
             emask_nd = nmask[..., None]
-            # ONE fused gather for projected-features+positions (halves the
-            # gather / reverse-gather traffic — the dominant dense-mode cost)
-            both_j = neighbor_rows(
-                jnp.concatenate([y_snd, pos], axis=-1), extras
+            # ONE gather for projected features + positions. The messages
+            # run at the width the precision policy gave x and the
+            # parameters (bf16: the table is then bf16 and the gather a
+            # block-local product, ops/local_gather.py); positions and
+            # what is computed from them stay f32, bit for bit
+            y_j, pos_j = neighbor_rows(y_snd, extras, exact=pos)
+            radial, coord_diff = _slot_geometry(pos_j, pos)
+            e = (
+                y_j + y_rcv[:, None, :]
+                + (radial[..., None] * w_rad).astype(y_j.dtype)
             )
-            y_j, pos_j = both_j[..., : self.hidden_dim], both_j[..., self.hidden_dim :]
-            coord_diff = pos_j - pos[:, None, :]
-            radial = (coord_diff * coord_diff).sum(-1, keepdims=True)
-            norm = _safe_sqrt(radial) + 1.0  # norm_diff=True
-            coord_diff = coord_diff / norm
-            e = y_j + y_rcv[:, None, :] + radial * w_rad
             if self.edge_attr_dim > 0:
                 # gather the NARROW raw edge_attr first, project after —
                 # projecting first would gather [N, K, H] instead of
@@ -139,32 +168,36 @@ class E_GCL(nn.Module):
             small = nn.initializers.variance_scaling(
                 0.001 * 0.001 / 3.0, "fan_avg", "uniform"
             )
-            cw = cw @ self.param("coord_mlp_1", small, (self.hidden_dim, 1))
+            w1 = self.param("coord_mlp_1", small, (self.hidden_dim, 1))
+            # the translation's weight leaves the messages' width here and
+            # stays f32, as everything the positions are computed from
+            # (bf16 messages: an f32 product of bf16 operands)
+            cw = jnp.dot(cw, w1, preferred_element_type=pos.dtype)
             cw = jnp.tanh(cw)  # tanh=True bounds the update
-            trans = jnp.clip(coord_diff * cw, -100.0, 100.0)
-            trans = jnp.where(emask_nd, trans, 0.0)
             # the coord update (trans + count) and the node-model message
             # aggregation all land at the SAME sender index — ONE packed
             # pass (and one halo_reduce) instead of two
-            packed = jnp.concatenate(
-                [e, trans, emask_nd.astype(trans.dtype)], -1
-            )
-            both = (
-                self._sender_sum_dense(packed, extras, batch)
-                if dense
-                else self._sender_sum(packed, row, n, batch)
-            )
-            agg = both[:, : self.hidden_dim]
-            coord_agg = both[:, self.hidden_dim : self.hidden_dim + 3]
-            cnt = both[:, -1]
-            pos = pos + coord_agg / jnp.maximum(cnt, 1.0)[:, None]
+            if dense:
+                # the messages where they lie, at their own dtype; the
+                # translations and the count beside them, f32
+                moved = _slot_moves(coord_diff, cw[..., 0], nmask)
+                agg, moved = self._sender_sum_dense(e, moved, extras, batch)
+            else:
+                trans = jnp.clip(coord_diff * cw, -100.0, 100.0)
+                trans = jnp.where(emask_nd, trans, 0.0)
+                moved = jnp.concatenate(
+                    [trans, emask_nd.astype(trans.dtype)], -1
+                )
+                both = self._sender_sum(
+                    jnp.concatenate([e, moved], -1), row, n, batch
+                )
+                agg, moved = both[:, : self.hidden_dim], both[:, self.hidden_dim :]
+            pos = pos + moved[:, :3] / jnp.maximum(moved[:, 3], 1.0)[:, None]
+        elif dense:
+            agg, _ = self._sender_sum_dense(e, None, extras, batch)
         else:
             # node model: aggregate edge features at the sender index (row)
-            agg = (
-                self._sender_sum_dense(e, extras, batch)
-                if dense
-                else self._sender_sum(e, row, n, batch)
-            )
+            agg = self._sender_sum(e, row, n, batch)
         h = jnp.concatenate([x, agg], axis=-1)
         h = jax.nn.relu(TorchLinear(self.hidden_dim, name="node_mlp_0")(h))
         h = TorchLinear(self.out_dim, name="node_mlp_1")(h)

@@ -82,8 +82,7 @@ class CFConv(nn.Module):
             # scatter-free too
             from hydragnn_tpu.ops.dense_agg import neighbor_rows
 
-            nbr, nmask = extras["nbr_idx"], extras["nbr_mask"]
-            rev, rmask = extras["rev_idx"], extras["rev_mask"]
+            nmask, rmask = extras["nbr_mask"], extras["rev_mask"]
             pos_j = neighbor_rows(pos, extras)
             pos_i = jnp.broadcast_to(pos[:, None, :], pos_j.shape)
             if self.use_edge_attr:
@@ -138,10 +137,10 @@ class CFConv(nn.Module):
             if dense:
                 # sender-side sum through the reverse lists (scatter-free);
                 # per-sender count = real out-degree
-                from hydragnn_tpu.ops.dense_agg import aggregate_to_senders
+                from hydragnn_tpu.ops.dense_agg import sender_sums
 
                 trans = jnp.where(nmask[..., None], trans, 0.0)
-                agg = aggregate_to_senders(trans, nbr, nmask, rev, rmask)
+                agg = sender_sums(trans, extras)
                 cnt = rmask.sum(axis=1).astype(trans.dtype)
                 if self.partition_axis is not None:
                     from hydragnn_tpu.parallel.graph_partition import (

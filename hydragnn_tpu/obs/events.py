@@ -65,8 +65,9 @@ EVENT_FIELDS: Dict[str, tuple] = {
     # aggregation reporting (ops/agg_policy.py): source "layout" is the
     # family (segment|dense) the batch layout committed one bucket to
     # (models/base.py); source "operands" is the dense path's neighbour
-    # gather (ops/dense_agg.py): gather = choice = onehot|xla, h the
-    # window's halo in blocks
+    # gather or sender sum (ops/dense_agg.py; bucket gather/... or
+    # scatter/...): gather = choice = onehot|xla, h the window's halo in
+    # blocks
     "agg_choice": ("bucket", "choice", "source"),
     # elastic training (train/elastic.py): a peer's heartbeat lease
     # expired — emitted by the detecting watchdog just before it breaks

@@ -6,33 +6,46 @@ Two aggregation families serve the message-passing hot path:
   (``graph/segment.py``; XLA fuses them with the surrounding elementwise
   work);
 - **dense**: host-built fixed-width neighbor lists, scatter-free masked
-  K-axis reductions (``ops/dense_agg.py``), whose neighbour gather is a
-  block-local one-hot product where the operands allow it
+  K-axis reductions (``ops/dense_agg.py``), whose neighbour gather and
+  sender sum are block-local one-hot products where the operands allow it
   (``ops/local_gather.py window_halo``).
 
 The family is a LAYOUT decision (the loader builds neighbor lists or it
 does not), made once per run by :func:`needs_dense_neighbors`: partitioned
 -> segment; an explicit ``Architecture.dense_aggregation`` -> that; else
-the static width tables below. Nothing else steers it: no environment
-name, no file.
+the static width tables below (EGNN's row for runs that compute in bf16,
+as the one precision rule resolves it). Nothing else steers it: no
+environment name or file of its own.
 
 What ran is emitted as ``agg_choice`` obs events (schema in
 ``obs/events.py``) and an ``aggregation_kernel`` labeled gauge: the family
 the batch layout committed to (source ``layout``, emitted by
-``models/base.py`` at trace time) and the neighbour gather's
-implementation (source ``operands``, ``ops/dense_agg.py``).
+``models/base.py`` at trace time) and the implementation of each neighbour
+gather and sender sum (source ``operands``, ``ops/dense_agg.py``).
 """
 
 CHOICES = ("segment", "dense")
 
-# Dense/segment crossovers measured on a v5e before the current tree
-# (2026-07/08, same-session A/Bs at deg ~12; not re-measured since, nor
-# after PR 27 changed the dense path's cost — ROADMAP D11): minimum
-# hidden_dim at which the dense scatter-free path beats segment reductions
-# for each model. Scatter-heavy models (PNA's 4 aggregators, GAT's edge
-# softmax, MFC's degree banks, DimeNet's triplet axis) cross early;
-# GIN/SAGE only win mildly at MXU widths; SchNet and EGNN never do (one
-# already-fused scatter per layer).
+# Dense/segment crossovers: minimum hidden_dim at which the dense
+# scatter-free path beats segment reductions for each model. All rows but
+# EGNN's were measured on a v5e before the current tree (2026-07/08,
+# same-session A/Bs at deg ~12; not re-measured since, nor after PR 27
+# changed the dense path's cost — ROADMAP D11). Scatter-heavy models
+# (PNA's 4 aggregators, GAT's edge softmax, MFC's degree banks, DimeNet's
+# triplet axis) cross early; GIN/SAGE only win mildly at MXU widths;
+# SchNet never does (one already-fused scatter per layer).
+#
+# EGNN's row was read on THIS tree, 2026-10-03 (PR 29, one TPU v5 lite,
+# benchmarks/egnn_family_ab.py: the train step of egnn_h128x7_train_mptrj,
+# its traffic, rung 384 and seven layers, dense_aggregation false | true,
+# ms a step): hidden 32: 77.9 | 110.6; 64: 83.8 | 109.8; 128: 106.1 | 62.5.
+# The smallest of the three at which dense wins by more than 10% is 128:
+# where the precision policy ("auto") turns bf16 on, so the dense path's
+# gather and sender sum are the products of ops/local_gather.py; under it
+# the tables are f32, keep XLA's gathers, and lose to the one fused
+# scatter a layer; so does an f32 run AT 128 (mixed_precision false:
+# 106.5 | 176.5), which is why the row holds for bf16 runs only
+# (DENSE_ROWS_READ_IN_BF16).
 DENSE_AUTO_MIN_HIDDEN = {
     "PNA": 96,
     "GAT": 96,
@@ -40,10 +53,18 @@ DENSE_AUTO_MIN_HIDDEN = {
     "DimeNet": 96,
     "GIN": 192,
     "SAGE": 192,
+    "EGNN": 128,
     # CGCNN absent from THIS table: its convs run at input_dim width
     # (constant-width CGConv), so hidden_dim says nothing about where it
     # sits relative to the crossover — it gets its own rule below.
 }
+
+# Rows that hold only where the run computes in bf16 (the dense side wins
+# as products, and a product wants a bf16 table): ``arch_for_auto_policy``
+# states the run's precision as ``bf16_compute``, resolved by the one
+# precision rule (``models/create.py precision_for``); absent, the row
+# does not apply.
+DENSE_ROWS_READ_IN_BF16 = ("EGNN",)
 
 # CGCNN's crossover keyed on its TRUE conv width (round-4 verdict item 8,
 # measured round 5 at OC20 shape): INVERSE to the hidden-width table —
@@ -67,7 +88,11 @@ def auto_dense_aggregation(arch_config: dict) -> bool:
         dim = int(arch_config.get("input_dim") or 0)
         return 1 <= dim <= th_in
     th = DENSE_AUTO_MIN_HIDDEN.get(mt)
-    return th is not None and int(arch_config.get("hidden_dim") or 0) >= th
+    if th is None or int(arch_config.get("hidden_dim") or 0) < th:
+        return False
+    return mt not in DENSE_ROWS_READ_IN_BF16 or bool(
+        arch_config.get("bf16_compute")
+    )
 
 
 def static_aggregation_choice(arch_config: dict) -> str:
@@ -77,17 +102,28 @@ def static_aggregation_choice(arch_config: dict) -> str:
 
 
 def arch_for_auto_policy(nn_config: dict) -> dict:
-    """Architecture dict enriched with ``input_dim`` (CGCNN's crossover
+    """Architecture dict enriched with what the tables key on and the
+    Architecture section does not state: ``input_dim`` (CGCNN's crossover
     key) derived from ``Variables_of_interest.input_node_features`` when
-    the config predates ``update_config`` — ONE derivation shared by every
-    entry point so their dense/segment decisions cannot diverge."""
+    the config predates ``update_config``, and ``bf16_compute`` for the
+    rows read in bf16, from the ``Training`` section through the one
+    precision rule. ONE derivation shared by every entry point so their
+    dense/segment decisions cannot diverge."""
     arch = nn_config["Architecture"]
+    derived = {}
     feats = nn_config.get("Variables_of_interest", {}).get(
         "input_node_features"
     )
     if feats and "input_dim" not in arch:
-        return dict(arch, input_dim=len(feats))
-    return arch
+        derived["input_dim"] = len(feats)
+    mt = arch.get("model_type")
+    if mt in DENSE_ROWS_READ_IN_BF16 and "bf16_compute" not in arch:
+        from hydragnn_tpu.models.create import precision_for
+
+        derived["bf16_compute"] = precision_for(
+            mt, arch.get("hidden_dim"), nn_config.get("Training", {})
+        )["mixed"]
+    return dict(arch, **derived) if derived else arch
 
 
 def needs_dense_neighbors(arch_config: dict) -> bool:

@@ -15,6 +15,15 @@ BOTH directions of the conv:
   neighbor list (sender-side slots, also precomputed host-side), so the
   backward pass is a gather + masked reduction too.
 
+Two calls move rows between nodes and slots, each the other's transpose:
+:func:`gather_neighbors` (sender rows to their receivers' slots) and
+:func:`aggregate_to_senders` (slot values summed at the sender each
+names). Where the operands allow it (a bf16 table, a TPU, a batch whose
+collate states its locality) both are block-local one-hot products on the
+MXU (``ops/local_gather.py``), f32 columns going through beside the bf16
+table as three exact bf16 pieces each; otherwise XLA's gathers through
+the forward and reverse lists.
+
 Numerics are identical to the segment path (same masking, same empty-
 segment fill); see ``tests/test_dense_agg.py`` for the parity proof.
 The lists live in ``batch.extras`` and are built by the loader when the
@@ -212,8 +221,48 @@ def _backend() -> str:
     return jax.default_backend()
 
 
+def _halo(x, exact, nbr_idx, nbr_mask, nbr_reach):
+    """``window_halo`` for the table (operand) ``x`` with the f32 columns
+    ``exact`` beside it as pieces, behind these lists: the one question
+    both neighbour products ask, answered from the operands alone."""
+    from hydragnn_tpu.ops.local_gather import lane_width, window_halo
+
+    if nbr_mask is None or nbr_reach is None:
+        return None
+    widths = (x.shape[-1],) + (() if exact is None else (3 * exact.shape[-1],))
+    return window_halo(
+        x.dtype, nbr_reach.shape[-1], nbr_idx.shape[1], lane_width(*widths),
+        _backend(),
+    )
+
+
+def _report(kind, n, k, width, dtype, h):
+    from hydragnn_tpu.ops.agg_policy import emit_choice
+
+    impl = "xla" if h is None else "onehot"
+    emit_choice(
+        f"{kind}/n{n}/k{k}/d{width}/{jnp.dtype(dtype).name}", impl,
+        "operands", gather=impl, **({} if h is None else {"h": h}),
+    )
+
+
+def _one_table(x, exact):
+    """``x`` and the f32 columns ``exact`` as ONE table for XLA's gather
+    (f32 as soon as there are such columns)."""
+    return x if exact is None else jnp.concatenate([x, exact], axis=-1)
+
+
+def _apart(out, x):
+    """A result of :func:`_one_table`'s table apart again: ``x``'s columns
+    at its dtype, the rest (f32) or None."""
+    d = x.shape[-1]
+    if out.shape[-1] == d:
+        return out.astype(x.dtype), None
+    return out[..., :d].astype(x.dtype), out[..., d:]
+
+
 def gather_neighbors(
-    x, nbr_idx, rev_idx, rev_mask, nbr_mask=None, nbr_reach=None
+    x, nbr_idx, rev_idx, rev_mask, nbr_mask=None, nbr_reach=None, exact=None
 ):
     """``x[nbr_idx]`` ([N, D] -> [N, K, D]) whose backward pass is no
     scatter-add. The ONE neighbour gather of the dense path, in one of
@@ -231,31 +280,35 @@ def gather_neighbors(
       equal the indexed read bit for bit; padded slots read zero instead
       of row 0 (every consumer masks them with ``nbr_mask``).
 
+    ``exact``: f32 columns ``[N, C]`` (EGNN's positions) to gather through
+    the same lists without giving up a bit: the result is then ``(x rows,
+    exact rows [N, K, C] f32)``. Behind a bf16 ``x`` they go through the
+    product as three bf16 pieces each (``local_gather.split_f32``), a
+    second table of the same call, their cotangents likewise; under
+    ``xla`` they are further columns of one (f32) table, as EGNN always
+    gathered them. A table that IS f32 is not split: it keeps ``xla``.
+
     The choice is reported as an ``agg_choice`` event (``gather``, ``h``).
     """
-    from hydragnn_tpu.ops.agg_policy import emit_choice
-    from hydragnn_tpu.ops.local_gather import window_halo
-
     (n, d), k_in = x.shape, nbr_idx.shape[1]
-    h = None
-    if nbr_mask is not None and nbr_reach is not None:
-        h = window_halo(x.dtype, nbr_reach.shape[-1], k_in, d, _backend())
-    impl = "xla" if h is None else "onehot"
-    emit_choice(
-        f"gather/n{n}/k{k_in}/d{d}/{x.dtype.name}", impl, "operands",
-        gather=impl, **({} if h is None else {"h": h}),
-    )
+    h = _halo(x, exact, nbr_idx, nbr_mask, nbr_reach)
     if h is None:
-        return _gather_xla(x, nbr_idx, rev_idx, rev_mask)
-    return _gather_onehot(h, x, nbr_idx, nbr_mask)
+        table = _one_table(x, exact)
+        _report("gather", n, k_in, table.shape[-1], table.dtype, None)
+        rows = _apart(_gather_xla(table, nbr_idx, rev_idx, rev_mask), x)
+    else:
+        pieces = 0 if exact is None else 3 * exact.shape[-1]
+        _report("gather", n, k_in, d + pieces, x.dtype, h)
+        rows = _gather_onehot(h, x, exact, nbr_idx, nbr_mask)
+    return rows[0] if exact is None else rows
 
 
-def neighbor_rows(x, extras):
+def neighbor_rows(x, extras, exact=None):
     """:func:`gather_neighbors` through a dense-list batch's ``extras``,
     with everything they state (the convs' one call)."""
     return gather_neighbors(
         x, extras["nbr_idx"], extras["rev_idx"], extras["rev_mask"],
-        extras["nbr_mask"], extras.get("nbr_reach"),
+        extras["nbr_mask"], extras.get("nbr_reach"), exact,
     )
 
 
@@ -290,28 +343,74 @@ def _gather_bwd(res, g):
 _gather_xla.defvjp(_gather_fwd, _gather_bwd)
 
 
+# The two products, each the other's transpose: what one does forward the
+# other does to the cotangents. f32 columns go through as a second bf16
+# table of pieces; the kernels work slot-major ([K, N, D]: whole tiles per
+# slot), and the transposes are layout choices XLA folds into the fusions
+# beside them. Each is ONE ``jax.jit``-wrapped body: the calls of a step
+# program that agree in shape (EGNN: seven layers, and a gather's backward
+# with a sender sum's forward) share one traced and lowered function, so
+# set-up pays for pieces, padding and kernel once per shape, not per site.
+
+
+def _stated_mesh():
+    """The abstract mesh that is current, stated: a no-op for the program,
+    but JAX runs a backward rule under a stated (empty) mesh and a forward
+    rule under none, and a jit body's trace cache tells the two apart. With
+    this around both, a gather's forward and a sender sum's backward are
+    ONE traced body and ONE lowered kernel (lowering a kernel is most of
+    what set-up pays for the products: PERF.md section 6, PR 29)."""
+    return jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh())
+
+
+def _tables(x, exact):
+    from hydragnn_tpu.ops.local_gather import split_f32
+
+    return (x,) if exact is None else (x, split_f32(exact))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _rows_product(h, x, exact, nbr_idx):
+    """``(x[nbr_idx], exact[nbr_idx])``: [N, K, D] at ``x.dtype`` and
+    [N, K, C] f32 (None without ``exact``)."""
+    from hydragnn_tpu.ops.local_gather import gather_product, join_f32
+
+    rows = gather_product(_tables(x, exact), nbr_idx, h)
+    rows = [r.transpose(1, 0, 2) for r in rows]
+    return rows[0], None if exact is None else join_f32(rows[1])
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _sums_product(h, g, exact, nbr_idx, nbr_mask):
+    """Sums of the real slots of ``g [N, K, D]`` and ``exact [N, K, C]``
+    at the sender each names: [N, D] at ``g.dtype`` and [N, C] f32 (the
+    pieces leave the kernel in f32, its accumulator as it is)."""
+    from hydragnn_tpu.ops.local_gather import join_f32, scatter_product
+
+    sums = scatter_product(
+        tuple(t.transpose(1, 0, 2) for t in _tables(g, exact)),
+        nbr_idx, nbr_mask, h,
+        out_dtype=(None,) if exact is None else (None, jnp.float32),
+    )
+    return sums[0], None if exact is None else join_f32(sums[1])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 @_scope
-def _gather_onehot(h, x, nbr_idx, nbr_mask):
-    from hydragnn_tpu.ops.local_gather import gather_product
-
-    # the kernels work slot-major ([K, N, D]: whole tiles per slot); the
-    # transposes are layout choices XLA folds into the fusions beside them
-    return gather_product(x, nbr_idx, h).transpose(1, 0, 2)
+def _gather_onehot(h, x, exact, nbr_idx, nbr_mask):
+    with _stated_mesh():
+        return _rows_product(h, x, exact, nbr_idx)
 
 
 @_scope
-def _gather_onehot_fwd(h, x, nbr_idx, nbr_mask):
-    return _gather_onehot(h, x, nbr_idx, nbr_mask), (nbr_idx, nbr_mask)
+def _gather_onehot_fwd(h, x, exact, nbr_idx, nbr_mask):
+    return _gather_onehot(h, x, exact, nbr_idx, nbr_mask), (nbr_idx, nbr_mask)
 
 
 @_scope
 def _gather_onehot_bwd(h, res, g):
-    from hydragnn_tpu.ops.local_gather import scatter_product
-
-    nbr_idx, nbr_mask = res
-    gx = scatter_product(g.transpose(1, 0, 2), nbr_idx, nbr_mask, h)
-    return gx, None, None
+    with _stated_mesh():
+        return (*_sums_product(h, *g, *res), None, None)
 
 
 _gather_onehot.defvjp(_gather_onehot_fwd, _gather_onehot_bwd)
@@ -436,17 +535,61 @@ def build_group_lists(
     return lists, mask
 
 
+def aggregate_to_senders(
+    h, nbr_idx, nbr_mask, rev_idx, rev_mask, nbr_reach=None, exact=None
+):
+    """Sum dense per-edge values ``h [N, K_in, D]`` (keyed by receiver x
+    slot) onto their SENDER nodes -> ``[N, D]`` at ``h.dtype``, f32
+    accumulation, scatter-free in both directions. The transpose of
+    :func:`gather_neighbors`, and chosen like it, at trace time, from the
+    operands alone (``window_halo``: operand dtype, backend, the collate's
+    stated ``nbr_reach``, the window's size):
+
+    - ``xla``: each sender reads its outgoing slots through the reverse
+      list; backward a gather through the forward list. Every caller that
+      states no locality or hands an f32 operand (SchNet's ``trans``,
+      partition shards, the CPU), bit for bit as before;
+    - ``onehot``: forward ``local_gather.scatter_product`` on the
+      slot-major operand, backward ``gather_product`` of the cotangent
+      masked by ``nbr_mask``.
+
+    ``exact``: f32 values ``[N, K_in, C]`` (EGNN's translations) to sum
+    alongside without giving up a bit of their addends: the result is then
+    ``(sums of h, sums of exact [N, C] f32)``. Behind a bf16 ``h`` they
+    go through the product as three bf16 pieces each, a second operand of
+    the same call (a 0/1 product of a piece is exact, the kernel's f32
+    accumulator leaves as it is), their cotangents likewise; under ``xla``
+    they are further columns of one (f32) operand.
+
+    Reported as an ``agg_choice`` event (``scatter/...``, ``gather``, ``h``).
+    """
+    n, k_in, d = h.shape
+    halo = _halo(h, exact, nbr_idx, nbr_mask, nbr_reach)
+    if halo is None:
+        operand = _one_table(h, exact)
+        _report("scatter", n, k_in, operand.shape[-1], operand.dtype, None)
+        sums = _apart(
+            _sender_sum_xla(operand, nbr_idx, nbr_mask, rev_idx, rev_mask), h
+        )
+    else:
+        pieces = 0 if exact is None else 3 * exact.shape[-1]
+        _report("scatter", n, k_in, d + pieces, h.dtype, halo)
+        sums = _sender_sum_onehot(halo, h, exact, nbr_idx, nbr_mask)
+    return sums[0] if exact is None else sums
+
+
+def sender_sums(h, extras, exact=None):
+    """:func:`aggregate_to_senders` through a dense-list batch's
+    ``extras``, with everything they state (the convs' one call)."""
+    return aggregate_to_senders(
+        h, extras["nbr_idx"], extras["nbr_mask"], extras["rev_idx"],
+        extras["rev_mask"], extras.get("nbr_reach"), exact,
+    )
+
+
 @jax.custom_vjp
 @_scope
-def aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
-    """Sum dense per-edge values ``h [N, K_in, D]`` (keyed by receiver x
-    slot) onto their SENDER nodes -> ``[N, D]``, scatter-free.
-
-    Forward reads each sender's outgoing slots through the reverse list;
-    backward is the exact dual — a gather through the forward list:
-    ``grad_h[r, k] = g_out[nbr_idx[r, k]]`` — so EGNN/SchNet-style
-    sender-side aggregations stay scatter-free in both directions too.
-    """
+def _sender_sum_xla(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     n, k_in, d = h.shape
     flat = h.reshape(n * k_in, d)
     contrib = flat[rev_idx]  # [N, K_out, D]
@@ -457,22 +600,55 @@ def aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
 
 
 @_scope
-def _agg_send_fwd(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
+def _sender_sum_xla_fwd(h, nbr_idx, nbr_mask, rev_idx, rev_mask):
     return (
-        aggregate_to_senders(h, nbr_idx, nbr_mask, rev_idx, rev_mask),
+        _sender_sum_xla(h, nbr_idx, nbr_mask, rev_idx, rev_mask),
         (nbr_idx, nbr_mask),
     )
 
 
 @_scope
-def _agg_send_bwd(res, g):
+def _sender_sum_xla_bwd(res, g):
     nbr_idx, nbr_mask = res
     gh = g[nbr_idx]  # [N, K_in, D]
     gh = jnp.where(nbr_mask[..., None], gh, 0.0)
     return gh, None, None, None, None
 
 
-aggregate_to_senders.defvjp(_agg_send_fwd, _agg_send_bwd)
+_sender_sum_xla.defvjp(_sender_sum_xla_fwd, _sender_sum_xla_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+@_scope
+def _sender_sum_onehot(halo, h, exact, nbr_idx, nbr_mask):
+    with _stated_mesh():
+        return _sums_product(halo, h, exact, nbr_idx, nbr_mask)
+
+
+@_scope
+def _sender_sum_onehot_fwd(halo, h, exact, nbr_idx, nbr_mask):
+    return (
+        _sender_sum_onehot(halo, h, exact, nbr_idx, nbr_mask),
+        (nbr_idx, nbr_mask),
+    )
+
+
+@_scope
+def _sender_sum_onehot_bwd(halo, res, g):
+    nbr_idx, nbr_mask = res
+    with _stated_mesh():
+        rows = _rows_product(halo, *g, nbr_idx)
+    # a padded slot's row (zero, or row 0) is no cotangent of its value
+    return (
+        *(
+            None if r is None else jnp.where(nbr_mask[..., None], r, 0)
+            for r in rows
+        ),
+        None, None,
+    )
+
+
+_sender_sum_onehot.defvjp(_sender_sum_onehot_fwd, _sender_sum_onehot_bwd)
 
 
 @_scope

@@ -55,9 +55,14 @@ def update_config(config, train_loader, val_loader, test_loader):
         # wins, else the width tables decide (ops/agg_policy.py). Partition
         # mode keeps its own explicit opt-in (per-shard lists change the
         # memory equation)
-        from hydragnn_tpu.ops.agg_policy import needs_dense_neighbors
+        from hydragnn_tpu.ops.agg_policy import (
+            arch_for_auto_policy,
+            needs_dense_neighbors,
+        )
 
-        arch["dense_aggregation"] = needs_dense_neighbors(arch)
+        arch["dense_aggregation"] = needs_dense_neighbors(
+            arch_for_auto_policy(config["NeuralNetwork"])
+        )
     if arch["model_type"] == "MFC":
         # dataset-wide max in-degree: a STATIC bound that lets the conv
         # slice dead banks out of its one-hot degree matmul (the reference

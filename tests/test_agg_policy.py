@@ -22,7 +22,7 @@ _SIDES = [
     ("SAGE", "hidden_dim", 191, 192),
     ("CGCNN", "input_dim", 65, 64),  # inverse, and keyed on its input width
     ("SchNet", "hidden_dim", 2048, None),
-    ("EGNN", "hidden_dim", 2048, None),
+    ("EGNN", "hidden_dim", 127, 128),  # read on the chip in PR 29, in bf16
 ]
 
 
@@ -32,11 +32,36 @@ _SIDES = [
     + [(m, k, den, True) for m, k, _, den in _SIDES if den is not None],
 )
 def pytest_table_decides_each_stack_on_each_side(model_type, key, width, dense):
-    arch = {"model_type": model_type, "hidden_dim": 64, key: width}
+    arch = {"model_type": model_type, "hidden_dim": 64, key: width,
+            "bf16_compute": True}
     assert ap.needs_dense_neighbors(arch) is dense
     assert ap.static_aggregation_choice(arch) == (
         "dense" if dense else "segment"
     )
+
+
+@pytest.mark.parametrize(
+    "training,dense",
+    [
+        ({"mixed_precision": "auto"}, True),  # bf16 from 128 on: products
+        ({"mixed_precision": True}, True),
+        ({"mixed_precision": False}, False),  # f32 tables keep XLA's gathers
+        ({}, False),  # the default is f32
+    ],
+    ids=["auto", "bf16", "f32", "unstated"],
+)
+def pytest_egnn_row_holds_for_bf16_runs_only(training, dense):
+    """EGNN's dense side wins as products (106.1 | 62.5 ms a step at 128)
+    and loses with f32 tables (106.5 | 176.5): the row asks the one
+    precision rule, through the one derivation every entry point shares."""
+    nn = {
+        "Architecture": {"model_type": "EGNN", "hidden_dim": 128},
+        "Training": training,
+    }
+    arch = ap.arch_for_auto_policy(nn)
+    assert arch["bf16_compute"] is dense and arch is not nn["Architecture"]
+    assert ap.needs_dense_neighbors(arch) is dense
+    assert not ap.needs_dense_neighbors(nn["Architecture"])  # unstated: segment
 
 
 @pytest.mark.parametrize("flag", [True, False])
@@ -44,7 +69,7 @@ def pytest_table_decides_each_stack_on_each_side(model_type, key, width, dense):
     "arch",
     [
         {"model_type": "PNA", "hidden_dim": 256},  # table: dense
-        {"model_type": "EGNN", "hidden_dim": 128},  # table: segment
+        {"model_type": "EGNN", "hidden_dim": 64},  # under its row: segment
         {"model_type": "CGCNN", "hidden_dim": 64},  # no input_dim: segment
     ],
     ids=lambda a: a["model_type"],
@@ -78,15 +103,15 @@ def pytest_no_file_and_no_environment_name_moves_the_decision(monkeypatch):
     from hydragnn_tpu.utils.compile_cache import DEFAULT_CACHE_DIR
 
     pna = {"model_type": "PNA", "hidden_dim": 256}
-    egnn = {"model_type": "EGNN", "hidden_dim": 128}
-    before = (ap.needs_dense_neighbors(pna), ap.needs_dense_neighbors(egnn))
+    schnet = {"model_type": "SchNet", "hidden_dim": 128}  # no row: segment
+    before = (ap.needs_dense_neighbors(pna), ap.needs_dense_neighbors(schnet))
     assert before == (True, False)
     path = os.path.join(DEFAULT_CACHE_DIR, "autotune.json")
     planted = not os.path.exists(path)  # a stray one serves as well
     flipped = {"timings_ms": {"segment": 1.0, "dense": 2.0}, "ts": 9e9}
     record = {
         "PNA/n48/e160/d256": dict(flipped, choice="segment"),
-        "EGNN/n48/e160/d128": dict(flipped, choice="dense"),
+        "SchNet/n48/e160/d128": dict(flipped, choice="dense"),
     }
     try:
         if planted:
@@ -100,7 +125,7 @@ def pytest_no_file_and_no_environment_name_moves_the_decision(monkeypatch):
         for forced in ("segment", "dense", "fused"):
             monkeypatch.setenv("HYDRAGNN_AGG", forced)
             after = (
-                ap.needs_dense_neighbors(pna), ap.needs_dense_neighbors(egnn)
+                ap.needs_dense_neighbors(pna), ap.needs_dense_neighbors(schnet)
             )
             assert after == before
     finally:

@@ -110,6 +110,49 @@ def pytest_local_gather_compiles_where_the_rule_selects_it(
     assert text.count(KERNEL) == 2
 
 
+@pytest.mark.parametrize(
+    "n,reach",
+    [
+        (44336, 200),  # the EGNN cell's largest bucket: h = 2, 10 tiles
+        (7480, 29),  # its smallest: h = 1
+    ],
+)
+def pytest_egnn_products_compile_at_the_cell_buckets(chip, monkeypatch, n, reach):
+    """E_GCL's two calls at the cell's width and 16 slots: the gather of
+    128 bf16 columns with 3 f32 positions beside them (9 pieces, a second
+    table of the call) and the sender sum of 128 with 3 translations and
+    the count (12 pieces), each with its transpose: four kernels, the
+    pieces' sums out of the kernel in f32."""
+    monkeypatch.setattr(da, "_backend", lambda: "tpu")
+    k, d = 16, 128
+    assert lg.window_halo(
+        jnp.bfloat16, reach, k, lg.lane_width(d, 12), "tpu"
+    ) == -(-(reach - 1) // lg.BLOCK)
+    lists = {
+        "nbr_idx": _shape(chip, (n, k), jnp.int32),
+        "nbr_mask": _shape(chip, (n, k), jnp.bool_),
+        "rev_idx": _shape(chip, (n, 21), jnp.int32),
+        "rev_mask": _shape(chip, (n, 21), jnp.bool_),
+        "nbr_reach": _shape(chip, (reach,), jnp.int8),
+    }
+
+    def both(y, pos, e, trans, ex):
+        y_j, pos_j = da.neighbor_rows(y, ex, exact=pos)
+        agg, moved = da.sender_sums(e, ex, exact=trans)
+        return y_j.astype(jnp.float32), pos_j, agg.astype(jnp.float32), moved
+
+    text = _compile_fwd_and_grad(
+        both, 4,
+        (
+            _shape(chip, (n, d), jnp.bfloat16), _shape(chip, (n, 3)),
+            _shape(chip, (n, k, d), jnp.bfloat16), _shape(chip, (n, k, 4)),
+            lists,
+        ),
+    )
+    assert text.count(KERNEL) == 4
+    assert "f32[%d,128]" % n in text  # the pieces' sums leave the kernel in f32
+
+
 # ---- the whole jitted train step of chip_smoke.py ---------------------------
 
 

@@ -141,8 +141,9 @@ def pytest_update_config_pna_degree_histogram():
 
 def pytest_auto_dense_aggregation_policy():
     """The measured-crossover policy (ops/agg_policy.py): scatter-heavy models
-    pick the dense path at MXU widths with NO config flag; SchNet/EGNN
-    never do; an explicit flag and partition mode always win."""
+    pick the dense path at MXU widths with NO config flag; SchNet never
+    does; an explicit flag and partition mode always win. (EGNN's row is
+    ``tests/test_agg_policy.py``'s.)"""
     from hydragnn_tpu.ops.agg_policy import needs_dense_neighbors
 
     for m in ("PNA", "GAT", "MFC", "DimeNet"):
@@ -152,9 +153,9 @@ def pytest_auto_dense_aggregation_policy():
     for m in ("GIN", "SAGE"):
         assert needs_dense_neighbors({"model_type": m, "hidden_dim": 256})
         assert not needs_dense_neighbors({"model_type": m, "hidden_dim": 128})
-    # SchNet/EGNN: one fused scatter/layer — dense never wins. CGCNN runs
+    # SchNet: one fused scatter/layer — dense never wins. CGCNN runs
     # at input_dim width, so hidden_dim is not a crossover signal.
-    for m in ("SchNet", "EGNN", "CGCNN"):
+    for m in ("SchNet", "CGCNN"):
         assert not needs_dense_neighbors({"model_type": m, "hidden_dim": 512})
     # CGCNN's own rule keys on input_dim — its true conv width — and
     # INVERSELY: the dense frame's gather traffic grows with input width
