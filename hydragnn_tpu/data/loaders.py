@@ -141,6 +141,21 @@ def _sample_degrees(data: GraphData) -> Tuple[int, int]:
     return int(k_in) + 1, int(k_out) + 1
 
 
+def _sample_triplet_count(data: GraphData) -> int:
+    """Real (k->j->i) triplets of the sample, k != i: per central node j
+    its in-edges times its out-edges, less the pairs that only turn back
+    (k->j with j->k). What the dense lists' ``K_out x K_in`` grids hold of
+    real work; cached on the sample like its slots."""
+    count = data.extras.get("triplet_count")
+    if count is None:
+        send, recv = (np.asarray(r, np.int64) for r in data.edge_index)
+        n = int(data.num_nodes)
+        pairs = np.bincount(recv, minlength=n) * np.bincount(send, minlength=n)
+        back = np.isin(send * n + recv, recv * n + send).sum()
+        count = data.extras["triplet_count"] = int(pairs.sum() - back)
+    return count
+
+
 def _lcm(a, b):
     import math
 
@@ -443,11 +458,15 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
     if layout.packs_triplets:
         from hydragnn_tpu.graph.batch import pack_triplets
 
-        with tr.span("triplets"):
+        with tr.span("triplets") as span:
             trips = [
                 _sample_triplets(s) + (s.num_nodes, s.num_edges)
                 for s in samples
             ]
+            span.set(
+                triplets=sum(t[0].shape[0] for t in trips),
+                triplet_slots=layout.t_pad,
+            )
             batch = batch.replace(
                 extras=pack_triplets(trips, layout.n_pad, layout.t_pad)
             )
@@ -476,6 +495,13 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
                 with_slot_tables=layout.need_triplets,
             )
             span.set(slots_cached=len(samples) - built, slots_built=built)
+            if layout.need_triplets:
+                # what the bmm-triplet grids hold: real triplets against
+                # the n_pad x k_out x k_in slots the step computes over
+                span.set(
+                    triplets=sum(_sample_triplet_count(s) for s in samples),
+                    triplet_slots=layout.n_pad * layout.k_out * layout.k_in,
+                )
             merged = dict(batch.extras or {})
             merged.update(nbr)
             if layout.nbr_reach:

@@ -163,9 +163,20 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
 # step is op-latency/scatter-bound and bf16 buys nothing while costing
 # precision (graph/segment.py upcasts scatters for exactly this reason);
 # at MXU widths the measured wins are large (BENCH_EXTRA dense-bf16 rows,
-# e.g. PNA h256 1.76x). DimeNet is deliberately absent: its spherical-
-# basis recurrences are precision-sensitive and the measured bf16 delta
-# was within noise — it stays f32 under "auto".
+# e.g. PNA h256 1.76x).
+#
+# DimeNet's row was read on THIS tree, 2026-10-03 (PR 30, one TPU v5 lite,
+# benchmarks/dimenet_family_ab.py: the train step of
+# dimenetpp_h128x4_train_mptrj, its traffic at rung 64, hidden 128 x 4
+# blocks, ms a step, f32 | bf16): dense lists 30.012 | 21.029 (1.43 x),
+# triplet tables 149.371 | 134.452 (1.11 x). Before it the stack was
+# absent here ("the measured bf16 delta was within noise", at toy widths
+# and with an f32 radial basis that promoted every edge-level product
+# back to f32). Its geometry (distances, angles, the spherical and radial
+# bases) and the Bessel frequencies stay f32 in a bf16 run
+# (models/dimenet.py, DIMEStack.f32_params); against the f32 reference the
+# cell's bf16 step reads grad_gap 0.013 where fp8 operands read 1.0
+# (PERF.md section 2).
 BF16_AUTO_MIN_HIDDEN = {
     "PNA": 128,
     "GAT": 128,
@@ -175,6 +186,7 @@ BF16_AUTO_MIN_HIDDEN = {
     "CGCNN": 128,
     "SchNet": 128,
     "EGNN": 128,
+    "DimeNet": 128,
 }
 
 

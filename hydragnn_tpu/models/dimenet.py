@@ -168,6 +168,7 @@ def spherical_basis(
     return out.reshape(out.shape[0], num_spherical * num_radial)
 
 
+@jax.named_scope("dimenet_geometry")
 def _dimenet_geometry_dense(
     batch, pos, num_spherical, num_radial, cutoff, envelope_exponent
 ):
@@ -237,6 +238,7 @@ def _dimenet_geometry_dense(
     return dist, rad, cbf
 
 
+@jax.named_scope("dimenet_geometry")
 def _dimenet_geometry(
     batch, pos, num_spherical, num_radial, cutoff, envelope_exponent,
     partition_axis,
@@ -411,75 +413,86 @@ class DimeNetConv(nn.Module):
                 self.partition_axis,
             )
 
-        rbf = BesselBasisLayer(
-            self.num_radial, self.cutoff, self.envelope_exponent, name="rbf"
-        )(dist)
+        # four names a device trace reads this layer's time by, beside
+        # ``dimenet_geometry`` (``jax.named_scope``: a name, no run-time cost)
+        with jax.named_scope("dimenet_embed"):
+            # the Bessel layer itself is f32 whatever the run computes in
+            # (``DIMEStack.f32_params``); what it feeds into the linears is
+            # an activation like any other, at the compute dtype
+            rbf = BesselBasisLayer(
+                self.num_radial, self.cutoff, self.envelope_exponent,
+                name="rbf",
+            )(dist).astype(x.dtype)
 
-        # lin + embedding block (edge-level states)
-        h = TorchLinear(self.hidden_dim, name="lin")(x)
-        r = act(TorchLinear(self.hidden_dim, name="emb_lin_rbf")(rbf))
-        e = act(
-            TorchLinear(self.hidden_dim, name="emb_lin")(
-                jnp.concatenate([h[i], h[j], r], axis=-1)
+            # lin + embedding block (edge-level states)
+            h = TorchLinear(self.hidden_dim, name="lin")(x)
+            r = act(TorchLinear(self.hidden_dim, name="emb_lin_rbf")(rbf))
+            e = act(
+                TorchLinear(self.hidden_dim, name="emb_lin")(
+                    jnp.concatenate([h[i], h[j], r], axis=-1)
+                )
             )
-        )
 
         # InteractionPPBlock
-        rbf_b = TorchLinear(self.basis_emb_size, use_bias=False, name="int_rbf1")(rbf)
-        rbf_b = TorchLinear(self.hidden_dim, use_bias=False, name="int_rbf2")(rbf_b)
-        lin_sbf1 = TorchLinear(
-            self.basis_emb_size, use_bias=False, name="int_sbf1"
-        )
-        lin_sbf2 = TorchLinear(
-            self.int_emb_size, use_bias=False, name="int_sbf2"
-        )
-        x_ji = act(TorchLinear(self.hidden_dim, name="int_lin_ji")(e))
-        x_kj = act(TorchLinear(self.hidden_dim, name="int_lin_kj")(e))
-        x_kj = x_kj * rbf_b
-        x_kj = act(TorchLinear(self.int_emb_size, use_bias=False, name="int_down")(x_kj))
-        if bmm_mode:
-            x_kj = _bmm_triplet_aggregate(
-                x_kj, rad, cbf, lin_sbf1, lin_sbf2, batch,
-                self.num_spherical, self.num_radial,
+        with jax.named_scope("dimenet_triplets"):
+            rbf_b = TorchLinear(self.basis_emb_size, use_bias=False, name="int_rbf1")(rbf)
+            rbf_b = TorchLinear(self.hidden_dim, use_bias=False, name="int_rbf2")(rbf_b)
+            lin_sbf1 = TorchLinear(
+                self.basis_emb_size, use_bias=False, name="int_sbf1"
             )
-        else:
-            idx_kj, idx_ji = ex["trip_kj"], ex["trip_ji"]
-            trip_mask = ex["trip_mask"]
-            sbf_b = lin_sbf2(lin_sbf1(sbf))
-            if self.partition_axis is not None:
-                from hydragnn_tpu.parallel.graph_partition import halo_extend
-
-                # extend the edge-state table with fresh (k->j) states from
-                # their owner shards; idx_kj already references this layout
-                x_kj = halo_extend(
-                    x_kj, ex["halo_send_edges"], self.partition_axis
+            lin_sbf2 = TorchLinear(
+                self.int_emb_size, use_bias=False, name="int_sbf2"
+            )
+            x_kj = act(TorchLinear(self.hidden_dim, name="int_lin_kj")(e))
+            x_kj = x_kj * rbf_b
+            x_kj = act(TorchLinear(self.int_emb_size, use_bias=False, name="int_down")(x_kj))
+            if bmm_mode:
+                x_kj = _bmm_triplet_aggregate(
+                    x_kj, rad, cbf, lin_sbf1, lin_sbf2, batch,
+                    self.num_spherical, self.num_radial,
                 )
-            x_kj = jnp.where(trip_mask[:, None], x_kj[idx_kj] * sbf_b, 0.0)
-            x_kj = segment_sum(x_kj, idx_ji, num_edges)
-        x_kj = act(TorchLinear(self.hidden_dim, use_bias=False, name="int_up")(x_kj))
-        hh = x_ji + x_kj
-        for bi in range(self.num_before_skip):
-            hh = ResidualLayer(self.hidden_dim, name=f"before_skip_{bi}")(hh)
-        hh = act(TorchLinear(self.hidden_dim, name="int_lin")(hh)) + e
-        for ai in range(self.num_after_skip):
-            hh = ResidualLayer(self.hidden_dim, name=f"after_skip_{ai}")(hh)
+            else:
+                idx_kj, idx_ji = ex["trip_kj"], ex["trip_ji"]
+                trip_mask = ex["trip_mask"]
+                sbf_b = lin_sbf2(lin_sbf1(sbf.astype(x_kj.dtype)))
+                if self.partition_axis is not None:
+                    from hydragnn_tpu.parallel.graph_partition import halo_extend
+
+                    # extend the edge-state table with fresh (k->j) states
+                    # from their owner shards; idx_kj already references
+                    # this layout
+                    x_kj = halo_extend(
+                        x_kj, ex["halo_send_edges"], self.partition_axis
+                    )
+                x_kj = jnp.where(trip_mask[:, None], x_kj[idx_kj] * sbf_b, 0.0)
+                x_kj = segment_sum(x_kj, idx_ji, num_edges)
+        with jax.named_scope("dimenet_update"):
+            x_ji = act(TorchLinear(self.hidden_dim, name="int_lin_ji")(e))
+            x_kj = act(TorchLinear(self.hidden_dim, use_bias=False, name="int_up")(x_kj))
+            hh = x_ji + x_kj
+            for bi in range(self.num_before_skip):
+                hh = ResidualLayer(self.hidden_dim, name=f"before_skip_{bi}")(hh)
+            hh = act(TorchLinear(self.hidden_dim, name="int_lin")(hh)) + e
+            for ai in range(self.num_after_skip):
+                hh = ResidualLayer(self.hidden_dim, name=f"after_skip_{ai}")(hh)
 
         # OutputPPBlock: edge states -> node states
-        o = TorchLinear(self.hidden_dim, use_bias=False, name="out_lin_rbf")(rbf) * hh
-        o = jnp.where(batch.edge_mask[:, None], o, 0.0)
-        if "nbr_edge" in ex and self.partition_axis is None:
-            # edges -> receivers through the neighbor-edge lists (each edge
-            # has exactly one receiver: group_sum applies)
-            from hydragnn_tpu.ops.dense_agg import group_sum
+        with jax.named_scope("dimenet_output"):
+            o = TorchLinear(self.hidden_dim, use_bias=False, name="out_lin_rbf")(rbf) * hh
+            o = jnp.where(batch.edge_mask[:, None], o, 0.0)
+            if "nbr_edge" in ex and self.partition_axis is None:
+                # edges -> receivers through the neighbor-edge lists (each
+                # edge has exactly one receiver: group_sum applies)
+                from hydragnn_tpu.ops.dense_agg import group_sum
 
-            o = group_sum(
-                o, ex["nbr_edge"], ex["nbr_mask"], i, batch.edge_mask
-            )
-        else:
-            o = segment_sum(o, i, n)
-        o = TorchLinear(self.out_emb_size, use_bias=False, name="out_up")(o)
-        o = act(TorchLinear(self.out_emb_size, name="out_0")(o))
-        o = TorchLinear(self.out_dim, use_bias=False, name="out_final")(o)
+                o = group_sum(
+                    o, ex["nbr_edge"], ex["nbr_mask"], i, batch.edge_mask
+                )
+            else:
+                o = segment_sum(o, i, n)
+            o = TorchLinear(self.out_emb_size, use_bias=False, name="out_up")(o)
+            o = act(TorchLinear(self.out_emb_size, name="out_0")(o))
+            o = TorchLinear(self.out_dim, use_bias=False, name="out_final")(o)
         return o, pos
 
 
@@ -495,6 +508,10 @@ class DIMEStack(HydraBase):
     num_spherical: int = 7
     radius: float = 2.0
     conv_use_batchnorm: bool = False  # Identity feature layers (DIMEStack.py:73)
+    # parameters a bf16 run keeps in f32 (``train/steps.py``): the Bessel
+    # frequencies n*pi, whose rounding to 8 bits would move the basis's
+    # zeros off the cutoff
+    f32_params = ("freq",)
 
     def _prepare_batch(self, batch):
         """Hoist the parameter-free geometry that every interaction block

@@ -28,12 +28,12 @@ CHOICES = ("segment", "dense")
 
 # Dense/segment crossovers: minimum hidden_dim at which the dense
 # scatter-free path beats segment reductions for each model. All rows but
-# EGNN's were measured on a v5e before the current tree (2026-07/08,
-# same-session A/Bs at deg ~12; not re-measured since, nor after PR 27
-# changed the dense path's cost — ROADMAP D11). Scatter-heavy models
-# (PNA's 4 aggregators, GAT's edge softmax, MFC's degree banks, DimeNet's
-# triplet axis) cross early; GIN/SAGE only win mildly at MXU widths;
-# SchNet never does (one already-fused scatter per layer).
+# EGNN's and DimeNet's were measured on a v5e before the current tree
+# (2026-07/08, same-session A/Bs at deg ~12; not re-measured since, nor
+# after PR 27 changed the dense path's cost — ROADMAP D11). Scatter-heavy
+# models (PNA's 4 aggregators, GAT's edge softmax, MFC's degree banks,
+# DimeNet's triplet axis) cross early; GIN/SAGE only win mildly at MXU
+# widths; SchNet never does (one already-fused scatter per layer).
 #
 # EGNN's row was read on THIS tree, 2026-10-03 (PR 29, one TPU v5 lite,
 # benchmarks/egnn_family_ab.py: the train step of egnn_h128x7_train_mptrj,
@@ -46,6 +46,17 @@ CHOICES = ("segment", "dense")
 # scatter a layer; so does an f32 run AT 128 (mixed_precision false:
 # 106.5 | 176.5), which is why the row holds for bf16 runs only
 # (DENSE_ROWS_READ_IN_BF16).
+#
+# DimeNet's row was read on THIS tree too, 2026-10-03 (PR 30, one TPU v5
+# lite, benchmarks/dimenet_family_ab.py: the train step of
+# dimenetpp_h128x4_train_mptrj, its traffic at rung 64, <= 32 neighbours,
+# hidden 128 x 4 blocks, dense_aggregation true | false, ms a step): f32
+# 30.012 | 149.371 (4.98 x), bf16 21.029 | 134.452 (6.39 x): the triplet
+# tables pay a gather, a scatter and their transposes per triplet and
+# layer (~25 triplets an edge), the slot grids one batched product per
+# central node; and the tables do not fit a memory-filling batch (the v5e
+# compiler refuses rung 96, 16.07 GB, where the lists take 10.7). The row
+# stands at 96 in either precision; only 128 was read.
 DENSE_AUTO_MIN_HIDDEN = {
     "PNA": 96,
     "GAT": 96,

@@ -127,10 +127,15 @@ def build_steps(
 
     guarded = guard_enabled(training_config)
 
+    # leaves a stack computes in f32 whatever the run's precision (by
+    # their name: DimeNet's Bessel frequencies)
+    keep_f32 = tuple(getattr(model, "f32_params", ()))
+
     def _cast_bf16(tree):
-        return jax.tree_util.tree_map(
-            lambda a: a.astype(jnp.bfloat16)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: a.astype(jnp.bfloat16)
             if hasattr(a, "dtype") and a.dtype == jnp.float32
+            and getattr(path[-1], "key", None) not in keep_f32
             else a,
             tree,
         )

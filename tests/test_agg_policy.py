@@ -231,7 +231,10 @@ def pytest_resolve_precision_policy():
         }
     finally:
         del os.environ["HYDRAGNN_MIXED_PRECISION"]
-    # DimeNet stays f32 under auto by policy
-    from hydragnn_tpu.models.create import BF16_AUTO_MIN_HIDDEN
+    # DimeNet computes in bf16 from 128 on under auto (read on the chip,
+    # PR 30), and stays f32 under it, as the whole zoo
+    from hydragnn_tpu.models.create import precision_for
 
-    assert "DimeNet" not in BF16_AUTO_MIN_HIDDEN
+    auto = {"mixed_precision": "auto"}
+    assert precision_for("DimeNet", 128, auto)["mixed"] is True
+    assert precision_for("DimeNet", 64, auto)["mixed"] is False
