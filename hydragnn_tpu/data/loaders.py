@@ -554,11 +554,13 @@ class GraphLoader:
     """Iterates padded batches; DistributedSampler-style sharding + epoch
     shuffling (``load_data.py:237-245``, ``train_validate_test.py:151-153``).
 
-    ``prefetch > 0`` collates ahead on a background thread (bounded queue) so
-    host-side batch assembly overlaps the device step — the role of the
-    reference's thread-pool ``HydraDataLoader`` (``load_data.py:94-204``);
-    XLA's async dispatch provides the other half of the overlap. The
-    ``HYDRAGNN_PREFETCH`` env var sets the default depth.
+    Collation runs ahead of the consumer on a background thread
+    (``graphloader-prefetch``, a bounded queue of ``prefetch`` batches, 2
+    unless the caller or ``HYDRAGNN_PREFETCH`` says otherwise) so host-side
+    batch assembly overlaps the trainer's transfer stage and the device
+    step — the role of the reference's thread-pool ``HydraDataLoader``
+    (``load_data.py:94-204``). ``prefetch=0`` collates inline, on whichever
+    thread iterates.
     """
 
     def __init__(
@@ -588,7 +590,7 @@ class GraphLoader:
         if prefetch is None:
             # validated parse: a typo'd HYDRAGNN_PREFETCH must name the
             # variable, not raise a bare int() ValueError mid-construction
-            prefetch = env_int("HYDRAGNN_PREFETCH", 0)
+            prefetch = env_int("HYDRAGNN_PREFETCH", 2)
         self.prefetch = prefetch
         self._plan_cache = None  # (epoch, plan) — packing is O(dataset)
         # contiguous_buckets: shuffle samples within buckets and the ORDER
@@ -738,6 +740,16 @@ class GraphLoader:
         self._plan_cache = (self.epoch, plan)
         return plan
 
+    def batch_keys(self):
+        """The epoch's batches in order, each named by what fixes every
+        leaf's shape (the first bucket with its layout; one layout: a
+        constant), known before anything is collated. Equal neighbours are
+        a run that ``Trainer._group_plan`` may stack."""
+        if isinstance(self.layout, BucketedLayout):
+            layouts = self.layout.layouts
+            return [layouts.index(layouts[b]) for b, _ in self._batch_plan()]
+        return [0] * len(self)
+
     def __len__(self):
         if isinstance(self.layout, BucketedLayout):
             return len(self._batch_plan())
@@ -860,6 +872,9 @@ class GraphLoader:
         if self.prefetch <= 0:
             yield from self._batches()
             return
+        # the collate stage of the input pipeline: its consumer (the
+        # trainer's transfer stage, or whoever iterates) gets each batch as
+        # it is finished, while the next one is being collated
         yield from prefetch_iter(
             self._batches(), self.prefetch, name="graphloader-prefetch"
         )

@@ -145,6 +145,25 @@ def stack_batches(batches):
     return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches)
 
 
+def stack_into(stacked, batch, index, count):
+    """:func:`stack_batches` one batch at a time: lay ``batch`` into row
+    ``index`` of ``stacked`` (``None`` on the first call, which makes it)
+    and return it. After ``count`` calls it equals ``stack_batches`` of the
+    batches, and the copying was done while the later ones were still being
+    collated (``Trainer._group_plan``)."""
+    import jax
+
+    if stacked is None:
+        stacked = jax.tree_util.tree_map(
+            lambda x: np.empty((count,) + np.shape(x), np.asarray(x).dtype),
+            batch,
+        )
+    jax.tree_util.tree_map(
+        lambda out, x: out.__setitem__(index, x), stacked, batch
+    )
+    return stacked
+
+
 def collate_graphs(
     samples,
     n_pad: int,

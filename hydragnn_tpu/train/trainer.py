@@ -16,6 +16,7 @@ format in ``transfer.py``, the predict paths in ``predict.py``
 module re-exports the public names so existing imports keep working.
 """
 
+import itertools
 import os
 from typing import Optional
 
@@ -23,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hydragnn_tpu.graph.batch import GraphBatch
+from hydragnn_tpu.graph.batch import GraphBatch, stack_batches, stack_into
 from hydragnn_tpu.obs import runtime as obs
 from hydragnn_tpu.models.create import init_model_params
 from hydragnn_tpu.train.common import (  # noqa: F401  (re-exported API)
@@ -49,6 +50,28 @@ from hydragnn_tpu.utils import tracer as tr
 # site re-traces on EVERY invocation (the jit cache keys on function object
 # identity) — one deep-copy program serves every fit_staged best-state seed
 _copy_tree = jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))
+
+
+def _goes_alone(loader, nbatch, K):
+    """The epoch's batches that are dispatched alone, by index: the tail of
+    a run of equal keys that is short of ``K`` (``Trainer._group_plan``).
+    ``None`` where the loader states no keys."""
+    keys_of = getattr(loader, "batch_keys", None)
+    if K == 1 or keys_of is None:
+        return None
+    alone, start = set(), 0
+    for _, run in itertools.groupby(keys_of()[:nbatch]):
+        n = sum(1 for _ in run)
+        alone.update(range(start + n - n % K, start + n))
+        start += n
+    return alone
+
+
+class _Group(list):
+    """The batches of one dispatch. ``stacked`` is their ``stack_batches``
+    where ``Trainer._group_plan`` laid them down as they arrived."""
+
+    stacked = None
 
 
 class Trainer(PredictMixin):
@@ -361,8 +384,6 @@ class Trainer(PredictMixin):
         :meth:`train_epoch_staged`. Use when the (padded) training set fits
         device memory — it removes host->device transfers from the training
         loop entirely, which otherwise bound small-graph workloads."""
-        from hydragnn_tpu.graph.batch import stack_batches
-
         batches = list(batches)
         obs.emit("staged", num_batches=len(batches))
         return self.put_batch_stacked(stack_batches(batches))
@@ -601,7 +622,17 @@ class Trainer(PredictMixin):
         Only FULL K-groups take the scan — a partial group would compile a
         fresh scan program per novel length (bucketed layouts hit this at
         every segment boundary) — so partial groups stream through the
-        single-step program."""
+        single-step program.
+
+        A loader that states its epoch's shapes before collating anything
+        (``GraphLoader.batch_keys``) says which batches end a run short of
+        ``K``: those are handed on the moment they arrive, and a batch of
+        a full group is laid into the group's stacked arrays on arrival
+        (``_Group.stacked``), so the transfer stage never sits on a
+        finished batch. Without the statement (a list, ``StreamLoader``) a
+        run's end is found by looking ahead: its partial group is held
+        until the next shape or the loader's end shows it, and a full one
+        is stacked at the put. The dispatches are the same."""
 
         def _shape_key(b):
             # ALL leaf shapes (incl. extras: triplet tables, neighbor
@@ -611,11 +642,16 @@ class Trainer(PredictMixin):
                 tuple(a.shape) for a in jax.tree_util.tree_leaves(b)
             )
 
-        pending = []
+        # asked BEFORE the loader's first batch: its collate thread then
+        # finds the epoch's plan cached
+        alone = _goes_alone(loader, nbatch, K)
+        pending = _Group()
         for ibatch, batch in enumerate(loader):
             if ibatch >= nbatch:
                 break
-            if K == 1:
+            if K == 1 or (
+                alone is not None and ibatch in alone and not pending
+            ):
                 yield [batch]
                 continue
             # bucketed layouts interleave batch shapes; a stack group must
@@ -623,24 +659,40 @@ class Trainer(PredictMixin):
             if pending and _shape_key(batch) != _shape_key(pending[0]):
                 for b in pending:
                     yield [b]
-                pending = []
+                pending = _Group()
             pending.append(batch)
+            if alone is not None:
+                # the plan says this run fills a group: the batch goes
+                # into the group's stacked arrays now, beside the collate
+                # of the next one, and not all K at the last one's arrival
+                with tr.span("stack_batch", index=len(pending) - 1):
+                    pending.stacked = stack_into(
+                        pending.stacked, batch, len(pending) - 1, K
+                    )
             if len(pending) == K:
                 yield pending
-                pending = []
+                pending = _Group()
         for b in pending:  # trailing partial group: single-step path
             yield [b]
 
     def _put_group(self, group):
         """Transfer stage: a group becomes (device_payload, count). Runs on
         the prefetch thread when ``device_prefetch > 0`` — so stacked
-        multi-step transfers double-buffer exactly like single batches."""
-        with tr.span("put_group", batches=len(group)) as span:
+        multi-step transfers double-buffer exactly like single batches —
+        behind the loader's collate thread (``GraphLoader.__iter__``): the
+        producer's pace is its slower stage, not their sum."""
+        # collate_open: whether the loader's thread was collating when this
+        # put began (the two stages overlap); its share over a window is the
+        # pipeline's overlap share
+        with tr.span(
+            "put_group", batches=len(group),
+            collate_open=tr.open_elsewhere("collate"),
+        ) as span:
             if len(group) > 1:
-                from hydragnn_tpu.graph.batch import stack_batches
-
-                with tr.span("stack_batches"):
-                    stacked = stack_batches(group)
+                stacked = getattr(group, "stacked", None)
+                if stacked is None:
+                    with tr.span("stack_batches"):
+                        stacked = stack_batches(group)
                 dev = self.put_batch_stacked(stacked)
             else:
                 dev = self.put_batch(group[0])

@@ -74,6 +74,7 @@ class _State:
         self.lock = threading.Lock()  # ring and totals
         self.ids = itertools.count(1)
         self.local = threading.local()  # .stack, .thread per thread
+        self.stacks = {}  # thread ident -> that thread's stack, live threads
 
     @property
     def live(self):
@@ -87,6 +88,13 @@ class _State:
         except AttributeError:
             local.stack = []
             local.thread = threading.current_thread().name
+            with self.lock:
+                # once a thread: threads that ended leave the table here
+                alive = {t.ident for t in threading.enumerate()}
+                self.stacks = {
+                    i: s for i, s in self.stacks.items() if i in alive
+                }
+                self.stacks[threading.get_ident()] = local.stack
             return local.stack
 
     def close(self, span):
@@ -243,6 +251,18 @@ def span(name, **counts) -> Span:
     host-side stage on whichever thread runs it. Never waits for the
     device."""
     return Span(name, counts)
+
+
+def open_elsewhere(name) -> bool:
+    """Whether a span called ``name`` is open on ANOTHER thread right now:
+    what one stage of a pipeline notes about the stage beside it (``put_group``
+    carries ``collate_open``). Reads the other threads' stacks as they are;
+    recording or not, a span is on its thread's stack while it is open."""
+    st = _state
+    me = threading.get_ident()
+    with st.lock:
+        others = [s for i, s in st.stacks.items() if i != me]
+    return any(sp.name == name for stack in others for sp in list(stack))
 
 
 def start(name, **counts) -> Span:

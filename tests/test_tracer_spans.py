@@ -270,8 +270,12 @@ def pytest_train_epoch_spans_and_counts_add_up(
     for child in ("compact", "h2d"):
         assert len(spans[child]) == len(puts)
         assert all(by_id[s.parent].name == "put_group" for s in spans[child])
-    assert len(spans.get("stack_batches", ())) == sum(
-        s.attrs["batches"] > 1 for s in puts)
+    # a group's batches are stacked at the put, or, where the loader states
+    # its plan (GraphLoader does), one by one as they arrive
+    grouped = sum(s.attrs["batches"] for s in puts if s.attrs["batches"] > 1)
+    assert "stack_batches" not in spans
+    assert len(spans.get("stack_batch", ())) == grouped
+    assert all(s.thread == puts[0].thread for s in spans.get("stack_batch", ()))
 
     if prefetch:
         assert {s.thread for s in collates} == {"graphloader-prefetch"}
