@@ -47,7 +47,7 @@ def step_reading(dense, bf16, graphs, cell, config, mix, rung, iters):
     config = copy.deepcopy(config)
     config["NeuralNetwork"]["Architecture"]["dense_aggregation"] = dense
     config["NeuralNetwork"]["Training"]["mixed_precision"] = bf16
-    work = tempfile.mkdtemp(prefix="dimenet_ab_", dir=os.environ.get("TMPDIR"))
+    work = tempfile.mkdtemp(prefix="family_ab_", dir=os.environ.get("TMPDIR"))
     os.chdir(work)
     paths = build.write_dataset(work, graphs, graphs[: mix["eval_graphs"]])
     cfg = build.hydragnn_config(config, mix, cell, paths, rung)
@@ -99,7 +99,9 @@ def step_reading(dense, bf16, graphs, cell, config, mix, rung, iters):
     return line
 
 
-def main():
+def main(cell_name=CELL):
+    """The four readings of ``cell_name`` (``gat_family_ab.py`` reads its
+    own cell through this)."""
     import build
     import traffic_gen
 
@@ -114,10 +116,10 @@ def main():
         raise SystemExit(f"needs a TPU, found {dev.platform}")
     out = args.out and os.path.abspath(args.out)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        cell, config, mix = build.load_cell(CELL, json.load(f))
+        cell, config, mix = build.load_cell(cell_name, json.load(f))
     rung = args.rung or build.batch_size_for(mix, cell["chips"])
     graphs = traffic_gen.make_graphs(mix, rung * mix["dataset_batches"], 0)
-    lines = [{"device": dev.device_kind, "cell": CELL, "rung": rung}]
+    lines = [{"device": dev.device_kind, "cell": cell_name, "rung": rung}]
     print(json.dumps(lines[0]), flush=True)
     for dense in (True, False):
         for bf16 in (False, True):

@@ -84,8 +84,14 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
         assert config.get("pna_deg") is not None, "PNA requires degree input."
         return PNAStack(deg=tuple(config["pna_deg"]), edge_dim=edge_dim, **common)
     if model_type == "GAT":
-        # reference hardcodes these (create.py:150-152)
-        return GATStack(heads=6, negative_slope=0.05, **common)
+        # the reference hardcodes 6 / 0.05 (create.py:150-152) and 0.25
+        # (Base.py's dropout); a config that states them is heard
+        return GATStack(
+            heads=config.get("heads", 6),
+            negative_slope=config.get("negative_slope", 0.05),
+            dropout=config.get("dropout", 0.25),
+            **common,
+        )
     if model_type == "MFC":
         assert (
             config.get("max_neighbours") is not None
@@ -177,6 +183,19 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
 # (models/dimenet.py, DIMEStack.f32_params); against the f32 reference the
 # cell's bf16 step reads grad_gap 0.013 where fp8 operands read 1.0
 # (PERF.md section 2).
+#
+# GAT's row was read on THIS tree, 2026-10-04 (PR 32, one TPU v5 lite,
+# benchmarks/gat_family_ab.py: the train step of gatv2_h4x256_train_oc20
+# at its rung of 256, 4 heads x 256 x 3 layers, ms a step, f32 | bf16):
+# dense lists 135.952 | 79.868 (1.70 x: the [N, 12, 1024] tables halve),
+# edge list 400.179 | 320.029 (1.25 x). That is a SPEED reading. Accuracy
+# (the cell's comparison against its f32 reference, first gradient, worst
+# leaf; PERF.md section 2): bf16 0.035-0.26 over 27 seeds, the f32 program
+# 0.003-0.007, f32 under HIGHEST products 3e-5, fp8 operands 1.0; scores,
+# the softmax and its denominator stay f32 in a bf16 run (models/gat.py),
+# so what bf16 costs is the rounding of weights and node states ahead of
+# a BatchNorm over a nearly constant layer-0 output. The row stands at
+# 128; only 256 was read.
 BF16_AUTO_MIN_HIDDEN = {
     "PNA": 128,
     "GAT": 128,

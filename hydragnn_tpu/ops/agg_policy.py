@@ -28,9 +28,9 @@ CHOICES = ("segment", "dense")
 
 # Dense/segment crossovers: minimum hidden_dim at which the dense
 # scatter-free path beats segment reductions for each model. All rows but
-# EGNN's and DimeNet's were measured on a v5e before the current tree
-# (2026-07/08, same-session A/Bs at deg ~12; not re-measured since, nor
-# after PR 27 changed the dense path's cost — ROADMAP D11). Scatter-heavy
+# EGNN's, DimeNet's and GAT's were measured on a v5e before the current
+# tree (2026-07/08, same-session A/Bs at deg ~12; not re-measured since,
+# nor after PR 27 changed the dense path's cost — ROADMAP D11). Scatter-heavy
 # models (PNA's 4 aggregators, GAT's edge softmax, MFC's degree banks,
 # DimeNet's triplet axis) cross early; GIN/SAGE only win mildly at MXU
 # widths; SchNet never does (one already-fused scatter per layer).
@@ -57,6 +57,17 @@ CHOICES = ("segment", "dense")
 # central node; and the tables do not fit a memory-filling batch (the v5e
 # compiler refuses rung 96, 16.07 GB, where the lists take 10.7). The row
 # stands at 96 in either precision; only 128 was read.
+#
+# GAT's row was read on THIS tree, 2026-10-04 (PR 32, one TPU v5 lite,
+# benchmarks/gat_family_ab.py: the train step of gatv2_h4x256_train_oc20,
+# its traffic at its rung of 256, 4 heads x 256 x 3 layers, degree 12,
+# dense_aggregation true | false, ms a step): f32 135.952 | 400.179
+# (2.94 x), bf16 79.868 | 320.029 (4.01 x). hidden_dim is the width PER
+# HEAD here: the tables the row decides about are heads x hidden_dim
+# wide (1,024 columns at this reading, which keep XLA's gather on the
+# dense side: window_halo refuses eight lane tiles), and the edge list
+# pays a 1,025-column scatter per head group and layer. The row stands at
+# 96; only 256 (x 4 heads) was read.
 DENSE_AUTO_MIN_HIDDEN = {
     "PNA": 96,
     "GAT": 96,
