@@ -10,13 +10,14 @@ evenly across processes with wrap-around padding).
 
 import bisect
 import os
-from dataclasses import dataclass, field
+from dataclasses import astuple, dataclass, field
 from typing import List, Optional, Tuple, Union
 
 import numpy as np
 
 from hydragnn_tpu.data.dataobj import GraphData
 from hydragnn_tpu.graph.batch import _round_up, collate_graphs, pad_sizes_for
+from hydragnn_tpu.graph.slots import SlotPool
 from hydragnn_tpu.ops.agg_policy import (
     arch_for_auto_policy,
     needs_dense_neighbors,
@@ -438,14 +439,22 @@ def padding_efficiency(datasets, layout, batch_size: int) -> float:
     return real / max(padded, 1)
 
 
-def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
+def collate_for_layout(
+    samples, layout: BatchLayout, with_targets: bool = True, slot=None
+):
     """Collate ``samples`` into the static shapes of ``layout``, including
     any model-specific extras (DimeNet triplet tables, dense neighbor
     lists). The ONE layout-aware collation path — the training loader and
     the serving request packer (``hydragnn_tpu/serve``) both route through
     here. ``with_targets=False`` packs inputs only (inference requests
     carry no labels). Each of its three parts is a span of the recorder
-    (``utils/tracer.py``), on whichever thread collates."""
+    (``utils/tracer.py``), on whichever thread collates.
+
+    ``slot`` (``graph/slots.py``) holds the arrays to write into, the
+    batch's leaves and the large temporaries, for a caller that will give
+    it back once the batch has been read (``GraphLoader.pooled``); without
+    it every array is fresh and the caller's to keep. The batch is bitwise
+    the same either way."""
     with tr.span("collate_graphs"):
         batch = collate_graphs(
             samples,
@@ -454,6 +463,7 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
             layout.g_pad,
             head_types=layout.head_types if with_targets else (),
             head_dims=layout.head_dims if with_targets else (),
+            slot=slot,
         )
     if layout.packs_triplets:
         from hydragnn_tpu.graph.batch import pack_triplets
@@ -468,7 +478,9 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
                 triplet_slots=layout.t_pad,
             )
             batch = batch.replace(
-                extras=pack_triplets(trips, layout.n_pad, layout.t_pad)
+                extras=pack_triplets(
+                    trips, layout.n_pad, layout.t_pad, slot=slot
+                )
             )
     if layout.need_neighbors:
         from hydragnn_tpu.ops.dense_agg import assemble_neighbor_lists
@@ -480,10 +492,14 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
             # request or an unretained streamed sample computes its own
             # here): the batch is never sorted
             built = sum(_cached_neighbor_slots(s) is None for s in samples)
-            slots = np.concatenate(
-                [_sample_neighbor_slots(s) for s in samples], axis=1
-            )
-            real = slots.shape[1]  # collate lays real edges down first
+            parts = [_sample_neighbor_slots(s) for s in samples]
+            real = sum(p.shape[1] for p in parts)  # laid down first
+            into = None
+            if slot is not None:
+                into = slot.array(
+                    "slots", (2, layout.e_pad), np.result_type(*parts)
+                )[:, :real]
+            slots = np.concatenate(parts, axis=1, out=into)
             nbr = assemble_neighbor_lists(
                 batch.senders[:real],
                 batch.receivers[:real],
@@ -493,6 +509,7 @@ def collate_for_layout(samples, layout: BatchLayout, with_targets: bool = True):
                 layout.k_in,
                 layout.k_out,
                 with_slot_tables=layout.need_triplets,
+                slot=slot,
             )
             span.set(slots_cached=len(samples) - built, slots_built=built)
             if layout.need_triplets:
@@ -629,6 +646,7 @@ class GraphLoader:
         self._sizes = None
         self._plain_nodes = None  # node counts cache for the plain layout
         self._padding_stats_cache = None  # (epoch, (real, padded))
+        self._pool = SlotPool()  # host buffers of pooled() batches
 
     def set_epoch(self, epoch: int):
         self.epoch = epoch
@@ -829,15 +847,22 @@ class GraphLoader:
         for start in range(0, len(idx), self.batch_size):
             yield (self.layout, idx[start : start + self.batch_size])
 
-    def _collate_task(self, task):
+    def _collate_task(self, task, pool=None):
         """Fetch and collate one batch: the ``collate`` span, with the
         counts the padding metrics read (real rows are those of the
-        samples, not of the padding graph that fills the layout)."""
+        samples, not of the padding graph that fills the layout) and
+        where its arrays came from (``slot``: ``"fresh"`` without a pool,
+        else the slot's ``"made"`` / ``"reused"``). With a pool the batch
+        comes with its slot, for the reader to give back."""
         layout, chunk = task
+        slot = None
+        if pool is not None:
+            # what fixes every leaf's shape names the slot
+            slot = pool.acquire(("batch",) + astuple(layout))
         with tr.span("collate") as span:
             with tr.span("fetch"):
                 samples = [self.dataset[i] for i in chunk]
-            batch = _collate_with_extras(samples, layout)
+            batch = _collate_with_extras(samples, layout, slot=slot)
             g = len(samples)
             span.set(
                 graphs=g,
@@ -845,14 +870,35 @@ class GraphLoader:
                 edges=int(batch.n_edge[:g].sum()),
                 bucket=int(layout.n_pad),
                 e_pad=int(layout.e_pad),
+                slot="fresh" if slot is None else slot.state,
             )
-        return batch
+        return batch if pool is None else (batch, slot)
 
-    def _batches(self):
+    def _batches(self, pool=None):
         for task in self._batch_tasks():
-            yield self._collate_task(task)
+            yield self._collate_task(task, pool)
 
     def __iter__(self):
+        """Batches made of fresh arrays, the consumer's to keep."""
+        return self._iterate(None)
+
+    def pooled(self):
+        """``(batch, slot)`` pairs for the consumer that holds the release
+        end (the trainer's transfer stage): each batch is written into a
+        slot of the loader's pool (``graph/slots.py``), which the consumer
+        gives back (``slot.release()``) once nothing reads the batch's
+        arrays any more. A slot that is not given back is not handed out
+        again. The pool lives as long as the loader; its slots are made as
+        they are first needed."""
+        return self._iterate(self._pool)
+
+    def pool_counts(self):
+        """``{"reused", "made", "bytes"}`` of the pool so far
+        (``SlotPool.counts``); zeros while nobody has asked for
+        :meth:`pooled` batches."""
+        return self._pool.counts()
+
+    def _iterate(self, pool):
         # HYDRAGNN_NUM_WORKERS > 1: fan sample fetch + collation over a
         # worker pool (ordered), optionally core-pinned via OMP_PLACES +
         # HYDRAGNN_AFFINITY — the reference HydraDataLoader's thread-pool
@@ -864,19 +910,19 @@ class GraphLoader:
             yield from prefetch_iter(
                 self._batch_tasks(),
                 max(self.prefetch, workers),
-                fn=self._collate_task,
+                fn=lambda task: self._collate_task(task, pool),
                 workers=workers,
                 name="graphloader-worker",
             )
             return
         if self.prefetch <= 0:
-            yield from self._batches()
+            yield from self._batches(pool)
             return
         # the collate stage of the input pipeline: its consumer (the
         # trainer's transfer stage, or whoever iterates) gets each batch as
         # it is finished, while the next one is being collated
         yield from prefetch_iter(
-            self._batches(), self.prefetch, name="graphloader-prefetch"
+            self._batches(pool), self.prefetch, name="graphloader-prefetch"
         )
 
 

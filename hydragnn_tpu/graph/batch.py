@@ -24,6 +24,8 @@ import numpy as np
 import jax.numpy as jnp
 from flax import struct
 
+from hydragnn_tpu.graph.slots import filled
+
 
 @struct.dataclass
 class GraphBatch:
@@ -87,7 +89,9 @@ def pad_sizes_for(
     return n_pad, e_pad, g_pad
 
 
-def pack_triplets(triplets, n_pad: int, t_pad: Optional[int] = None):
+def pack_triplets(
+    triplets, n_pad: int, t_pad: Optional[int] = None, slot=None
+):
     """Pack per-sample DimeNet triplet tables into one padded extras dict.
 
     ``triplets``: list of ``(t_i, t_j, t_k, t_kj, t_ji, n_nodes, n_edges)``
@@ -95,19 +99,21 @@ def pack_triplets(triplets, n_pad: int, t_pad: Optional[int] = None):
     ``collate_graphs`` lays the samples out). Padded triplet slots point at
     the padding node ``n_pad - 1`` with mask False. ``t_pad`` defaults to
     the total rounded up to 8. The ONE canonical packer — the loader, the
-    benches and the driver entry all route through here.
+    benches and the driver entry all route through here. ``slot``
+    (``graph/slots.py``) gives the tables to fill, reset to what fresh ones
+    hold; without it they are allocated.
     """
     total = sum(t[0].shape[0] for t in triplets)
     if t_pad is None:
         t_pad = _round_up(max(total, 1), 8)
     if total > t_pad:
         raise ValueError(f"{total} triplets exceed t_pad={t_pad}")
-    ti = np.full((t_pad,), n_pad - 1, np.int32)
-    tj = np.full((t_pad,), n_pad - 1, np.int32)
-    tk = np.full((t_pad,), n_pad - 1, np.int32)
-    tkj = np.zeros((t_pad,), np.int32)
-    tji = np.zeros((t_pad,), np.int32)
-    tmask = np.zeros((t_pad,), bool)
+    ti = filled(slot, "trip_i", (t_pad,), np.int32, n_pad - 1)
+    tj = filled(slot, "trip_j", (t_pad,), np.int32, n_pad - 1)
+    tk = filled(slot, "trip_k", (t_pad,), np.int32, n_pad - 1)
+    tkj = filled(slot, "trip_kj", (t_pad,), np.int32)
+    tji = filled(slot, "trip_ji", (t_pad,), np.int32)
+    tmask = filled(slot, "trip_mask", (t_pad,), bool)
     off_n = off_e = off_t = 0
     for a, b, c, kj, ji, n_nodes, n_edges in triplets:
         t = a.shape[0]
@@ -145,19 +151,24 @@ def stack_batches(batches):
     return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *batches)
 
 
-def stack_into(stacked, batch, index, count):
+def stack_into(stacked, batch, index, count, slot=None):
     """:func:`stack_batches` one batch at a time: lay ``batch`` into row
-    ``index`` of ``stacked`` (``None`` on the first call, which makes it)
-    and return it. After ``count`` calls it equals ``stack_batches`` of the
-    batches, and the copying was done while the later ones were still being
-    collated (``Trainer._group_plan``)."""
+    ``index`` of ``stacked`` (``None`` on the first call, which makes it,
+    out of ``slot``'s arrays where one is given) and return it. After
+    ``count`` calls it equals ``stack_batches`` of the batches, and the
+    copying was done while the later ones were still being collated
+    (``Trainer._group_plan``)."""
     import jax
 
     if stacked is None:
-        stacked = jax.tree_util.tree_map(
-            lambda x: np.empty((count,) + np.shape(x), np.asarray(x).dtype),
-            batch,
-        )
+        leaves, treedef = jax.tree_util.tree_flatten(batch)
+        stacked = treedef.unflatten([
+            filled(
+                slot, f"stack/{i}", (count,) + np.shape(x),
+                np.asarray(x).dtype, None,
+            )
+            for i, x in enumerate(leaves)
+        ])
     jax.tree_util.tree_map(
         lambda out, x: out.__setitem__(index, x), stacked, batch
     )
@@ -172,6 +183,7 @@ def collate_graphs(
     head_types: Tuple[str, ...] = (),
     head_dims: Tuple[int, ...] = (),
     to_device: bool = False,
+    slot=None,
 ):
     """Collate a list of ``GraphData``-like samples into one padded batch.
 
@@ -181,7 +193,9 @@ def collate_graphs(
     node head: ``[n, d]``).
 
     Runs on the host in numpy: this is the producer side of the input
-    pipeline; the arrays are shipped to HBM once per step.
+    pipeline; the arrays are shipped to HBM once per step. ``slot``
+    (``graph/slots.py``) gives the arrays to fill, each reset to exactly
+    what a fresh one holds; without it they are allocated.
     """
     num_graphs = len(samples)
     total_nodes = int(sum(s.x.shape[0] for s in samples))
@@ -194,29 +208,30 @@ def collate_graphs(
         raise ValueError(f"{total_edges} edges exceed e_pad={e_pad}")
 
     feat_dim = samples[0].x.shape[1]
-    x = np.zeros((n_pad, feat_dim), dtype=np.float32)
-    pos = np.zeros((n_pad, 3), dtype=np.float32)
+    x = filled(slot, "x", (n_pad, feat_dim), np.float32)
+    pos = filled(slot, "pos", (n_pad, 3), np.float32)
     # padding edges point at the last node slot (always a padding node since
     # total_nodes <= n_pad - 1) and live in the padding graph.
-    senders = np.full((e_pad,), n_pad - 1, dtype=np.int32)
-    receivers = np.full((e_pad,), n_pad - 1, dtype=np.int32)
+    senders = filled(slot, "senders", (e_pad,), np.int32, n_pad - 1)
+    receivers = filled(slot, "receivers", (e_pad,), np.int32, n_pad - 1)
     edge_dim = None
     if samples[0].edge_attr is not None:
         edge_dim = samples[0].edge_attr.shape[1]
-        edge_attr = np.zeros((e_pad, edge_dim), dtype=np.float32)
-    node_graph = np.full((n_pad,), g_pad - 1, dtype=np.int32)
-    n_node = np.zeros((g_pad,), dtype=np.int32)
-    n_edge = np.zeros((g_pad,), dtype=np.int32)
-    node_mask = np.zeros((n_pad,), dtype=bool)
-    edge_mask = np.zeros((e_pad,), dtype=bool)
-    graph_mask = np.zeros((g_pad,), dtype=bool)
+        edge_attr = filled(slot, "edge_attr", (e_pad, edge_dim), np.float32)
+    node_graph = filled(slot, "node_graph", (n_pad,), np.int32, g_pad - 1)
+    n_node = filled(slot, "n_node", (g_pad,), np.int32)
+    n_edge = filled(slot, "n_edge", (g_pad,), np.int32)
+    node_mask = filled(slot, "node_mask", (n_pad,), bool)
+    edge_mask = filled(slot, "edge_mask", (e_pad,), bool)
+    graph_mask = filled(slot, "graph_mask", (g_pad,), bool)
 
-    targets = []
-    for t, d in zip(head_types, head_dims):
-        if t == "graph":
-            targets.append(np.zeros((g_pad, d), dtype=np.float32))
-        else:
-            targets.append(np.zeros((n_pad, d), dtype=np.float32))
+    targets = [
+        filled(
+            slot, f"target{ih}", (g_pad if t == "graph" else n_pad, d),
+            np.float32,
+        )
+        for ih, (t, d) in enumerate(zip(head_types, head_dims))
+    ]
 
     node_off = 0
     edge_off = 0

@@ -69,6 +69,11 @@ EVENT_FIELDS: Dict[str, tuple] = {
     # scatter/...): gather = choice = onehot|xla, h the window's halo in
     # blocks
     "agg_choice": ("bucket", "choice", "source"),
+    # the input pipeline's host buffers (graph/slots.py), one per
+    # train_epoch over a loader that pools: slots acquired in the epoch
+    # that had been used before / were made new (collated batches and
+    # group stacks alike), and the bytes of every slot alive at its end
+    "pool": ("reused", "made", "bytes"),
     # elastic training (train/elastic.py): a peer's heartbeat lease
     # expired — emitted by the detecting watchdog just before it breaks
     # the survivors out of the hung collective

@@ -117,25 +117,44 @@ def assemble_neighbor_lists(
     k_out: int,
     with_slot_tables: bool = False,
     rows: Optional[np.ndarray] = None,
+    slot=None,
 ):
     """The extras dict of :func:`build_neighbor_lists` from known per-edge
     ``slots [2, E]`` (:func:`edge_slots`), by direct indexed writes into
     zero-initialised arrays — no sort. ``senders``/``receivers``/``slots``
     cover the REAL edges only; ``rows`` are their rows in the edge table of
     ``num_edge_rows`` rows (None: the first ``E`` rows, the collate
-    contract). Padded slots hold index 0, mask False."""
+    contract). Padded slots hold index 0, mask False. ``slot``
+    (``graph/slots.py``) gives the lists to fill, zeroed again, and the
+    scratch the ``E``-row temporaries are written into (sized at
+    ``num_edge_rows``, which bounds ``E``); without it all are allocated."""
+    from hydragnn_tpu.graph.slots import filled
+
     _check_slots(slots[0], k_in, "k_in")
     _check_slots(slots[1], k_out, "k_out")
+    e = senders.shape[0]
+    # a slot's scratch is sized once, at the bound; fresh scratch at E
+    cap = e if slot is None else num_edge_rows
     if rows is None:
-        rows = np.arange(senders.shape[0], dtype=np.int32)
+        if slot is None:
+            rows = np.arange(e, dtype=np.int32)
+        else:
+            rows = slot.array(
+                "rows", (cap,), np.int32,
+                make=lambda: np.arange(cap, dtype=np.int32),
+            )[:e]
     # flat [N*K_in] / [N*K_out] dense slot of every real edge
-    flat_in = receivers.astype(np.intp) * k_in + slots[0]
-    flat_out = senders.astype(np.intp) * k_out + slots[1]
-    nbr_idx = np.zeros((num_nodes, k_in), np.int32)
-    nbr_edge = np.zeros((num_nodes, k_in), np.int32)
-    nbr_mask = np.zeros((num_nodes, k_in), bool)
-    rev_idx = np.zeros((num_nodes, k_out), np.int32)
-    rev_mask = np.zeros((num_nodes, k_out), bool)
+    flat_in = filled(slot, "flat_in", (cap,), np.intp, None)[:e]
+    flat_out = filled(slot, "flat_out", (cap,), np.intp, None)[:e]
+    np.multiply(receivers, k_in, out=flat_in, dtype=np.intp)
+    np.add(flat_in, slots[0], out=flat_in, dtype=np.intp)
+    np.multiply(senders, k_out, out=flat_out, dtype=np.intp)
+    np.add(flat_out, slots[1], out=flat_out, dtype=np.intp)
+    nbr_idx = filled(slot, "nbr_idx", (num_nodes, k_in), np.int32)
+    nbr_edge = filled(slot, "nbr_edge", (num_nodes, k_in), np.int32)
+    nbr_mask = filled(slot, "nbr_mask", (num_nodes, k_in), bool)
+    rev_idx = filled(slot, "rev_idx", (num_nodes, k_out), np.int32)
+    rev_mask = filled(slot, "rev_mask", (num_nodes, k_out), bool)
     nbr_idx.reshape(-1)[flat_in] = senders
     nbr_edge.reshape(-1)[flat_in] = rows
     nbr_mask.reshape(-1)[flat_in] = True
@@ -152,10 +171,10 @@ def assemble_neighbor_lists(
         # out_slot is the inverse permutation of out_edge — the bmm-triplet
         # path routes per-(sender, out-slot) results back onto the edge
         # table with it
-        out_edge = np.zeros((num_nodes, k_out), np.int32)
+        out_edge = filled(slot, "out_edge", (num_nodes, k_out), np.int32)
         out_edge.reshape(-1)[flat_out] = rows
-        edge_slot = np.zeros(num_edge_rows, np.int32)
-        out_slot = np.zeros(num_edge_rows, np.int32)
+        edge_slot = filled(slot, "edge_slot", (num_edge_rows,), np.int32)
+        out_slot = filled(slot, "out_slot", (num_edge_rows,), np.int32)
         edge_slot[rows] = flat_in
         out_slot[rows] = flat_out
         out.update(out_edge=out_edge, edge_slot=edge_slot, out_slot=out_slot)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from hydragnn_tpu.graph.batch import GraphBatch, stack_batches, stack_into
+from hydragnn_tpu.graph.slots import filled
 from hydragnn_tpu.obs import runtime as obs
 from hydragnn_tpu.models.create import init_model_params
 from hydragnn_tpu.train.common import (  # noqa: F401  (re-exported API)
@@ -69,9 +70,82 @@ def _goes_alone(loader, nbatch, K):
 
 class _Group(list):
     """The batches of one dispatch. ``stacked`` is their ``stack_batches``
-    where ``Trainer._group_plan`` laid them down as they arrived."""
+    where ``Trainer._group_plan`` laid them down as they arrived; ``slots``
+    are the pool's buffers (``graph/slots.py``) that what will be put is
+    made of, for the put stage to give back once the transfer has read
+    them."""
 
     stacked = None
+    slots = ()
+
+    def singles(self):
+        """Its batches one by one, for a group that a new shape or the
+        loader's end found short: each takes its own slot along. Where
+        they were stacked on arrival they are rows of the stacked arrays,
+        whose slot then stays out of the pool."""
+        own = self.slots if self.stacked is None else ()
+        for i, batch in enumerate(self):
+            yield _single(batch, own[i] if own else None)
+
+
+def _pooled(loader):
+    """``(batch, slot)`` pairs: the loader's own where it hands out the
+    release end (``GraphLoader.pooled``), else every slot ``None`` (a list,
+    ``StreamLoader``: fresh arrays, as they come)."""
+    pooled = getattr(loader, "pooled", None)
+    if pooled is None:
+        return ((batch, None) for batch in loader)
+    return pooled()
+
+
+def _pool_counts(loader):
+    """The loader's pool counters (``SlotPool.counts``), None for a loader
+    that has no pool."""
+    counts = getattr(loader, "pool_counts", None)
+    return None if counts is None else counts()
+
+
+def _single(batch, slot):
+    group = _Group([batch])
+    if slot is not None:
+        group.slots = (slot,)
+    return group
+
+
+def _taken_by_device(host, dev):
+    """The host arrays that a device array ALIASES: the CPU backend takes a
+    64-byte-aligned numpy buffer as it is (zero copy), for the device
+    array's whole life. Decided by looking: a device array of another
+    platform lives in its own memory; one on the CPU is asked where."""
+    devs = jax.tree_util.tree_leaves(dev)
+    if not devs or next(iter(devs[0].devices())).platform != "cpu":
+        return []
+    taken = []
+    for h, d in zip(jax.tree_util.tree_leaves(host), devs):
+        if not isinstance(h, np.ndarray) or not h.nbytes:
+            continue
+        lo = h.ctypes.data
+        if any(
+            lo <= shard.data.unsafe_buffer_pointer() < lo + h.nbytes
+            for shard in d.addressable_shards
+        ):
+            taken.append(h)
+    return taken
+
+
+def _give_back(slots, host, dev):
+    """The release rule of the put stage. ``jnp.asarray`` / ``device_put``
+    of a numpy array return before the runtime has read it (jax 0.9.0,
+    TPU v5e and CPU alike: an overwrite right after the call shows in the
+    device copy; PERF.md section 6, PR 33), so the slots go back only after
+    the transfer has completed, waited for on the thread that put
+    (``h2d_wait``). An array the device took for its own leaves its slot
+    first."""
+    with tr.span("h2d_wait"):
+        jax.block_until_ready(dev)
+    taken = _taken_by_device(host, dev)
+    for slot in slots:
+        slot.release(forget=taken)
 
 
 class Trainer(PredictMixin):
@@ -138,6 +212,9 @@ class Trainer(PredictMixin):
         # process-global optimizer-step counter: drives the fault-injection
         # hooks (kill_at_step / nan_at_step, utils/faults.py)
         self._host_step = 0
+        # the last put whose host arrays are the pool's: (slots, host, dev)
+        # until its transfer is known to have completed (``_settle``)
+        self._in_flight = None
 
     # compiled-program accessors: tests and the partitioned trainer reach
     # these by their historical names
@@ -259,7 +336,8 @@ class Trainer(PredictMixin):
         return self._zero_stage() >= 1
 
     def _compact_for_transfer(
-        self, batch: GraphBatch, allow_pos_placeholder: bool = True
+        self, batch: GraphBatch, allow_pos_placeholder: bool = True,
+        slot=None,
     ):
         """Shrink the host->device wire format (streaming is H2D-bound;
         undone INSIDE the jitted step by ``_decompact_traced``):
@@ -276,6 +354,7 @@ class Trainer(PredictMixin):
         Applies to single-process transfers (plain and mesh-sharded); the
         multi-host assembly path ships uncompacted. ``compact_transfer`` /
         ``HYDRAGNN_COMPACT_TRANSFER`` (default on) disables it entirely.
+        ``slot``: the pool's buffer to write the copies into.
         """
         if not _env_flag(
             "HYDRAGNN_COMPACT_TRANSFER", self.training_config,
@@ -285,16 +364,25 @@ class Trainer(PredictMixin):
         # shape[-2] of x is the node count for both plain [N, F] and
         # stacked [K, N, F] layouts; n_node's last axis is the graph count
         if batch.x.shape[-2] < 2**15 and batch.n_node.shape[-1] < 2**15:
+
+            def int16(name):
+                wide = np.asarray(getattr(batch, name))
+                out = filled(slot, "wire/" + name, wide.shape, np.int16, None)
+                np.copyto(out, wide, casting="unsafe")
+                return out
+
             batch = batch.replace(
-                senders=np.asarray(batch.senders, np.int16),
-                receivers=np.asarray(batch.receivers, np.int16),
-                node_graph=np.asarray(batch.node_graph, np.int16),
+                senders=int16("senders"),
+                receivers=int16("receivers"),
+                node_graph=int16("node_graph"),
             )
         needs_pos = getattr(self.model, "conv_needs_pos", True) or getattr(
             self.model, "equivariance", False
         )
         if not needs_pos and allow_pos_placeholder:
-            placeholder = np.zeros(batch.pos.shape[:-2] + (1, 3), np.float32)
+            placeholder = filled(
+                slot, "wire/pos", batch.pos.shape[:-2] + (1, 3), np.float32
+            )
             batch = batch.replace(pos=placeholder)
         return batch
 
@@ -317,16 +405,21 @@ class Trainer(PredictMixin):
         ``data``."""
         return self._put(stacked, stacked=True)
 
-    def _put(self, batch: GraphBatch, stacked: bool) -> GraphBatch:
+    def _put(self, batch: GraphBatch, stacked: bool, slots=()) -> GraphBatch:
         """The one transfer path, two spans of the recorder: ``compact``,
         the host-side shaping of the wire format (the multi-host path
         offsets its local shard there instead), and ``h2d``, the
         ``device_put`` tree-map. ``h2d`` measures the HOST's side of the
         put — staging and enqueue; the transfer itself is the device's and
-        shows in a profiler trace, not here."""
+        shows in a profiler trace, not here. ``slots`` are the pool's
+        buffers ``batch`` is made of: the wire format is written into the
+        first, and all go back by the rule of :func:`_give_back`, one put
+        later (:meth:`_settle`)."""
+        slot = slots[0] if slots else None
         with tr.span("compact"):
             if self.mesh is None:
-                host, put = self._compact_for_transfer(batch), jnp.asarray
+                host = self._compact_for_transfer(batch, slot=slot)
+                put = jnp.asarray
             else:
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -358,13 +451,28 @@ class Trainer(PredictMixin):
                         )
                 else:
                     host = self._compact_for_transfer(
-                        batch, allow_pos_placeholder=False
+                        batch, allow_pos_placeholder=False, slot=slot
                     )
 
                     def put(a):
                         return jax.device_put(jnp.asarray(a), sharding)
         with tr.span("h2d"):
-            return jax.tree_util.tree_map(put, host)
+            dev = jax.tree_util.tree_map(put, host)
+        if slots:
+            # the put before this one has had this one's time to cross:
+            # its wait (``h2d_wait``) comes after this enqueue, so the
+            # transfers follow each other without the host between them
+            self._settle()
+            self._in_flight = (slots, host, dev)
+        return dev
+
+    def _settle(self):
+        """Give the last pooled put's slots back (:func:`_give_back`): at
+        the next pooled put, and at the end of the epoch loop, whose put
+        stage has finished by then."""
+        in_flight, self._in_flight = self._in_flight, None
+        if in_flight is not None:
+            _give_back(*in_flight)
 
     # ---- compiled steps ------------------------------------------------
     def _build_steps(self):
@@ -646,34 +754,49 @@ class Trainer(PredictMixin):
         # finds the epoch's plan cached
         alone = _goes_alone(loader, nbatch, K)
         pending = _Group()
-        for ibatch, batch in enumerate(loader):
+        for ibatch, (batch, slot) in enumerate(_pooled(loader)):
             if ibatch >= nbatch:
                 break
             if K == 1 or (
                 alone is not None and ibatch in alone and not pending
             ):
-                yield [batch]
+                yield _single(batch, slot)
                 continue
             # bucketed layouts interleave batch shapes; a stack group must
             # be shape-uniform, so a shape change flushes the open group
             if pending and _shape_key(batch) != _shape_key(pending[0]):
-                for b in pending:
-                    yield [b]
+                yield from pending.singles()
                 pending = _Group()
             pending.append(batch)
-            if alone is not None:
+            if alone is None:
+                pending.slots += () if slot is None else (slot,)
+            else:
                 # the plan says this run fills a group: the batch goes
                 # into the group's stacked arrays now, beside the collate
                 # of the next one, and not all K at the last one's arrival
-                with tr.span("stack_batch", index=len(pending) - 1):
+                index = len(pending) - 1
+                with tr.span("stack_batch", index=index) as span:
+                    if index == 0 and slot is not None:
+                        pending.slots = (
+                            slot.pool.acquire(("stack", K) + slot.key),
+                        )
+                    into = pending.slots[0] if pending.slots else None
                     pending.stacked = stack_into(
-                        pending.stacked, batch, len(pending) - 1, K
+                        pending.stacked, batch, index, K, slot=into
                     )
+                    span.set(slot="fresh" if into is None else into.state)
+                if slot is not None:
+                    # the batch lives in the group's arrays from here on,
+                    # and its own slot is the next collate's
+                    pending[-1] = jax.tree_util.tree_map(
+                        lambda a: a[index], pending.stacked
+                    )
+                    slot.release()
             if len(pending) == K:
                 yield pending
                 pending = _Group()
-        for b in pending:  # trailing partial group: single-step path
-            yield [b]
+        # trailing partial group: single-step path
+        yield from pending.singles()
 
     def _put_group(self, group):
         """Transfer stage: a group becomes (device_payload, count). Runs on
@@ -684,18 +807,25 @@ class Trainer(PredictMixin):
         # collate_open: whether the loader's thread was collating when this
         # put began (the two stages overlap); its share over a window is the
         # pipeline's overlap share
+        slots = getattr(group, "slots", ())
         with tr.span(
             "put_group", batches=len(group),
             collate_open=tr.open_elsewhere("collate"),
+            slot=slots[0].state if slots else "fresh",
         ) as span:
             if len(group) > 1:
                 stacked = getattr(group, "stacked", None)
                 if stacked is None:
                     with tr.span("stack_batches"):
                         stacked = stack_batches(group)
-                dev = self.put_batch_stacked(stacked)
+                    # the batches have been read; the stack is fresh
+                    for slot in slots:
+                        slot.release()
+                    slots = ()
+                    span.set(slot="fresh")
+                dev = self._put(stacked, stacked=True, slots=slots)
             else:
-                dev = self.put_batch(group[0])
+                dev = self._put(group[0], stacked=False, slots=slots)
             # what device_put was handed: the arrays keep dtype and shape
             span.set(bytes=sum(
                 int(a.nbytes) for a in jax.tree_util.tree_leaves(dev)
@@ -718,6 +848,7 @@ class Trainer(PredictMixin):
         # resolved once per epoch: the per-step telemetry hooks must cost
         # one global read when observability is off
         _telemetry = obs.active()
+        pool_before = _pool_counts(loader)
         plan = self._group_plan(loader, nbatch, K)
         for dev, count in self._prefetch_put(
             plan, float("inf"), self.device_prefetch, put=self._put_group,
@@ -789,9 +920,19 @@ class Trainer(PredictMixin):
                 faults.lose_host_at_step(self._host_step)
                 self._host_step += 1
                 elastic.note_step(self._host_step)
+        self._settle()
         with tr.span("epoch_readback", dispatches=len(acc or ())):
             loss, tasks = self._acc_read(acc)  # the epoch's one readback
         tr.stop("train")
+        if _telemetry is not None and pool_before is not None:
+            # how often the epoch's host buffers were the pool's own
+            now = _pool_counts(loader)
+            _telemetry.emit(
+                "pool",
+                reused=now["reused"] - pool_before["reused"],
+                made=now["made"] - pool_before["made"],
+                bytes=now["bytes"],
+            )
         return state, rng, loss, tasks
 
     def evaluate(self, state, loader, desc="validate"):
@@ -817,5 +958,6 @@ class Trainer(PredictMixin):
                     state.params, state.batch_stats, dev
                 )
                 acc = self._acc_add(acc, metrics, multi=False)
+        self._settle()
         with tr.span("epoch_readback", dispatches=len(acc or ())):
             return self._acc_read(acc)

@@ -223,6 +223,13 @@ def pytest_stages_sit_on_their_threads_and_puts_note_the_collate(recorder):
     assert {s.thread for s in collates} == {"graphloader-prefetch"}
     assert {s.thread for s in puts} == {"hydragnn-device-prefetch"}
     assert all(type(s.attrs["collate_open"]) is bool for s in puts)
+    # the trainer's transfer stage holds the release end: every batch and
+    # every group's stack is written into one of the pool's slots
+    stacks = [s for s in records if s.name == "stack_batch"]
+    assert stacks
+    for s in collates + puts + stacks:
+        assert s.attrs["slot"] in ("made", "reused")
+    assert "made" in {s.attrs["slot"] for s in collates}
     # the dispatches are the plan's: full pairs stacked, run tails alone
     want = _dispatches(
         Trainer._group_plan(_fake_batches(loader.batch_keys()), len(loader), 2))

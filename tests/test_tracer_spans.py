@@ -257,6 +257,11 @@ def pytest_train_epoch_spans_and_counts_add_up(
     assert sum(s.attrs["edges"] for s in collates) == sum(
         d.num_edges for d in ds)
     assert all(s.attrs["e_pad"] == layout.e_pad for s in collates)
+    # the trainer holds the release end, so every batch is written into a
+    # slot of the loader's pool; one epoch has run: this one makes none
+    assert {s.attrs["slot"] for s in collates} <= {"reused", "made"}
+    if not prefetch:
+        assert {s.attrs["slot"] for s in collates} == {"reused"}
     by_id = {s.id: s for s in records}
     for child in ("fetch", "collate_graphs"):
         assert len(spans[child]) == nbatch
@@ -270,12 +275,22 @@ def pytest_train_epoch_spans_and_counts_add_up(
     for child in ("compact", "h2d"):
         assert len(spans[child]) == len(puts)
         assert all(by_id[s.parent].name == "put_group" for s in spans[child])
+    # ... and gives a put's slots back once its transfer has completed,
+    # waited for after the NEXT put's enqueue; the last one's by the epoch
+    # loop
+    waits = spans["h2d_wait"]
+    assert len(waits) == len(puts)
+    assert [by_id[s.parent].name for s in waits] == (
+        ["put_group"] * (len(puts) - 1) + ["train"])
+    assert {s.attrs["slot"] for s in puts} <= {"reused", "made"}
     # a group's batches are stacked at the put, or, where the loader states
     # its plan (GraphLoader does), one by one as they arrive
     grouped = sum(s.attrs["batches"] for s in puts if s.attrs["batches"] > 1)
     assert "stack_batches" not in spans
     assert len(spans.get("stack_batch", ())) == grouped
     assert all(s.thread == puts[0].thread for s in spans.get("stack_batch", ()))
+    assert {s.attrs["slot"] for s in spans.get("stack_batch", ())} <= {
+        "reused", "made"}
 
     if prefetch:
         assert {s.thread for s in collates} == {"graphloader-prefetch"}
