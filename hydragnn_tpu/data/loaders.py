@@ -171,22 +171,31 @@ def _sample_stats(datasets, need_triplets, need_neighbors):
     whole dataset would be pure startup waste."""
     nodes, edges, trips_n, kis, kos = [], [], [], [], []
     first = None
-    for ds in datasets:
-        for d in ds:
-            first = first or d
-            nodes.append(d.num_nodes)
-            edges.append(d.num_edges)
-            t = ki = ko = 0
-            if need_triplets and not need_neighbors:
-                trips = _sample_triplets(d)
-                t = trips[0].shape[0]
-            if need_neighbors:
-                # and the slot cache is filled: here on the main thread,
-                # for every split
-                ki, ko = _sample_degrees(d)
-            trips_n.append(t)
-            kis.append(ki)
-            kos.append(ko)
+    with tr.span(
+        "sample_stats", need_neighbors=bool(need_neighbors),
+        need_triplets=bool(need_triplets),
+    ) as span:
+        built = 0
+        for ds in datasets:
+            for d in ds:
+                first = first or d
+                nodes.append(d.num_nodes)
+                edges.append(d.num_edges)
+                t = ki = ko = 0
+                if need_triplets and not need_neighbors:
+                    trips = _sample_triplets(d)
+                    t = trips[0].shape[0]
+                if need_neighbors:
+                    # and the slot cache is filled: here on the main thread,
+                    # for every split
+                    built += bool(
+                        d.num_edges and _cached_neighbor_slots(d) is None
+                    )
+                    ki, ko = _sample_degrees(d)
+                trips_n.append(t)
+                kis.append(ki)
+                kos.append(ko)
+        span.set(graphs=len(nodes), slots_built=built)
     head_types = tuple(first.target_types)
     head_dims = tuple(
         t.shape[-1] if t.ndim > 1 else t.shape[0] for t in first.targets
@@ -367,17 +376,24 @@ def compute_layout(
             k_out=int(kos[mask].max()) if len(kos) else 1,
         )
 
-    everything = np.ones(len(nodes), bool)
-    if num_buckets <= 1:
-        return build(everything)
-    bounds = _partition_node_bounds(nodes, num_buckets)
-    layouts = []
-    lo = 0
-    for hi in bounds:
-        mask = (nodes > lo) & (nodes <= hi)
-        layouts.append(build_budget(mask))
-        lo = hi
-    return BucketedLayout(layouts=layouts, node_bounds=bounds)
+    with tr.span("compute_layout") as span:
+        if num_buckets <= 1:
+            layout = build(np.ones(len(nodes), bool))
+            layouts = [layout]
+        else:
+            bounds = _partition_node_bounds(nodes, num_buckets)
+            layouts = []
+            lo = 0
+            for hi in bounds:
+                mask = (nodes > lo) & (nodes <= hi)
+                layouts.append(build_budget(mask))
+                lo = hi
+            layout = BucketedLayout(layouts=layouts, node_bounds=bounds)
+        span.set(
+            buckets=len(layouts), n_pad=[lay.n_pad for lay in layouts],
+            e_pad=[lay.e_pad for lay in layouts],
+        )
+    return layout
 
 
 def _pack_indices(
@@ -673,16 +689,17 @@ class GraphLoader:
         counts) per sample — the packer's inputs."""
         if self._bucket_ids is None:
             ids, nodes, edges, trips = [], [], [], []
-            for i in range(len(self.dataset)):
-                d = self.dataset[i]
-                ids.append(self.layout.bucket_for(d.num_nodes))
-                nodes.append(d.num_nodes)
-                edges.append(d.num_edges)
-                trips.append(
-                    _sample_triplets(d)[0].shape[0]
-                    if self.layout.packs_triplets
-                    else 0
-                )
+            with tr.span("bucket_assignments", graphs=len(self.dataset)):
+                for i in range(len(self.dataset)):
+                    d = self.dataset[i]
+                    ids.append(self.layout.bucket_for(d.num_nodes))
+                    nodes.append(d.num_nodes)
+                    edges.append(d.num_edges)
+                    trips.append(
+                        _sample_triplets(d)[0].shape[0]
+                        if self.layout.packs_triplets
+                        else 0
+                    )
             self._bucket_ids = np.asarray(ids, np.int64)
             self._sizes = (
                 np.asarray(nodes, np.int64),
@@ -701,60 +718,66 @@ class GraphLoader:
         epoch: ``len(loader)`` + iteration must not pack twice."""
         if self._plan_cache is not None and self._plan_cache[0] == self.epoch:
             return self._plan_cache[1]
-        rng = np.random.default_rng(self.seed + self.epoch)
-        plan = []
         assignments = self._bucket_assignments()
-        nodes, edges, trips = self._sizes
-        for b in range(len(self.layout.layouts)):
-            lay = self.layout.layouts[b]
-            bidx = np.nonzero(assignments == b)[0]
-            n = len(bidx)
-            if n == 0:
-                continue
-            if self.shuffle:
-                bidx = bidx[rng.permutation(n)]
-            if self.num_shards > 1:
-                total = -(-n // self.num_shards) * self.num_shards
-                bidx = np.concatenate([bidx, bidx[: total - n]])
-                # every process packs ALL shards to learn the common batch
-                # count; shards short of it wrap their own first batches
-                # (sample duplication — DistributedSampler's padding rule
-                # applied at batch granularity)
-                per_shard = [
-                    _pack_indices(
-                        bidx[s :: self.num_shards], nodes, edges, trips, lay,
-                        batch_size=self._graph_cap(),
+        # computed once an epoch, on whichever thread first asks: the
+        # epoch boundary's loader work, under its own name
+        with tr.span("batch_plan") as span:
+            rng = np.random.default_rng(self.seed + self.epoch)
+            plan = []
+            nodes, edges, trips = self._sizes
+            for b in range(len(self.layout.layouts)):
+                lay = self.layout.layouts[b]
+                bidx = np.nonzero(assignments == b)[0]
+                n = len(bidx)
+                if n == 0:
+                    continue
+                if self.shuffle:
+                    bidx = bidx[rng.permutation(n)]
+                if self.num_shards > 1:
+                    total = -(-n // self.num_shards) * self.num_shards
+                    bidx = np.concatenate([bidx, bidx[: total - n]])
+                    # every process packs ALL shards to learn the common
+                    # batch count; shards short of it wrap their own first
+                    # batches (sample duplication — DistributedSampler's
+                    # padding rule applied at batch granularity)
+                    per_shard = [
+                        _pack_indices(
+                            bidx[s :: self.num_shards], nodes, edges, trips,
+                            lay, batch_size=self._graph_cap(),
+                        )
+                        for s in range(self.num_shards)
+                    ]
+                    m = max(len(p) for p in per_shard)
+                    own = per_shard[self.shard_id]
+                    mine = list(own)
+                    while len(mine) < m:
+                        mine.append(mine[len(mine) % len(own)])
+                    plan.extend((b, chunk) for chunk in mine)
+                else:
+                    plan.extend(
+                        (b, chunk)
+                        for chunk in _pack_indices(
+                            bidx, nodes, edges, trips, lay,
+                            batch_size=self._graph_cap(),
+                        )
                     )
-                    for s in range(self.num_shards)
-                ]
-                m = max(len(p) for p in per_shard)
-                mine = list(per_shard[self.shard_id])
-                while len(mine) < m:
-                    mine.append(mine[len(mine) % len(per_shard[self.shard_id])])
-                plan.extend((b, chunk) for chunk in mine)
-            else:
-                plan.extend(
-                    (b, chunk)
-                    for chunk in _pack_indices(
-                        bidx, nodes, edges, trips, lay,
-                        batch_size=self._graph_cap(),
-                    )
-                )
-        if self.shuffle and plan:
-            if self.contiguous_buckets:
-                # permute within each bucket segment + the segment order,
-                # preserving same-shape adjacency for multi-step stacking
-                segments = {}
-                for item in plan:
-                    segments.setdefault(item[0], []).append(item)
-                keys = list(segments)
-                plan = []
-                for k in rng.permutation(len(keys)):
-                    seg = segments[keys[k]]
-                    plan.extend(seg[i] for i in rng.permutation(len(seg)))
-            else:
-                order = rng.permutation(len(plan))
-                plan = [plan[i] for i in order]
+            if self.shuffle and plan:
+                if self.contiguous_buckets:
+                    # permute within each bucket segment + the segment
+                    # order, preserving same-shape adjacency for multi-step
+                    # stacking
+                    segments = {}
+                    for item in plan:
+                        segments.setdefault(item[0], []).append(item)
+                    keys = list(segments)
+                    plan = []
+                    for k in rng.permutation(len(keys)):
+                        seg = segments[keys[k]]
+                        plan.extend(seg[i] for i in rng.permutation(len(seg)))
+                else:
+                    order = rng.permutation(len(plan))
+                    plan = [plan[i] for i in order]
+            span.set(batches=len(plan), buckets=len({b for b, _ in plan}))
         self._plan_cache = (self.epoch, plan)
         return plan
 
@@ -981,7 +1004,7 @@ def _affinity_places():
 
 def prefetch_iter(
     source, depth: int, fn=None, name: str = "prefetch", workers: int = 1,
-    probe=None,
+    probe=None, primed: bool = False,
 ):
     """Bounded background pipeline stage: applies ``fn`` (identity if
     None) to each item of ``source`` on worker thread(s), up to ``depth``
@@ -996,6 +1019,10 @@ def prefetch_iter(
     telemetry's ``stream_queue_depth`` gauge feed. Single-worker path
     only; the pool path's in-flight window is not a readiness signal.
 
+    ``primed``: yield ``None`` once before the first item, as soon as the
+    stage is up (the worker started, nothing waited for yet), so a consumer
+    that times its waits can take the start-up apart from the first wait.
+
     Shared by the loader's collation prefetch and the trainer's
     double-buffered device transfers. The shutdown protocol matters: puts
     are stop-aware timed puts, so an abandoned consumer (early ``break``
@@ -1009,6 +1036,8 @@ def prefetch_iter(
         fn = lambda x: x  # noqa: E731
     places = _affinity_places()
     if workers > 1:
+        if primed:
+            yield None
         yield from _ordered_pool_map(source, fn, workers, depth, name, places)
         return
     q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
@@ -1051,6 +1080,8 @@ def prefetch_iter(
     t = threading.Thread(target=worker, daemon=True, name=name)
     t.start()
     try:
+        if primed:
+            yield None
         while True:
             if probe is not None:
                 probe(q.qsize())
@@ -1179,41 +1210,43 @@ def dataset_loading_and_splitting(config: dict):
     split pkls -> per-split datasets -> loaders."""
     from hydragnn_tpu.data.serialized import SerializedGraphLoader
 
-    paths = config["Dataset"]["path"]
-    if not list(paths.values())[0].endswith(".pkl"):
-        transform_raw_data_to_serialized(config["Dataset"])
-    if "total" in paths:
-        total_to_train_val_test_pkls(config)
+    with tr.span("load_datasets") as span:
+        paths = config["Dataset"]["path"]
+        if not list(paths.values())[0].endswith(".pkl"):
+            transform_raw_data_to_serialized(config["Dataset"])
+        if "total" in paths:
+            total_to_train_val_test_pkls(config)
 
-    loader = SerializedGraphLoader(config)
-    datasets = {}
-    for name, p in config["Dataset"]["path"].items():
-        if p.endswith(".pkl"):
-            files_dir = p
-        else:
-            files_dir = (
-                f"{os.environ.get('SERIALIZED_DATA_PATH', os.getcwd())}"
-                f"/serialized_dataset/{config['Dataset']['name']}_{name}.pkl"
-            )
-        datasets[name] = loader.load_serialized_data(files_dir)
+        loader = SerializedGraphLoader(config)
+        datasets = {}
+        for name, p in config["Dataset"]["path"].items():
+            if p.endswith(".pkl"):
+                files_dir = p
+            else:
+                files_dir = (
+                    f"{os.environ.get('SERIALIZED_DATA_PATH', os.getcwd())}"
+                    f"/serialized_dataset/{config['Dataset']['name']}_{name}.pkl"
+                )
+            datasets[name] = loader.load_serialized_data(files_dir)
+        span.set(splits=len(datasets))
 
-    arch = config["NeuralNetwork"]["Architecture"]
-    need_triplets = arch.get("model_type") == "DimeNet"
-    need_neighbors = needs_dense_neighbors(
-        arch_for_auto_policy(config["NeuralNetwork"])
-    )
-    training = config["NeuralNetwork"]["Training"]
-    return create_dataloaders(
-        datasets["train"],
-        datasets["validate"],
-        datasets["test"],
-        batch_size=training["batch_size"],
-        need_triplets=need_triplets,
-        need_neighbors=need_neighbors,
-        num_buckets=training.get("batch_buckets"),
-        contiguous_buckets=training.get("contiguous_buckets"),
-        bucket_graph_cap=training.get("bucket_graph_cap", "batch"),
-    )
+        arch = config["NeuralNetwork"]["Architecture"]
+        need_triplets = arch.get("model_type") == "DimeNet"
+        need_neighbors = needs_dense_neighbors(
+            arch_for_auto_policy(config["NeuralNetwork"])
+        )
+        training = config["NeuralNetwork"]["Training"]
+        return create_dataloaders(
+            datasets["train"],
+            datasets["validate"],
+            datasets["test"],
+            batch_size=training["batch_size"],
+            need_triplets=need_triplets,
+            need_neighbors=need_neighbors,
+            num_buckets=training.get("batch_buckets"),
+            contiguous_buckets=training.get("contiguous_buckets"),
+            bucket_graph_cap=training.get("bucket_graph_cap", "batch"),
+        )
 
 
 def transform_raw_data_to_serialized(ds_config: dict):

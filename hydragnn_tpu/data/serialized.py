@@ -7,6 +7,7 @@ max edge length, apply optional descriptors, extract per-head targets, select
 input node-feature columns, optional stratified subsampling.
 """
 
+import os
 import pickle
 from typing import List
 
@@ -21,6 +22,7 @@ from hydragnn_tpu.data.transforms import (
     spherical_descriptor,
 )
 from hydragnn_tpu.utils import faults
+from hydragnn_tpu.utils import tracer as tr
 from hydragnn_tpu.utils.retry import retry_io
 
 
@@ -101,63 +103,80 @@ class SerializedGraphLoader:
                 _ = pickle.load(f)  # minmax graph
                 return pickle.load(f)
 
-        # one big read off a shared filesystem: transient OSError gets
-        # jittered-backoff retries instead of killing the job at startup
-        dataset = retry_io(_read, what=dataset_path)
+        # one span a stage and split (never one a sample): set-up's split
+        # of the loader's time, docs/observability.md "Training spans"
+        with tr.span(
+            "read_split", split=os.path.basename(dataset_path)
+        ) as span:
+            # one big read off a shared filesystem: transient OSError gets
+            # jittered-backoff retries instead of killing the job at startup
+            dataset = retry_io(_read, what=dataset_path)
+            span.set(graphs=len(dataset), bytes=os.path.getsize(dataset_path))
 
         if self.rotational_invariance:
             dataset = [normalize_rotation(d) for d in dataset]
 
-        for data in dataset:
-            if self.periodic:
-                edge_index, lengths = radius_graph_pbc(
-                    data.pos,
-                    data.supercell_size,
-                    self.radius,
-                    self.max_neighbours,
+        with tr.span(
+            "radius_graph", graphs=len(dataset), periodic=bool(self.periodic),
+            max_neighbours=self.max_neighbours,
+        ) as span:
+            atoms = edges = 0
+            for data in dataset:
+                if self.periodic:
+                    edge_index, lengths = radius_graph_pbc(
+                        data.pos,
+                        data.supercell_size,
+                        self.radius,
+                        self.max_neighbours,
+                    )
+                    data.edge_index = edge_index
+                    data.edge_attr = lengths[:, None].astype(np.float32)
+                else:
+                    data.edge_index = radius_graph(
+                        data.pos, self.radius, self.max_neighbours
+                    )
+                    data.edge_attr = None
+                    add_edge_lengths(data)
+                atoms += data.num_nodes
+                edges += data.num_edges
+            span.set(atoms=int(atoms), edges=int(edges))
+
+        with tr.span("finish_split", graphs=len(dataset)):
+            max_edge_length = 0.0
+            for data in dataset:
+                if data.edge_attr.size:
+                    max_edge_length = max(
+                        max_edge_length, float(data.edge_attr.max())
+                    )
+            if self.dist:
+                from hydragnn_tpu.parallel.distributed import host_allreduce
+
+                max_edge_length = float(
+                    host_allreduce(np.asarray([max_edge_length]), op="max")[0]
                 )
-                data.edge_index = edge_index
-                data.edge_attr = lengths[:, None].astype(np.float32)
-            else:
-                data.edge_index = radius_graph(
-                    data.pos, self.radius, self.max_neighbours
+            max_edge_length = max(max_edge_length, 1e-12)
+            for data in dataset:
+                data.edge_attr = data.edge_attr / max_edge_length
+
+            if self.spherical_coordinates:
+                dataset = [spherical_descriptor(d) for d in dataset]
+            if self.point_pair_features:
+                dataset = [point_pair_features(d) for d in dataset]
+
+            for data in dataset:
+                extract_targets(
+                    self.output_type,
+                    self.output_index,
+                    self.graph_feature_dim,
+                    self.node_feature_dim,
+                    data,
                 )
-                data.edge_attr = None
-                add_edge_lengths(data)
+                select_input_node_features(self.input_node_features, data)
 
-        max_edge_length = 0.0
-        for data in dataset:
-            if data.edge_attr.size:
-                max_edge_length = max(max_edge_length, float(data.edge_attr.max()))
-        if self.dist:
-            from hydragnn_tpu.parallel.distributed import host_allreduce
+            if "subsample_percentage" in self.variables:
+                from hydragnn_tpu.data.split import stratified_subsample
 
-            max_edge_length = float(
-                host_allreduce(np.asarray([max_edge_length]), op="max")[0]
-            )
-        max_edge_length = max(max_edge_length, 1e-12)
-        for data in dataset:
-            data.edge_attr = data.edge_attr / max_edge_length
-
-        if self.spherical_coordinates:
-            dataset = [spherical_descriptor(d) for d in dataset]
-        if self.point_pair_features:
-            dataset = [point_pair_features(d) for d in dataset]
-
-        for data in dataset:
-            extract_targets(
-                self.output_type,
-                self.output_index,
-                self.graph_feature_dim,
-                self.node_feature_dim,
-                data,
-            )
-            select_input_node_features(self.input_node_features, data)
-
-        if "subsample_percentage" in self.variables:
-            from hydragnn_tpu.data.split import stratified_subsample
-
-            return stratified_subsample(
-                dataset, self.variables["subsample_percentage"]
-            )
-        return dataset
+                return stratified_subsample(
+                    dataset, self.variables["subsample_percentage"]
+                )
+            return dataset

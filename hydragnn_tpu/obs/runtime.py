@@ -340,7 +340,9 @@ def _register_compile_listener():
             # of the recorder, under whatever span is open on the thread
             # that compiled; the counters below stay backend compiles only.
             # '/jax/core/compile/backend_compile_duration' fires once per
-            # actual XLA compilation (cache hits don't reach the backend)
+            # program the backend is asked for: jax 0.9.0 sends it around
+            # compile_or_get_cached, so a persistent-cache hit counts too,
+            # with its 'cache_retrieval_time_sec' inside the duration
             global _compile_events, _compile_seconds
             if event.startswith(_COMPILE_FAMILY):
                 _tracer.record(

@@ -57,26 +57,38 @@ def _get_summary_writer(log_name):
 
 
 def _build_model_and_trainer(config, train_loader, verbosity):
-    arch = _arch_for_factory(config)
-    if arch.get("partition_axis"):
-        return _build_partitioned(config, arch, train_loader, verbosity)
-    model = create_model_config(arch, verbosity)
-    # 2-D ("data", "model") when Training.model_parallel / HYDRAGNN_MESH
-    # asks for it, the historical 1-D data mesh otherwise; a shape that
-    # no longer fits the visible devices re-derives (parallel/mesh.py)
-    mesh = resolve_mesh(config["NeuralNetwork"]["Training"])
-    trainer = Trainer(
-        model,
-        config["NeuralNetwork"]["Training"],
-        mesh=mesh,
-        verbosity=verbosity,
-        freeze_conv=arch.get("freeze_conv_layers", False),
-    )
-    example_batch = next(iter(train_loader))
-    state = trainer.init_state(example_batch, seed=0)
-    from hydragnn_tpu.models.create import print_model
+    import jax
 
-    print_model(model, {"params": state.params}, verbosity)
+    arch = _arch_for_factory(config)
+    with tr.span("init_state") as span:
+        if arch.get("partition_axis"):
+            model, trainer, state = _build_partitioned(
+                config, arch, train_loader, verbosity
+            )
+        else:
+            model = create_model_config(arch, verbosity)
+            # 2-D ("data", "model") when Training.model_parallel /
+            # HYDRAGNN_MESH asks for it, the historical 1-D data mesh
+            # otherwise; a shape that no longer fits the visible devices
+            # re-derives (parallel/mesh.py)
+            mesh = resolve_mesh(config["NeuralNetwork"]["Training"])
+            trainer = Trainer(
+                model,
+                config["NeuralNetwork"]["Training"],
+                mesh=mesh,
+                verbosity=verbosity,
+                freeze_conv=arch.get("freeze_conv_layers", False),
+            )
+            example_batch = next(iter(train_loader))
+            state = trainer.init_state(example_batch, seed=0)
+            from hydragnn_tpu.models.create import print_model
+
+            print_model(model, {"params": state.params}, verbosity)
+        leaves = jax.tree_util.tree_leaves(state.params)
+        span.set(
+            params=len(leaves),
+            param_bytes=sum(int(a.nbytes) for a in leaves),
+        )
     return model, trainer, state
 
 

@@ -406,56 +406,55 @@ class Trainer(PredictMixin):
         return self._put(stacked, stacked=True)
 
     def _put(self, batch: GraphBatch, stacked: bool, slots=()) -> GraphBatch:
-        """The one transfer path, two spans of the recorder: ``compact``,
-        the host-side shaping of the wire format (the multi-host path
-        offsets its local shard there instead), and ``h2d``, the
-        ``device_put`` tree-map. ``h2d`` measures the HOST's side of the
-        put — staging and enqueue; the transfer itself is the device's and
-        shows in a profiler trace, not here. ``slots`` are the pool's
-        buffers ``batch`` is made of: the wire format is written into the
-        first, and all go back by the rule of :func:`_give_back`, one put
-        later (:meth:`_settle`)."""
+        """The one transfer path: the host-side shaping of the wire format
+        (the multi-host path offsets its local shard instead), whose time
+        is the self time of the caller's ``put_group`` span, then the
+        ``h2d`` span, the ``device_put`` tree-map. ``h2d`` measures the
+        HOST's side of the put — staging and enqueue; the transfer itself
+        is the device's and shows in a profiler trace, not here. ``slots``
+        are the pool's buffers ``batch`` is made of: the wire format is
+        written into the first, and all go back by the rule of
+        :func:`_give_back`, one put later (:meth:`_settle`)."""
         slot = slots[0] if slots else None
-        with tr.span("compact"):
-            if self.mesh is None:
-                host = self._compact_for_transfer(batch, slot=slot)
-                put = jnp.asarray
-            else:
-                from jax.sharding import NamedSharding, PartitionSpec as P
+        if self.mesh is None:
+            host = self._compact_for_transfer(batch, slot=slot)
+            put = jnp.asarray
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec as P
 
-                from hydragnn_tpu.parallel.mesh import DATA_AXIS
+            from hydragnn_tpu.parallel.mesh import DATA_AXIS
 
-                if self._batch_sharding is None:
-                    self._batch_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
-                    self._stacked_sharding = NamedSharding(
-                        self.mesh, P(None, DATA_AXIS)
-                    )
-                sharding = (
-                    self._stacked_sharding if stacked else self._batch_sharding
+            if self._batch_sharding is None:
+                self._batch_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
+                self._stacked_sharding = NamedSharding(
+                    self.mesh, P(None, DATA_AXIS)
                 )
-                if batch.extras and "nbr_reach" in batch.extras:
-                    # the collate's locality statement describes ONE array
-                    # of rows; here they are split over the data axis (a
-                    # neighbour may lie on another device), so the sharded
-                    # batch makes none and keeps XLA's gather
-                    batch = batch.replace(extras={
-                        k: v for k, v in batch.extras.items()
-                        if k != "nbr_reach"
-                    })
-                if jax.process_count() > 1:
-                    host = _offset_local_shard(batch, jax.process_index())
+            sharding = (
+                self._stacked_sharding if stacked else self._batch_sharding
+            )
+            if batch.extras and "nbr_reach" in batch.extras:
+                # the collate's locality statement describes ONE array
+                # of rows; here they are split over the data axis (a
+                # neighbour may lie on another device), so the sharded
+                # batch makes none and keeps XLA's gather
+                batch = batch.replace(extras={
+                    k: v for k, v in batch.extras.items()
+                    if k != "nbr_reach"
+                })
+            if jax.process_count() > 1:
+                host = _offset_local_shard(batch, jax.process_index())
 
-                    def put(a):
-                        return jax.make_array_from_process_local_data(
-                            sharding, np.asarray(a)
-                        )
-                else:
-                    host = self._compact_for_transfer(
-                        batch, allow_pos_placeholder=False, slot=slot
+                def put(a):
+                    return jax.make_array_from_process_local_data(
+                        sharding, np.asarray(a)
                     )
+            else:
+                host = self._compact_for_transfer(
+                    batch, allow_pos_placeholder=False, slot=slot
+                )
 
-                    def put(a):
-                        return jax.device_put(jnp.asarray(a), sharding)
+                def put(a):
+                    return jax.device_put(jnp.asarray(a), sharding)
         with tr.span("h2d"):
             dev = jax.tree_util.tree_map(put, host)
         if slots:
@@ -655,22 +654,34 @@ class Trainer(PredictMixin):
             # the epoch's single readback — EXPLICIT device_get, so the
             # transfer-guard harness (analysis/guards.py no_host_syncs)
             # can hard-error every implicit fetch in the epoch loop while
-            # this one sanctioned transfer passes
-            a = np.asarray(jax.device_get(jnp.stack(acc)), np.float64).sum(
-                axis=0
-            )
+            # this one sanctioned transfer passes.
+            # ``drain``: until the stacked accumulators are ready on the
+            # device: the
+            # stack's dispatches, which overlap the steps still queued, and
+            # the wait for that queue; the transfer and the summation after
+            # it are the host's own. No synchronisation added (the
+            # device_get waits for the same array) and nothing serialised:
+            # waiting for the parts BEFORE stacking them put the stack's
+            # 3 ms behind the queue, +3 ms a readback in every cell
+            # (PERF.md section 6, PR 34)
+            with tr.span("drain", dispatches=len(acc)):
+                stacked = jax.block_until_ready(jnp.stack(acc))
+            a = np.asarray(jax.device_get(stacked), np.float64).sum(axis=0)
         n = max(a[1], 1.0)
         return a[0] / n, a[2:] / n
 
     def _prefetch_put(self, loader, nbatch, depth, put=None,
-                      ledger_waits=True):
+                      ledger_waits=True, opened=None):
         """Yield device-resident batches with up to ``depth`` transfers in
         flight ahead of the consumer. The transfers are issued from a
         background thread (shared :func:`prefetch_iter` machinery): both
         halves of a put's cost — the host-side compaction/assembly (numpy,
         releases the GIL) and the H2D copy — overlap the steps already
         dispatched on earlier batches.
-        ``depth <= 0`` degrades to the strict transfer/step alternation."""
+        ``depth <= 0`` degrades to the strict transfer/step alternation.
+        ``opened``, when given, is called once, where the stage's start-up
+        ends and the wait for the first batch begins (the transfer thread
+        started; with ``depth <= 0`` before the loader is first asked)."""
         put = put or self.put_batch
         # goodput ledger (obs/ledger.py): the wall the consumer spends
         # waiting on the data plane is the data_stall category — resolved
@@ -689,6 +700,8 @@ class Trainer(PredictMixin):
 
         # the ``dataload`` span's clock is the ledger's: one reading
         if depth <= 0:
+            if opened is not None:
+                opened()
             for batch in limited():
                 wait = tr.start("dataload")
                 dev = put(batch)
@@ -706,8 +719,11 @@ class Trainer(PredictMixin):
 
         it = prefetch_iter(
             limited(), depth, fn=put, name="hydragnn-device-prefetch",
-            probe=note_depth,
+            probe=note_depth, primed=True,
         )
+        next(it)  # the transfer thread is started; nothing waited for yet
+        if opened is not None:
+            opened()
         while True:
             wait = tr.start("dataload")  # time spent WAITING on the transfer stage
             try:
@@ -845,18 +861,28 @@ class Trainer(PredictMixin):
         if guard is not None and guard.last_good is None:
             guard.commit(state)
         tr.start("train")
+        # the epoch's start-up on this thread, closed by ``_prefetch_put``
+        # where the wait for the first batch begins (the first ``dataload``)
+        opening = tr.start("epoch_open", prefetch=self.device_prefetch)
         # resolved once per epoch: the per-step telemetry hooks must cost
         # one global read when observability is off
         _telemetry = obs.active()
         pool_before = _pool_counts(loader)
         plan = self._group_plan(loader, nbatch, K)
+        batches = groups = 0
         for dev, count in self._prefetch_put(
             plan, float("inf"), self.device_prefetch, put=self._put_group,
             ledger_waits=not getattr(loader, "reports_stream_stats", False),
+            opened=opening.stop,
         ):
+            batches += count
+            groups += 1
             if count > 1:
-                subs = jax.random.split(rng, count + 1)
-                rng = subs[0]
+                # eager dispatches on the loop thread: each one gives the
+                # GIL up and waits to get it back from a producer thread
+                with tr.span("split_rng", steps=count):
+                    subs = jax.random.split(rng, count + 1)
+                    rng = subs[0]
                 step = tr.start(
                     "train_step", steps=count, program="train_multi",
                     bucket=dev.x.shape[-2],
@@ -890,7 +916,8 @@ class Trainer(PredictMixin):
                 if faults.nan_at_step(self._host_step):
                     dev = dev.replace(x=dev.x * jnp.nan)
                 prev = None if guard is None else guard.snapshot(state)
-                rng, sub = jax.random.split(rng)
+                with tr.span("split_rng", steps=1):
+                    rng, sub = jax.random.split(rng)
                 step = tr.start(
                     "train_step", steps=1, program="train_step",
                     bucket=dev.x.shape[-2],
@@ -920,7 +947,9 @@ class Trainer(PredictMixin):
                 faults.lose_host_at_step(self._host_step)
                 self._host_step += 1
                 elastic.note_step(self._host_step)
-        self._settle()
+        opening.set(batches=batches, groups=groups)
+        with tr.span("settle", waited=self._in_flight is not None):
+            self._settle()
         with tr.span("epoch_readback", dispatches=len(acc or ())):
             loss, tasks = self._acc_read(acc)  # the epoch's one readback
         tr.stop("train")
@@ -958,6 +987,7 @@ class Trainer(PredictMixin):
                     state.params, state.batch_stats, dev
                 )
                 acc = self._acc_add(acc, metrics, multi=False)
-        self._settle()
+        with tr.span("settle", waited=self._in_flight is not None):
+            self._settle()
         with tr.span("epoch_readback", dispatches=len(acc or ())):
             return self._acc_read(acc)

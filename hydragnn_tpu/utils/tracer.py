@@ -195,7 +195,7 @@ class Span:
         return (end - self.start_ns) * 1e-9
 
 
-def initialize(trace_backends=(), verbosity: int = 0):
+def initialize(trace_backends=()):
     """Switch the recorder on. ``trace_backends`` is accepted for the
     callers that name the former backends (``("native",)``, ``("timer",)``,
     ``("jax",)``): every call gives the same recorder, and a second call

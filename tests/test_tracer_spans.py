@@ -272,16 +272,15 @@ def pytest_train_epoch_spans_and_counts_add_up(
     puts = spans["put_group"]
     assert sum(s.attrs["batches"] for s in puts) == nbatch
     assert all(s.attrs["bytes"] > 0 for s in puts)
-    for child in ("compact", "h2d"):
-        assert len(spans[child]) == len(puts)
-        assert all(by_id[s.parent].name == "put_group" for s in spans[child])
+    assert len(spans["h2d"]) == len(puts)
+    assert all(by_id[s.parent].name == "put_group" for s in spans["h2d"])
     # ... and gives a put's slots back once its transfer has completed,
     # waited for after the NEXT put's enqueue; the last one's by the epoch
-    # loop
+    # loop, under its ``settle``
     waits = spans["h2d_wait"]
     assert len(waits) == len(puts)
     assert [by_id[s.parent].name for s in waits] == (
-        ["put_group"] * (len(puts) - 1) + ["train"])
+        ["put_group"] * (len(puts) - 1) + ["settle"])
     assert {s.attrs["slot"] for s in puts} <= {"reused", "made"}
     # a group's batches are stacked at the put, or, where the loader states
     # its plan (GraphLoader does), one by one as they arrive
