@@ -175,6 +175,23 @@ def stack_into(stacked, batch, index, count, slot=None):
     return stacked
 
 
+def _concat(parts, out, axis=0):
+    """``parts`` laid end to end into ``out``, each cast as an assignment
+    would (``out[a:b] = part``): one numpy call for the whole batch."""
+    np.concatenate(parts, axis=axis, out=out, casting="unsafe")
+
+
+def _repeat_into(out, values, starts, counts):
+    """``out[:] = np.repeat(values, counts)`` with no array of ``out``'s
+    size made: each run's first row gets its value, a running maximum
+    carries it down the run. ``values`` must not decrease (row offsets,
+    graph ids); ``starts`` are the runs' first rows."""
+    out.fill(0)
+    runs = counts > 0  # an empty run has no row (and may start at the end)
+    out[starts[runs]] = values[runs]
+    np.maximum.accumulate(out, out=out)
+
+
 def collate_graphs(
     samples,
     n_pad: int,
@@ -193,77 +210,96 @@ def collate_graphs(
     node head: ``[n, d]``).
 
     Runs on the host in numpy: this is the producer side of the input
-    pipeline; the arrays are shipped to HBM once per step. ``slot``
-    (``graph/slots.py``) gives the arrays to fill, each reset to exactly
-    what a fresh one holds; without it they are allocated.
+    pipeline; the arrays are shipped to HBM once per step. Each leaf is
+    one pass over the batch (a concatenation of the samples' arrays, a
+    slice write), not one per sample. ``slot`` (``graph/slots.py``) gives
+    the arrays to fill and the ``[2, E]`` / ``[E]`` scratch: each leaf's
+    head is written once and only its padding tail reset, so it holds
+    exactly what a fresh one would; without it they are allocated.
     """
-    num_graphs = len(samples)
-    total_nodes = int(sum(s.x.shape[0] for s in samples))
-    total_edges = int(sum(s.edge_index.shape[1] for s in samples))
-    if num_graphs > g_pad - 1:
-        raise ValueError(f"batch of {num_graphs} graphs exceeds g_pad-1={g_pad - 1}")
-    if total_nodes > n_pad - 1:
-        raise ValueError(f"{total_nodes} nodes exceed n_pad-1={n_pad - 1}")
-    if total_edges > e_pad:
-        raise ValueError(f"{total_edges} edges exceed e_pad={e_pad}")
+    G = len(samples)
+    # per-sample counts, read once; every leaf below is laid out from them
+    # with a fixed number of numpy calls, whatever the batch's size
+    nodes = np.fromiter((s.x.shape[0] for s in samples), np.int64, G)
+    edges = np.fromiter((s.edge_index.shape[1] for s in samples), np.int64, G)
+    N, E = int(nodes.sum()), int(edges.sum())
+    if G > g_pad - 1:
+        raise ValueError(f"batch of {G} graphs exceeds g_pad-1={g_pad - 1}")
+    if N > n_pad - 1:
+        raise ValueError(f"{N} nodes exceed n_pad-1={n_pad - 1}")
+    if E > e_pad:
+        raise ValueError(f"{E} edges exceed e_pad={e_pad}")
+    node_off = np.cumsum(nodes) - nodes  # first node row of each sample
+    edge_off = np.cumsum(edges) - edges
 
+    # each leaf: its head [:N] / [:E] / [:G] written once below, its tail
+    # reset to what a fresh array holds there
     feat_dim = samples[0].x.shape[1]
-    x = filled(slot, "x", (n_pad, feat_dim), np.float32)
-    pos = filled(slot, "pos", (n_pad, 3), np.float32)
+    x = filled(slot, "x", (n_pad, feat_dim), np.float32, start=N)
+    _concat([s.x for s in samples], x[:N])
+
+    pos = filled(slot, "pos", (n_pad, 3), np.float32, start=N)
+    parts = [s.pos for s in samples]
+    if any(p is None for p in parts):  # a sample without positions: zeros
+        blank = np.zeros((int(nodes.max()), 3), np.float32)
+        parts = [blank[:n] if p is None else p for p, n in zip(parts, nodes)]
+    _concat(parts, pos[:N])
+
     # padding edges point at the last node slot (always a padding node since
-    # total_nodes <= n_pad - 1) and live in the padding graph.
-    senders = filled(slot, "senders", (e_pad,), np.int32, n_pad - 1)
-    receivers = filled(slot, "receivers", (e_pad,), np.int32, n_pad - 1)
+    # N <= n_pad - 1) and live in the padding graph. Real edges: each
+    # sample's local indices (its [2, e] whole: numpy gives the GIL up for
+    # every piece over 500 items, so one piece a sample, not one a row),
+    # then + its first node row, the node offsets repeated by the edge counts
+    local = filled(slot, "edge_index", (2, e_pad), np.int32, None)[:, :E]
+    _concat([s.edge_index for s in samples], local, axis=1)
+    shift = filled(slot, "edge_shift", (e_pad,), np.int32, None)[:E]
+    _repeat_into(shift, node_off, edge_off, edges)
+    senders = filled(slot, "senders", (e_pad,), np.int32, n_pad - 1, start=E)
+    np.add(local[0], shift, out=senders[:E])
+    receivers = filled(
+        slot, "receivers", (e_pad,), np.int32, n_pad - 1, start=E
+    )
+    np.add(local[1], shift, out=receivers[:E])
     edge_dim = None
     if samples[0].edge_attr is not None:
         edge_dim = samples[0].edge_attr.shape[1]
-        edge_attr = filled(slot, "edge_attr", (e_pad, edge_dim), np.float32)
-    node_graph = filled(slot, "node_graph", (n_pad,), np.int32, g_pad - 1)
-    n_node = filled(slot, "n_node", (g_pad,), np.int32)
-    n_edge = filled(slot, "n_edge", (g_pad,), np.int32)
-    node_mask = filled(slot, "node_mask", (n_pad,), bool)
-    edge_mask = filled(slot, "edge_mask", (e_pad,), bool)
-    graph_mask = filled(slot, "graph_mask", (g_pad,), bool)
-
-    targets = [
-        filled(
-            slot, f"target{ih}", (g_pad if t == "graph" else n_pad, d),
-            np.float32,
+        edge_attr = filled(
+            slot, "edge_attr", (e_pad, edge_dim), np.float32, start=E
         )
-        for ih, (t, d) in enumerate(zip(head_types, head_dims))
-    ]
+        _concat([s.edge_attr for s in samples], edge_attr[:E])
 
-    node_off = 0
-    edge_off = 0
-    for g, s in enumerate(samples):
-        n = s.x.shape[0]
-        e = s.edge_index.shape[1]
-        x[node_off : node_off + n] = s.x
-        if s.pos is not None:
-            pos[node_off : node_off + n] = s.pos
-        senders[edge_off : edge_off + e] = s.edge_index[0] + node_off
-        receivers[edge_off : edge_off + e] = s.edge_index[1] + node_off
-        if edge_dim is not None:
-            edge_attr[edge_off : edge_off + e] = s.edge_attr
-        node_graph[node_off : node_off + n] = g
-        n_node[g] = n
-        n_edge[g] = e
-        node_mask[node_off : node_off + n] = True
-        edge_mask[edge_off : edge_off + e] = True
-        graph_mask[g] = True
-        for ih, t in enumerate(head_types):
-            tgt = np.asarray(s.targets[ih], dtype=np.float32)
-            if t == "graph":
-                targets[ih][g] = tgt.reshape(-1)
-            else:
-                targets[ih][node_off : node_off + n] = tgt.reshape(n, -1)
-        node_off += n
-        edge_off += e
-
+    node_graph = filled(
+        slot, "node_graph", (n_pad,), np.int32, g_pad - 1, start=N
+    )
+    _repeat_into(node_graph[:N], np.arange(G), node_off, nodes)
+    n_node = filled(slot, "n_node", (g_pad,), np.int32, start=G)
+    n_node[:G] = nodes
+    n_edge = filled(slot, "n_edge", (g_pad,), np.int32, start=G)
+    n_edge[:G] = edges
     # padding nodes all sit in the padding graph; record its node count so
     # segment means over the padding graph stay well-defined.
-    n_node[g_pad - 1] = n_pad - node_off
-    n_edge[g_pad - 1] = e_pad - edge_off
+    n_node[g_pad - 1] = n_pad - N
+    n_edge[g_pad - 1] = e_pad - E
+    node_mask = filled(slot, "node_mask", (n_pad,), bool, start=N)
+    node_mask[:N] = True
+    edge_mask = filled(slot, "edge_mask", (e_pad,), bool, start=E)
+    edge_mask[:E] = True
+    graph_mask = filled(slot, "graph_mask", (g_pad,), bool, start=G)
+    graph_mask[:G] = True
+
+    # a head's targets, arrays of any dtype or lists: a graph head's [d]
+    # rows (or scalars) flattened in sample order, a node head's [n, d]
+    # blocks stacked (flattening them would copy each with the GIL given up)
+    targets = []
+    for ih, (t, d) in enumerate(zip(head_types, head_dims)):
+        parts = [s.targets[ih] for s in samples]
+        if t == "graph":
+            tgt = filled(slot, f"target{ih}", (g_pad, d), np.float32, start=G)
+            _concat(parts, tgt.reshape(-1)[: G * d], axis=None)
+        else:
+            tgt = filled(slot, f"target{ih}", (n_pad, d), np.float32, start=N)
+            _concat(parts, tgt[:N])
+        targets.append(tgt)
 
     batch = GraphBatch(
         x=x,

@@ -22,11 +22,17 @@ import weakref
 import numpy as np
 
 
-def filled(slot, name, shape, dtype, fill=0):
+def filled(slot, name, shape, dtype, fill=0, start=0):
     """An array of ``shape`` and ``dtype`` holding ``fill`` everywhere
     (``None``: whatever it held): ``slot``'s array of that name, reset, or
-    a fresh one where there is no slot. The ONE allocation site of the
+    a fresh one where there is no slot. With ``start``, only the rows from
+    ``start`` on hold ``fill``, and those before it are the caller's to
+    write (a batch's head, written once). The ONE allocation site of the
     collate path's large arrays."""
+    if start:
+        a = filled(slot, name, shape, dtype, None)
+        a[start:] = fill
+        return a
     if slot is not None:
         return slot.array(name, shape, dtype, fill)
     if fill is None:
