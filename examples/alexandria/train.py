@@ -52,7 +52,9 @@ def make_crystal(rng, radius, max_neighbours):
         pos=pos.astype(np.float32),
         supercell_size=cell,
     )
-    d.edge_index, lengths = radius_graph_pbc(pos, cell, radius, max_neighbours)
+    d.edge_index, lengths, d.extras["edge_offset"] = radius_graph_pbc(
+        pos, cell, radius, max_neighbours
+    )
     # moment: species value damped by like-neighbor count
     like = np.zeros(len(z))
     for s, r in zip(*d.edge_index):

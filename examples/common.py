@@ -164,6 +164,7 @@ def train_with_loaders(config, trainset, valset, testset, log_name, seed=0):
         arch_for_auto_policy,
         needs_dense_neighbors,
     )
+    from hydragnn_tpu.models.create import needs_edge_offsets
 
     arch_cfg = config["NeuralNetwork"]["Architecture"]
     need_triplets = arch_cfg.get("model_type") == "DimeNet"
@@ -175,6 +176,7 @@ def train_with_loaders(config, trainset, valset, testset, log_name, seed=0):
         num_buckets=training.get("batch_buckets"),
         contiguous_buckets=training.get("contiguous_buckets"),
         bucket_graph_cap=training.get("bucket_graph_cap", "batch"),
+        need_offsets=needs_edge_offsets(arch_cfg),
     )
     config = update_config(config, train_loader, val_loader, test_loader)
     save_config(config, log_name)
@@ -210,6 +212,7 @@ def train_with_stream(config, sources, valset, testset, log_name,
     planner replaces the hand ``batch_buckets`` table, and config
     derivation runs over a cursor-neutral probe window (docs/data.md)."""
     from hydragnn_tpu.data.stream import assemble_stream_loaders
+    from hydragnn_tpu.models.create import needs_edge_offsets
     from hydragnn_tpu.obs import runtime as obs
 
     setup_distributed()
@@ -225,6 +228,9 @@ def train_with_stream(config, sources, valset, testset, log_name,
         assemble_stream_loaders(
             sources, weights, training["batch_size"], scfg, valset,
             testset, num_buckets=training.get("batch_buckets"),
+            need_offsets=needs_edge_offsets(
+                config["NeuralNetwork"]["Architecture"]
+            ),
         )
     )
     if train_loader.plan_event:

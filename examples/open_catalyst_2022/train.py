@@ -64,7 +64,9 @@ def make_oxide(rng, radius, max_neighbours):
         pos=pos.astype(np.float32),
         supercell_size=cell,
     )
-    d.edge_index, _ = radius_graph_pbc(pos, cell, radius, max_neighbours)
+    d.edge_index, _, d.extras["edge_offset"] = radius_graph_pbc(
+        pos, cell, radius, max_neighbours
+    )
     d.targets = [np.asarray([energy], np.float32), forces]
     d.target_types = ["graph", "node"]
     return d

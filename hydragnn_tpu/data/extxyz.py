@@ -222,7 +222,7 @@ def frame_to_graph(
     if frame.get("cell") is not None and bool(np.any(frame["pbc"])):
         # per-axis pbc mask: a slab (pbc="T T F") must not form edges
         # through the vacuum axis
-        edge_index, lengths = radius_graph_pbc(
+        edge_index, lengths, offsets = radius_graph_pbc(
             pos.astype(np.float64), frame["cell"], radius, max_neighbours,
             pbc=frame["pbc"],
         )
@@ -231,6 +231,7 @@ def frame_to_graph(
         lengths = np.linalg.norm(
             pos[edge_index[0]] - pos[edge_index[1]], axis=1
         )
+        offsets = None
     d = GraphData(
         x=z,
         pos=pos,
@@ -240,6 +241,8 @@ def frame_to_graph(
     )
     d.edge_index = edge_index
     d.edge_attr = np.asarray(lengths, np.float32).reshape(-1, 1)
+    if offsets is not None:
+        d.extras["edge_offset"] = offsets
     if energy_key not in frame["info"]:
         raise KeyError(
             f"frame has no {energy_key!r} in its comment line "

@@ -10,7 +10,7 @@ evenly across processes with wrap-around padding).
 
 import bisect
 import os
-from dataclasses import astuple, dataclass, field
+from dataclasses import astuple, dataclass, field, replace
 from typing import List, Optional, Tuple, Union
 
 import numpy as np
@@ -46,6 +46,10 @@ class BatchLayout:
     # of it; dense-list batches carry it on as ``extras["nbr_reach"]`` and
     # the neighbour gather reads it (``ops/dense_agg.py gather_neighbors``)
     nbr_reach: int = 0
+    # each edge's periodic image as ``extras["edge_offset"]``, for a stack
+    # that computes distances from positions (``graph/batch.py
+    # collate_graphs``); off for every other stack, which never pays for it
+    need_offsets: bool = False
 
     @property
     def packs_triplets(self) -> bool:
@@ -330,6 +334,7 @@ def compute_layout(
     device_multiple: Optional[int] = None,
     need_neighbors: bool = False,
     num_buckets: int = 1,
+    need_offsets: bool = False,
 ) -> Union[BatchLayout, "BucketedLayout"]:
     """``device_multiple``: every padded leading axis is made divisible by
     this (the data-parallel axis size) so sharded batches split evenly.
@@ -357,7 +362,7 @@ def compute_layout(
     )
 
     def build(mask) -> BatchLayout:
-        return _layout_from_maxima(
+        return replace(_layout_from_maxima(
             max(int(nodes[mask].max()), 1),
             max(int(edges[mask].max()), 1),
             int(trips_n[mask].max()) if need_triplets else 0,
@@ -365,16 +370,16 @@ def compute_layout(
             kos[mask].max() if len(kos) else 1,
             batch_size, mult, device_multiple, head_types, head_dims,
             need_triplets, need_neighbors,
-        )
+        ), need_offsets=need_offsets)
 
     def build_budget(mask) -> BatchLayout:
-        return budget_bucket_layout(
+        return replace(budget_bucket_layout(
             nodes[mask], edges[mask], trips_n[mask],
             batch_size, mult, device_multiple, head_types, head_dims,
             need_triplets, need_neighbors,
             k_in=int(kis[mask].max()) if len(kis) else 1,
             k_out=int(kos[mask].max()) if len(kos) else 1,
-        )
+        ), need_offsets=need_offsets)
 
     with tr.span("compute_layout") as span:
         if num_buckets <= 1:
@@ -480,6 +485,7 @@ def collate_for_layout(
             head_types=layout.head_types if with_targets else (),
             head_dims=layout.head_dims if with_targets else (),
             slot=slot,
+            offsets=layout.need_offsets,
         )
     if layout.packs_triplets:
         from hydragnn_tpu.graph.batch import pack_triplets
@@ -1175,6 +1181,7 @@ def create_dataloaders(
     num_buckets: Optional[int] = None,
     contiguous_buckets: Optional[bool] = None,
     bucket_graph_cap: str = "batch",
+    need_offsets: bool = False,
 ):
     """``num_buckets`` (the config's ``Training.batch_buckets``):
     size-bucketed layouts — <= num_buckets compiled programs per split,
@@ -1191,6 +1198,7 @@ def create_dataloaders(
         need_triplets,
         need_neighbors=need_neighbors,
         num_buckets=num_buckets,
+        need_offsets=need_offsets,
     )
     return (
         GraphLoader(trainset, batch_size, layout, shuffle=True,
@@ -1209,6 +1217,7 @@ def dataset_loading_and_splitting(config: dict):
     """Parity with ``preprocess/load_data.py:207-223``: raw -> serialized ->
     split pkls -> per-split datasets -> loaders."""
     from hydragnn_tpu.data.serialized import SerializedGraphLoader
+    from hydragnn_tpu.models.create import needs_edge_offsets
 
     with tr.span("load_datasets") as span:
         paths = config["Dataset"]["path"]
@@ -1246,6 +1255,7 @@ def dataset_loading_and_splitting(config: dict):
             num_buckets=training.get("batch_buckets"),
             contiguous_buckets=training.get("contiguous_buckets"),
             bucket_graph_cap=training.get("bucket_graph_cap", "batch"),
+            need_offsets=needs_edge_offsets(arch),
         )
 
 

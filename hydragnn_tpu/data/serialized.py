@@ -78,6 +78,11 @@ class SerializedGraphLoader:
         self.graph_feature_dim = ds["graph_features"]["dim"]
         self.rotational_invariance = ds.get("rotational_invariance", False)
         self.periodic = arch.get("periodic_boundary_conditions", False)
+        from hydragnn_tpu.models.create import needs_edge_offsets
+
+        # each edge's image is kept on the sample only for a stack that
+        # reads it: every other stack's samples stay as they were
+        self.keep_offsets = needs_edge_offsets(arch)
         self.radius = arch["radius"]
         self.max_neighbours = arch["max_neighbours"]
         self.variables = voi
@@ -123,13 +128,15 @@ class SerializedGraphLoader:
             atoms = edges = 0
             for data in dataset:
                 if self.periodic:
-                    edge_index, lengths = radius_graph_pbc(
+                    edge_index, lengths, offsets = radius_graph_pbc(
                         data.pos,
                         data.supercell_size,
                         self.radius,
                         self.max_neighbours,
                     )
                     data.edge_index = edge_index
+                    if self.keep_offsets:
+                        data.extras["edge_offset"] = offsets
                     data.edge_attr = lengths[:, None].astype(np.float32)
                 else:
                     data.edge_index = radius_graph(

@@ -91,6 +91,7 @@ def _eval_dataset(spec: dict, split: str):
 def assemble_stream_loaders(
     sources, weights, batch_size: int, scfg: dict, valset, testset,
     num_buckets: Optional[int] = None,
+    need_offsets: bool = False,
 ):
     """The ONE streaming-pipeline assembly (the config driver and
     ``examples/common.train_with_stream`` both route through here — env
@@ -123,6 +124,7 @@ def assemble_stream_loaders(
             scfg.get("num_buckets", num_buckets or 4)
         ),
         extra_datasets=[valset, testset],
+        need_offsets=need_offsets,
     )
     layout = planner.plan(emit=False)
     train_loader = StreamLoader(mix, batch_size, layout)
@@ -144,6 +146,7 @@ def build_stream_loaders(config: dict):
     """(train StreamLoader, val GraphLoader, test GraphLoader,
     probe GraphLoader) from the ``Dataset.streaming`` section."""
     from hydragnn_tpu.data.loaders import ConcatDataset
+    from hydragnn_tpu.models.create import needs_edge_offsets
 
     scfg = config["Dataset"]["streaming"]
     if config["NeuralNetwork"]["Architecture"].get("partition_axis"):
@@ -167,4 +170,5 @@ def build_stream_loaders(config: dict):
         ConcatDataset([d for d in vals if len(d)]),
         ConcatDataset([d for d in tests if len(d)]),
         num_buckets=training.get("batch_buckets"),
+        need_offsets=needs_edge_offsets(config["NeuralNetwork"]["Architecture"]),
     )

@@ -17,6 +17,7 @@ compared number-for-number against a hand table through the existing
 ``epoch_padding_stats`` accounting — the acceptance check.
 """
 
+from dataclasses import replace
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -52,6 +53,7 @@ class BucketPlanner:
         plan_shards: Optional[int] = None,
         device_multiple: Optional[int] = None,
         extra_datasets: Sequence = (),
+        need_offsets: bool = False,
     ):
         if not sources:
             raise ValueError("BucketPlanner needs at least one source")
@@ -64,6 +66,7 @@ class BucketPlanner:
         # the materialized compute_layout covers all splits for exactly
         # this reason
         self.extra_datasets = list(extra_datasets)
+        self.need_offsets = bool(need_offsets)  # BatchLayout.need_offsets
         if plan_shards is None:
             plan_shards = env_int("HYDRAGNN_STREAM_PLAN_SHARDS", 0)
         self.plan_shards = plan_shards
@@ -133,13 +136,14 @@ class BucketPlanner:
             if not mask.any():
                 continue
             kept_bounds.append(int(hi))
-            layouts.append(
+            layouts.append(replace(
                 budget_bucket_layout(
                     nodes[mask], edges[mask], np.zeros(int(mask.sum())),
                     self.batch_size, mult, self.device_multiple,
                     scan["head_types"], scan["head_dims"],
-                )
-            )
+                ),
+                need_offsets=self.need_offsets,
+            ))
         layout = BucketedLayout(layouts=layouts, node_bounds=kept_bounds)
         if emit:
             from hydragnn_tpu.obs import runtime as obs

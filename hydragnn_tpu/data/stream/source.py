@@ -303,7 +303,7 @@ class ExtxyzSource(StreamSource):
         pbc = d.extras.get("pbc")
         cell = d.extras.get("cell")
         if cell is not None and pbc is not None and bool(np.any(pbc)):
-            edge_index, lengths = radius_graph_pbc(
+            edge_index, lengths, d.extras["edge_offset"] = radius_graph_pbc(
                 d.pos.astype(np.float64),
                 cell,
                 self.radius,

@@ -201,6 +201,7 @@ def collate_graphs(
     head_dims: Tuple[int, ...] = (),
     to_device: bool = False,
     slot=None,
+    offsets: bool = False,
 ):
     """Collate a list of ``GraphData``-like samples into one padded batch.
 
@@ -216,6 +217,12 @@ def collate_graphs(
     the arrays to fill and the ``[2, E]`` / ``[E]`` scratch: each leaf's
     head is written once and only its padding tail reset, so it holds
     exactly what a fresh one would; without it they are allocated.
+
+    ``offsets`` adds ``extras["edge_offset"]`` ``[E, 3]`` float32: each
+    edge's periodic image, the samples' own ``extras["edge_offset"]``
+    (``data/radius_graph.py radius_graph_pbc``), zero on padding and for a
+    sample that has none (a non-periodic graph). Only the stacks that read
+    positions ask for it (``BatchLayout.need_offsets``).
     """
     G = len(samples)
     # per-sample counts, read once; every leaf below is laid out from them
@@ -267,6 +274,18 @@ def collate_graphs(
             slot, "edge_attr", (e_pad, edge_dim), np.float32, start=E
         )
         _concat([s.edge_attr for s in samples], edge_attr[:E])
+    extras = None
+    if offsets:
+        edge_offset = filled(
+            slot, "edge_offset", (e_pad, 3), np.float32, start=E
+        )
+        parts = [getattr(s, "extras", {}).get("edge_offset") for s in samples]
+        if any(p is None for p in parts):  # a sample without images: zeros
+            blank = np.zeros((int(edges.max()), 3), np.float32)
+            parts = [blank[:e] if p is None else p
+                     for p, e in zip(parts, edges)]
+        _concat(parts, edge_offset[:E])
+        extras = {"edge_offset": edge_offset}
 
     node_graph = filled(
         slot, "node_graph", (n_pad,), np.int32, g_pad - 1, start=N
@@ -314,6 +333,7 @@ def collate_graphs(
         edge_mask=edge_mask,
         graph_mask=graph_mask,
         targets=tuple(targets),
+        extras=extras,
     )
     if to_device:
         import jax

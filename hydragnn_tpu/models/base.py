@@ -124,6 +124,15 @@ class HydraBase(nn.Module):
     # updates) set this True; for the rest the partitioned halo exchange
     # skips the pos columns — pure ICI bandwidth savings
     conv_needs_pos: bool = False
+    # stacks whose distances come from positions set this (not a field);
+    # on periodic data their batches then carry each edge's image
+    # (``models/create.py needs_edge_offsets``)
+    reads_edge_offset = False
+
+    @property
+    def needs_edge_offsets(self) -> bool:
+        """This stack's answer to ``models/create.py needs_edge_offsets``."""
+        return self.reads_edge_offset and getattr(self, "periodic", False)
 
     @property
     def use_edge_attr(self) -> bool:
@@ -223,6 +232,11 @@ class HydraBase(nn.Module):
             p = p[:nl]
         return c, p
 
+    def _embed(self, x):
+        """Input features -> what the first conv reads. Default: as they
+        are (SchNet's interaction block embeds them first)."""
+        return x
+
     def _prepare_batch(self, batch: GraphBatch) -> GraphBatch:
         """Once-per-forward hook for values every conv layer would
         otherwise recompute identically (parameter-free functions of the
@@ -238,13 +252,16 @@ class HydraBase(nn.Module):
         from hydragnn_tpu.ops.agg_policy import emit_layout_choice
 
         emit_layout_choice(self, batch)
-        x = batch.x
+        x = self._embed(batch.x)
         pos = batch.pos
 
         # ---- encoder: conv stack (Base.py:289-302) ----------------------
         # SchNet/EGNN use Identity feature layers instead of BatchNorm
         # (SCFStack.py:63, EGCLStack.py:41)
         use_bn = getattr(self, "conv_use_batchnorm", True)
+        # SchNet's interaction block is residual and ends in its own
+        # atom-wise layer: no activation between its blocks
+        use_act = getattr(self, "conv_activation", True)
         for i, (in_dim, out_dim, bn_dim, kw) in enumerate(self._conv_layer_specs()):
             conv = self.get_conv(in_dim, out_dim, name=f"encoder_conv_{i}", **kw)
             c, pos = self._apply_conv(conv, x, pos, batch, train)
@@ -252,7 +269,7 @@ class HydraBase(nn.Module):
                 c = MaskedBatchNorm(
                     bn_dim, name=f"encoder_bn_{i}", axis_name=self.partition_axis
                 )(c, batch.node_mask, not train)
-            x = act(c)
+            x = act(c) if use_act else c
 
         # ---- decoder: multihead (Base.py:205-283,304-327) ---------------
         with jax.named_scope("heads"):  # the name the device trace reads

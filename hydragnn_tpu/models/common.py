@@ -7,6 +7,7 @@ uniform init so tiny CI-scale models land in the same loss basin — while the
 implementation is pure functional JAX that XLA can fuse end to end.
 """
 
+import math
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -78,6 +79,11 @@ class TorchLinear(nn.Module):
         return y
 
 
+def shifted_softplus(x):
+    """``softplus(x) - ln 2`` (SchNet's ssp: 0 at 0)."""
+    return jax.nn.softplus(x) - math.log(2.0)
+
+
 def get_activation(name: str) -> Callable:
     """Activation selection (reference: ``utils/model.py:30-47``)."""
     table = {
@@ -91,6 +97,7 @@ def get_activation(name: str) -> Callable:
         "lrelu_025": lambda x: jax.nn.leaky_relu(x, 0.25),
         "lrelu_05": lambda x: jax.nn.leaky_relu(x, 0.5),
         "sigmoid": jax.nn.sigmoid,
+        "ssp": shifted_softplus,
     }
     if name not in table:
         raise ValueError(f"Unknown activation function: {name}")

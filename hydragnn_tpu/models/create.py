@@ -23,17 +23,32 @@ from hydragnn_tpu.models.schnet import SCFStack
 from hydragnn_tpu.models.egnn import EGCLStack
 from hydragnn_tpu.models.dimenet import DIMEStack
 
-MODEL_TYPES = [
-    "GIN",
-    "PNA",
-    "GAT",
-    "MFC",
-    "CGCNN",
-    "SAGE",
-    "SchNet",
-    "DimeNet",
-    "EGNN",
-]
+STACKS = {
+    "GIN": GINStack,
+    "PNA": PNAStack,
+    "GAT": GATStack,
+    "MFC": MFCStack,
+    "CGCNN": CGCNNStack,
+    "SAGE": SAGEStack,
+    "SchNet": SCFStack,
+    "DimeNet": DIMEStack,
+    "EGNN": EGCLStack,
+}
+MODEL_TYPES = list(STACKS)
+
+
+def needs_edge_offsets(arch: dict) -> bool:
+    """Whether the batches of the stack that ``arch`` (an Architecture
+    section) names must carry each edge's periodic image,
+    ``extras["edge_offset"]``: the stack computes distances from positions
+    (its ``reads_edge_offset``) and the data is periodic. Every layout
+    builder asks this (the loaders, the examples' loaders, serving plans,
+    graph partitions), and the stack refuses a periodic batch without it."""
+    stack = STACKS.get(arch.get("model_type"))
+    return bool(
+        getattr(stack, "reads_edge_offset", False)
+        and arch.get("periodic_boundary_conditions")
+    )
 
 
 def _normalize_weights(task_weights, num_heads):
@@ -121,14 +136,18 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
         assert config.get("num_gaussians") is not None
         assert config.get("num_filters") is not None
         assert config.get("radius") is not None
-        # NOTE: the reference passes (num_gaussians, num_filters) positionally
-        # into SCFStack(num_filters, num_gaussians, ...) — effectively swapping
-        # them (create.py:228-247 vs SCFStack.py:33-46). Replicated for parity.
+        # the reference passes (num_gaussians, num_filters) positionally
+        # into SCFStack(num_filters, num_gaussians, ...), swapping them
+        # (create.py:228-247 vs SCFStack.py:33-46); here each is what its
+        # name says (docs/MIGRATION.md). ``interaction_block`` gives SchNet's
+        # own block (models/schnet.py); absent, HydraGNN's SCFStack form.
         return SCFStack(
-            num_filters=config["num_gaussians"],
-            num_gaussians=config["num_filters"],
+            num_filters=config["num_filters"],
+            num_gaussians=config["num_gaussians"],
             radius=config["radius"],
             edge_dim=edge_dim,
+            interaction_block=bool(config.get("interaction_block", False)),
+            periodic=bool(config.get("periodic_boundary_conditions", False)),
             **common,
         )
     if model_type == "DimeNet":

@@ -28,12 +28,12 @@ CHOICES = ("segment", "dense")
 
 # Dense/segment crossovers: minimum hidden_dim at which the dense
 # scatter-free path beats segment reductions for each model. All rows but
-# EGNN's, DimeNet's and GAT's were measured on a v5e before the current
-# tree (2026-07/08, same-session A/Bs at deg ~12; not re-measured since,
+# EGNN's, DimeNet's, GAT's and SchNet's were measured on a v5e before the
+# current tree (2026-07/08, same-session A/Bs at deg ~12; not re-measured since,
 # nor after PR 27 changed the dense path's cost — ROADMAP D11). Scatter-heavy
 # models (PNA's 4 aggregators, GAT's edge softmax, MFC's degree banks,
 # DimeNet's triplet axis) cross early; GIN/SAGE only win mildly at MXU
-# widths; SchNet never does (one already-fused scatter per layer).
+# widths.
 #
 # EGNN's row was read on THIS tree, 2026-10-03 (PR 29, one TPU v5 lite,
 # benchmarks/egnn_family_ab.py: the train step of egnn_h128x7_train_mptrj,
@@ -68,6 +68,18 @@ CHOICES = ("segment", "dense")
 # dense side: window_halo refuses eight lane tiles), and the edge list
 # pays a 1,025-column scatter per head group and layer. The row stands at
 # 96; only 256 (x 4 heads) was read.
+#
+# SchNet's row was read on THIS tree, 2026-10-15 (PR 36, one TPU v5 lite,
+# benchmarks/schnet_family_ab.py: the train step of
+# schnet_h1024x5_train_oc20, its traffic, 1,024 x 256 filters x 200
+# Gaussians x 5, 6 A / <= 50 neighbours, dense_aggregation true | false, ms
+# a step). The old "SchNet never" (one fused scatter a layer) was read at
+# small widths before the current tree. Rung 64: f32 68.51 | 47.03 (the
+# f32 tables keep XLA's gathers), bf16 35.49 | 42.29 (1.19 x). The cell's
+# rung of 128, bf16: 63.37 | 86.43 (1.36 x; by bucket 22.9 | 36.5,
+# 40.2 | 75.5, 120.3 | 122.8, 181.0 | 246.1), though the two large
+# buckets' step programs run no product kernel (their window is refused,
+# XLA's gathers). So the row is bf16-only, at 1,024: only 1,024 was read.
 DENSE_AUTO_MIN_HIDDEN = {
     "PNA": 96,
     "GAT": 96,
@@ -76,6 +88,7 @@ DENSE_AUTO_MIN_HIDDEN = {
     "GIN": 192,
     "SAGE": 192,
     "EGNN": 128,
+    "SchNet": 1024,
     # CGCNN absent from THIS table: its convs run at input_dim width
     # (constant-width CGConv), so hidden_dim says nothing about where it
     # sits relative to the crossover — it gets its own rule below.
@@ -86,7 +99,7 @@ DENSE_AUTO_MIN_HIDDEN = {
 # states the run's precision as ``bf16_compute``, resolved by the one
 # precision rule (``models/create.py precision_for``); absent, the row
 # does not apply.
-DENSE_ROWS_READ_IN_BF16 = ("EGNN",)
+DENSE_ROWS_READ_IN_BF16 = ("EGNN", "SchNet")
 
 # CGCNN's crossover keyed on its TRUE conv width (round-4 verdict item 8,
 # measured round 5 at OC20 shape): INVERSE to the hidden-width table —

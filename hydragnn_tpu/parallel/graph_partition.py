@@ -223,11 +223,14 @@ def partition_graph(
     need_triplets: bool = False,
     need_neighbors: bool = False,
     budgets: Optional[dict] = None,
+    need_offsets: bool = False,
 ) -> Tuple[GraphBatch, PartitionInfo]:
     """Split one giant graph into ``num_parts`` static-shape shards.
 
     ``sample`` exposes numpy ``x [n,F]``, ``pos [n,3]``, ``edge_index [2,e]``,
-    optional ``edge_attr``, and (per ``head_types``) ``targets``. Returns a
+    optional ``edge_attr``, and (per ``head_types``) ``targets``;
+    ``need_offsets`` carries its ``extras["edge_offset"]`` ``[e, 3]`` (each
+    edge's periodic image, zero where it has none) with its edges. Returns a
     ``GraphBatch`` whose leading axes concatenate the per-part arrays (part
     ``p`` owns rows ``[p*NL, (p+1)*NL)`` etc.) — sharding every leaf on axis 0
     over a ``num_parts``-sized mesh axis gives each device exactly its shard.
@@ -342,6 +345,8 @@ def partition_graph(
         if edge_attr is not None
         else None
     )
+    e_off = np.zeros((P, el, 3), np.float32) if need_offsets else None
+    offset = (getattr(sample, "extras", None) or {}).get("edge_offset")
     # padded slots point at the dummy row so halo_reduce's scatter-add and
     # halo_extend's sends never touch a real node
     halo_send = node_halo.send
@@ -372,6 +377,8 @@ def partition_graph(
         n_edge[p, 1] = el - k
         if e_attr is not None:
             e_attr[p, :k] = edge_attr[eidx]
+        if e_off is not None and offset is not None:
+            e_off[p, :k] = offset[eidx]
 
     # ---- triplet arrays (DimeNet), fully vectorized ---------------------
     trip_extras = {}
@@ -480,6 +487,7 @@ def partition_graph(
                 for k, v in trip_extras.items()
             },
             **{k: flat(v) for k, v in nbr_extras.items()},
+            **({"edge_offset": flat(e_off)} if e_off is not None else {}),
         },
     )
     info = PartitionInfo(

@@ -19,7 +19,7 @@ shapes training already measured (94% padding efficiency on OC20-shaped
 distributions, README).
 """
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -164,6 +164,7 @@ def plan_from_samples(
     need_triplets: bool = False,
     need_neighbors: bool = False,
     headroom: float = 1.0,
+    need_offsets: bool = False,
 ) -> ServingBucketPlan:
     """Derive a serving plan from representative graphs (e.g. the
     training set or a traffic sample).
@@ -173,7 +174,9 @@ def plan_from_samples(
     pure per-graph check and packing never re-plans. ``headroom``
     multiplies the observed per-bucket node/edge maxima so production
     graphs slightly larger than the sample still admit (capacity grows
-    with the pad)."""
+    with the pad). ``need_offsets``: the served stack's
+    ``models/create.py needs_edge_offsets`` (each edge's periodic image in
+    the batch); a periodic SchNet refuses a plan without it."""
     if not samples:
         raise ValueError("plan_from_samples needs at least one sample")
     if headroom < 1.0:
@@ -209,7 +212,7 @@ def plan_from_samples(
         cap_nodes = int(np.ceil(hi * headroom))
         cap_edges = int(np.ceil(int(edges[mask].max()) * headroom))
         cap_trips = int(np.ceil(int(trips[mask].max()) * headroom))
-        layouts.append(
+        layouts.append(replace(
             _layout_from_maxima(
                 cap_nodes,
                 max(cap_edges, 1),
@@ -223,8 +226,9 @@ def plan_from_samples(
                 (),
                 need_triplets,
                 need_neighbors,
-            )
-        )
+            ),
+            need_offsets=need_offsets,
+        ))
         capacities.append(
             BucketCapacity(
                 max_nodes=cap_nodes,

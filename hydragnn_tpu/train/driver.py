@@ -13,7 +13,7 @@ import os
 import numpy as np
 
 from hydragnn_tpu.data.loaders import dataset_loading_and_splitting
-from hydragnn_tpu.models.create import create_model_config
+from hydragnn_tpu.models.create import create_model_config, needs_edge_offsets
 from hydragnn_tpu.parallel.distributed import setup_distributed
 from hydragnn_tpu.parallel.mesh import announce_mesh, resolve_mesh
 from hydragnn_tpu.train.checkpoint import (
@@ -184,6 +184,7 @@ def make_partitioned_loaders(config, train_loader, val_loader, test_loader):
     head_dims = tuple(arch["output_dim"])
     need_triplets = arch["model_type"] == "DimeNet"
     need_neighbors = bool(arch.get("dense_aggregation"))
+    need_offsets = needs_edge_offsets(arch)
     # shards-per-graph = the partition axis size (the 2-D mesh's model
     # axis under model parallelism, every device on the legacy 1-D mesh)
     n_dev, part_axis = _partition_geometry(config)
@@ -213,6 +214,7 @@ def make_partitioned_loaders(config, train_loader, val_loader, test_loader):
                 shuffle=shuffle,
                 axis=part_axis,
                 budgets=budgets,
+                need_offsets=need_offsets,
             )
         )
     return tuple(out)

@@ -63,6 +63,7 @@ class PartitionedLoader:
         seed: int = 42,
         axis: str = "graph",
         budgets: dict = None,
+        need_offsets: bool = False,
     ):
         from hydragnn_tpu.parallel.graph_partition import partition_graph
 
@@ -86,7 +87,7 @@ class PartitionedLoader:
             b, info = partition_graph(
                 s, num_parts, self.head_types, self.head_dims,
                 need_triplets=need_triplets, need_neighbors=need_neighbors,
-                budgets=budgets,
+                budgets=budgets, need_offsets=need_offsets,
             )
             self._batches.append(b)
             self.infos.append(info)
@@ -179,7 +180,13 @@ class PartitionedTrainer:
         )
         g.targets = list(sample.targets)
         g.target_types = list(self.model.output_type)
-        layout = compute_layout([[g]], batch_size=1, need_triplets=need_triplets)
+        offset = (getattr(sample, "extras", None) or {}).get("edge_offset")
+        if offset is not None:
+            g.extras["edge_offset"] = np.asarray(offset, np.float32)
+        layout = compute_layout(
+            [[g]], batch_size=1, need_triplets=need_triplets,
+            need_offsets=self.ref_model.needs_edge_offsets,
+        )
         example_batch = _collate_with_extras([g], layout)
 
         variables = init_model_params(

@@ -21,7 +21,7 @@ _SIDES = [
     ("GIN", "hidden_dim", 191, 192),
     ("SAGE", "hidden_dim", 191, 192),
     ("CGCNN", "input_dim", 65, 64),  # inverse, and keyed on its input width
-    ("SchNet", "hidden_dim", 2048, None),
+    ("SchNet", "hidden_dim", 1023, 1024),  # read on the chip in PR 36, bf16
     ("EGNN", "hidden_dim", 127, 128),  # read on the chip in PR 29, in bf16
 ]
 

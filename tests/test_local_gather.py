@@ -363,11 +363,13 @@ _STACK_ARCH = {
 # products the rule selects per traced model: one gather a conv layer; EGNN
 # a gather (positions as pieces) and a sender sum a layer, with or without
 # its coordinate update; SchNet's ``pos`` table is f32: the dtype rule keeps
-# XLA's for it
+# XLA's for it, and its ``W_1 h`` table is bf16 in every layer since its
+# Gaussian expansion enters the filter as bf16 (PR 36; before, the f32
+# expansion promoted the filter, and every layer after the first, to f32)
 @pytest.mark.parametrize(
     "model_type,products",
     [("PNA", 2), ("GIN", 2), ("SAGE", 2), ("GAT", 2), ("MFC", 2),
-     ("CGCNN", 2), ("SchNet", 1), ("EGNN", 4), ("EGNN+coords", 6)],
+     ("CGCNN", 2), ("SchNet", 2), ("EGNN", 4), ("EGNN+coords", 6)],
 )
 def pytest_stack_grads_equal_on_both_paths(monkeypatch, model_type, products):
     """Every stack of the dense path, whole model under the bf16 policy's

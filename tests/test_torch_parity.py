@@ -27,7 +27,10 @@ from hydragnn_tpu.models import create_model_config, init_model_params
 from hydragnn_tpu.train.optimizer import select_optimizer
 
 HIDDEN = 16
-FWIDTH = 16  # filters == gaussians (sidesteps the reference's positional swap)
+# unequal widths: the factory honours each by its name (HydraGNN swaps them
+# positionally, create.py:228-247; docs/MIGRATION.md), as the torch side does
+NFILTERS = 16
+NGAUSSIANS = 12
 CUTOFF = 2.0
 STEPS = 200
 
@@ -56,8 +59,8 @@ def _arch():
         "num_conv_layers": 2,
         "num_nodes": 8,
         "edge_dim": None,
-        "num_gaussians": FWIDTH,
-        "num_filters": FWIDTH,
+        "num_gaussians": NGAUSSIANS,
+        "num_filters": NFILTERS,
         "radius": CUTOFF,
         "equivariance": False,
         "max_neighbours": 10,
@@ -151,7 +154,7 @@ def _torch_losses(variables, samples, steps):
     N, G = x0.shape[0], len(samples)
     send, recv = ei[0], ei[1]
 
-    offset = torch.linspace(0.0, CUTOFF, FWIDTH)
+    offset = torch.linspace(0.0, CUTOFF, NGAUSSIANS)
     coeff = -0.5 / float(offset[1] - offset[0]) ** 2
 
     leaves = []
