@@ -5,7 +5,7 @@ backward, on one TPU chip. The reading ``MAX_WINDOW_TILES`` was set from
 
     python benchmarks/gather_crossover.py [--out chiprun_out/gather_crossover.jsonl]
 
-Three sweeps, every line a JSON object on stdout (and in ``--out``;
+Four sweeps, every line a JSON object on stdout (and in ``--out``;
 ``--sweep`` runs some of them):
 
 - ``bucket``: the four buckets of the benchmark's PNA cell (padded rows,
@@ -14,6 +14,10 @@ Three sweeps, every line a JSON object on stdout (and in ``--out``;
   interpreter: real slots equal bit for bit, cotangents within a bf16 ulp.
 - ``window``: the product alone at one bucket's rows for halos 1-4 and
   widths 128-512: its cost per index and per [128 x 128] tile of window.
+- ``schnet``: the four buckets of the benchmark's SchNet cell, 50 slots a
+  row, its 256-column ``W_1 h`` table: the ``bucket`` sweep's readings
+  at the many-slot windows the VMEM budget admits since it counts what the
+  kernels hold (h = 2 in the two large buckets: PERF.md section 6).
 - ``egnn``: the four buckets of the benchmark's EGNN cell laid out dense,
   16 slots a row: E_GCL's gather (128 bf16 columns + 3 f32 positions: 137
   through the product, the positions as pieces, against XLA's gather of
@@ -50,6 +54,9 @@ BUCKETS = [(24488, 61), (39432, 93), (57592, 137), (88648, 225)]
 # the same of egnn_h128x7_train_mptrj under dense lists, 16 slots a row
 EGNN_BUCKETS = [(7480, 29), (14896, 51), (24984, 89), (44336, 200)]
 EGNN_K, EGNN_HIDDEN = 16, 128
+# the same of schnet_h1024x5_train_oc20, 50 slots a row, 256 columns
+SCHNET_BUCKETS = [(6128, 61), (9864, 93), (14416, 137), (22192, 225)]
+SCHNET_K, SCHNET_DIM = 50, 256
 
 
 def block_diagonal_lists(n, reach, rng, k=K_IN):
@@ -82,19 +89,20 @@ def device_ms(fn, *args, iters=20):
     return float(np.median(takes))
 
 
-def bucket_reading(n, reach, dim, rng):
-    ex = {k: jnp.asarray(v) for k, v in block_diagonal_lists(n, reach, rng).items()}
+def bucket_reading(n, reach, dim, rng, k_in=K_IN, sweep="bucket"):
+    ex = {k: jnp.asarray(v)
+          for k, v in block_diagonal_lists(n, reach, rng, k_in).items()}
     idx, mask = ex["nbr_idx"], ex["nbr_mask"]
     h = lg._cdiv(reach - 1, lg.BLOCK)
     x = jnp.asarray(rng.standard_normal((n, dim)), TABLE)
-    g = jnp.asarray(rng.standard_normal((n, K_IN, dim)), TABLE)
+    g = jnp.asarray(rng.standard_normal((n, k_in, dim)), TABLE)
 
     xla_fwd = jax.jit(lambda t: da._gather_xla(t, idx, ex["rev_idx"], ex["rev_mask"]))
-    one_fwd = jax.jit(lambda t: da._gather_onehot(h, t, idx, mask))
+    one_fwd = jax.jit(lambda t: da._gather_onehot(h, t, None, idx, mask)[0])
     xla_bwd = jax.jit(lambda t, c: jax.vjp(
         lambda u: da._gather_xla(u, idx, ex["rev_idx"], ex["rev_mask"]), t)[1](c)[0])
     one_bwd = jax.jit(lambda t, c: jax.vjp(
-        lambda u: da._gather_onehot(h, u, idx, mask), t)[1](c)[0])
+        lambda u: da._gather_onehot(h, u, None, idx, mask)[0], t)[1](c)[0])
 
     m = np.asarray(mask)[..., None]
     a = np.asarray(one_fwd(x), np.float32)
@@ -103,10 +111,10 @@ def bucket_reading(n, reach, dim, rng):
     ga = np.asarray(one_bwd(x, g), np.float32)
     gb = np.asarray(xla_bwd(x, g), np.float32)
     bwd_gap = float(np.max(np.abs(ga - gb) / np.maximum(np.abs(gb), 1.0)))
-    indices = n * K_IN
+    indices = n * k_in
     line = {
-        "sweep": "bucket", "n": n, "reach": reach, "h": h, "dim": dim,
-        "k_in": K_IN, "k_out": int(ex["rev_idx"].shape[1]),
+        "sweep": sweep, "n": n, "reach": reach, "h": h, "dim": dim,
+        "k_in": k_in, "k_out": int(ex["rev_idx"].shape[1]),
         "fwd_equal_on_real_slots": fwd_equal, "bwd_gap_ulps": bwd_gap * 2**7,
     }
     for name, fn, args in [
@@ -231,7 +239,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
     ap.add_argument("--sweep", nargs="+", default=["bucket", "window", "egnn"],
-                    choices=["bucket", "window", "egnn"])
+                    choices=["bucket", "window", "egnn", "schnet"])
     args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -257,6 +265,9 @@ def main():
     if "egnn" in args.sweep:
         for n, reach in EGNN_BUCKETS:
             emit(egnn_reading(n, reach, rng))
+    if "schnet" in args.sweep:
+        for n, reach in SCHNET_BUCKETS:
+            emit(bucket_reading(n, reach, SCHNET_DIM, rng, SCHNET_K, "schnet"))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:

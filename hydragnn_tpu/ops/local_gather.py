@@ -67,6 +67,16 @@ functions above); a table that IS f32 keeps XLA's gather.
 
 :func:`window_halo` is the rule that selects the product; everything it
 reads is a property of the operands.
+
+VMEM: the backward holds its window's ``2h + 1`` blocks of K x ``BLOCK``
+cotangent rows, double-buffered, so many-slot lists at h = 2 need more
+than the 32 MiB scoped limit every call once had (SchNet's 50 slots over
+256 columns: 36 MiB, by the v5e compiler). :func:`_vmem_bytes` counts what
+one grid step of each kernel holds, as that compiler allocates it; each
+call asks for its own count with room and never for less than those 32
+MiB (a call that fitted there compiles as it did), and the rule refuses a
+window whose limit would pass ``_VMEM_BLOCK_BUDGET``: one count for the
+rule and the kernel, so the two cannot disagree.
 """
 
 import functools
@@ -77,9 +87,20 @@ import jax.numpy as jnp
 
 BLOCK = 128  # receivers (senders) per grid step: the MXU's edge
 _LANES = 128
-# VMEM one grid step's blocks and 0/1 matrix may take (the scoped limit is
-# raised to fit; a v5e core has 128 MiB)
-_VMEM_BLOCK_BUDGET = 24 * 1024 * 1024
+_MIB = 1024 * 1024
+# The most scoped VMEM one kernel call may be given. A v5e core has 128 MiB
+# (``pltpu.get_tpu_info()`` on the chip), and the chip's compiler took every
+# limit from 36 MiB to that for the widest call of any benchmark cell
+# (SchNet's backward at K = 50, h = 2, 256 columns; refused at 32). That
+# call's limit is 41.7 MiB (39.7 by :func:`_vmem_bytes` and the headroom);
+# 48 MiB holds it and lists of up to 58 slots at its window (PERF.md
+# section 6).
+_VMEM_BLOCK_BUDGET = 48 * _MIB
+# The limit every call had before it followed the call's own reckoning: a
+# call that reckons less keeps it, so its program compiles as it did
+_VMEM_LIMIT_FLOOR = 32 * _MIB
+# room over the reckoning for what of the compiler's own it does not name
+_VMEM_HEADROOM = 2 * _MIB
 # The crossover, read once on a v5e (benchmarks/gather_crossover.py; PERF.md
 # section 6, PR 27): per index and per [128 x 128] tile of its window
 # ((2h + 1) row blocks x ceil(D / 128) lane tiles) the product costs
@@ -145,32 +166,60 @@ def window_halo(
       passes) to come out exact;
     - ``backend``: compiled Pallas is the TPU's;
     - the window's size: at most ``MAX_WINDOW_TILES`` MXU tiles per index
-      (as far as the product was read ahead), and blocks and 0/1 matrix
-      within the VMEM budget.
+      (as far as the product was read ahead), and the scoped VMEM limit
+      either kernel would be given (:func:`_vmem_limit`, from the same
+      count the kernel call takes it from) within ``_VMEM_BLOCK_BUDGET``.
     """
     if reach is None or backend != "tpu" or dtype != jnp.bfloat16:
         return None
     h = _cdiv(max(int(reach), 1) - 1, BLOCK)
-    w, lanes = 2 * h + 1, _cdiv(dim, _LANES)
-    if w * lanes > MAX_WINDOW_TILES:
+    lanes = _cdiv(dim, _LANES)
+    if (2 * h + 1) * lanes > MAX_WINDOW_TILES:
         return None
-    slab = k_in * BLOCK * lanes * _LANES * 2  # one [k_in, BLOCK, D] block
-    # backward: 2h+1 blocks of cotangents, double-buffered. Forward: the
-    # result block (double-buffered), its f32 product, and the [k_in
-    # BLOCK, w BLOCK] 0/1 matrix
-    backward = 2 * w * slab
-    forward = 4 * slab + k_in * BLOCK * w * BLOCK * 2
-    if max(backward, forward) > _VMEM_BLOCK_BUDGET:
+    if _vmem_limit(max(_vmem_bytes(k_in, h, lanes))) > _VMEM_BLOCK_BUDGET:
         return None
     return h
 
 
-def _compiler_params():
+def _vmem_bytes(k_in: int, h: int, lanes: int):
+    """(forward, backward): scoped VMEM one grid step of each kernel holds
+    for lists of ``k_in`` slots, a window of ``2h + 1`` blocks and tables
+    of ``lanes`` lane tiles side by side, as the v5e compiler allocates it:
+    every block a ``BlockSpec`` names twice (Pallas double-buffers them),
+    the scratch, and the kernels' large values. Over K 4-100, h 1-3 and 1-4
+    lane tiles it is 1.07-1.7 x what the compiler asked for the backward,
+    1.2-3 x for the forward (PERF.md section 6); the backward is the
+    larger at every shape."""
+    w, d = 2 * h + 1, lanes * _LANES
+    slab = k_in * BLOCK * d * 2  # one [K, BLOCK, D] bf16 block
+    onehot = k_in * BLOCK * BLOCK  # elements of the 0/1 matrix per block
+    forward = (
+        2 * BLOCK * _cdiv(k_in, _LANES) * _LANES * 4  # the lists' block
+        + 2 * w * BLOCK * d * 2  # the window's blocks
+        + 2 * slab  # the result block
+        + w * onehot * 3  # the [K BLOCK, w BLOCK] 0/1 matrix and its compare
+        + 2 * slab  # the product, in f32
+    )
+    backward = (
+        2 * w * k_in * BLOCK * 4  # the senders' [1, K BLOCK] windows
+        + 2 * w * slab  # the cotangents' window
+        + 3 * BLOCK * d * 4  # the result block (f32 at most), the accumulator
+        + onehot * 6  # the [BLOCK, K BLOCK] 0/1 matrix and the int32 iota
+        + slab  # a ragged block's cotangents, masked
+    )
+    return forward, backward
+
+
+def _vmem_limit(need: int) -> int:
+    """The scoped VMEM limit of a call that reckons ``need`` bytes."""
+    return max(_VMEM_LIMIT_FLOOR, need + _VMEM_HEADROOM)
+
+
+def _compiler_params(need: int):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",),
-        vmem_limit_bytes=_VMEM_BLOCK_BUDGET + 8 * 1024 * 1024,
+        dimension_semantics=("parallel",), vmem_limit_bytes=_vmem_limit(need)
     )
 
 
@@ -318,6 +367,7 @@ def _gather_call(given, nbr_idx, h, interpret):
     rows = nb * BLOCK - n
     idx = jnp.pad(nbr_idx, ((0, rows), (0, 0)))
     parts = [jnp.pad(p, ((0, rows), (0, 0))) for p in given]
+    need, _ = _vmem_bytes(k, h, sum(p.shape[1] for p in parts) // _LANES)
     # numlint: disable=pallas-vmem-unbounded — gated by window_halo above
     return pl.pallas_call(
         functools.partial(_fwd_kernel, h),
@@ -334,7 +384,7 @@ def _gather_call(given, nbr_idx, h, interpret):
         out_shape=[
             jax.ShapeDtypeStruct((k, n, p.shape[1]), p.dtype) for p in parts
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(need),
         interpret=interpret,
         name="gather_neighbors_onehot",
     )(idx, *(p for p in parts for _ in range(w)))
@@ -375,6 +425,7 @@ def _scatter_call(parts, nbr_idx, nbr_mask, h, interpret, out_dtypes):
     )
     senders = senders.reshape(nb, BLOCK, k).transpose(0, 2, 1)
     senders = senders.reshape(nb, 1, k * BLOCK)
+    _, need = _vmem_bytes(k, h, sum(p.shape[2] for p in parts) // _LANES)
     # numlint: disable=pallas-vmem-unbounded — gated by window_halo above
     return pl.pallas_call(
         functools.partial(_bwd_kernel, h, n),
@@ -394,7 +445,7 @@ def _scatter_call(parts, nbr_idx, nbr_mask, h, interpret, out_dtypes):
         scratch_shapes=[
             pltpu.VMEM((BLOCK, p.shape[2]), jnp.float32) for p in parts
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(need),
         interpret=interpret,
         name="gather_neighbors_onehot_bwd",
     )(*([senders] * w), *(p for p in parts for _ in range(w)))

@@ -15,6 +15,7 @@ launchers refuse two chip-using children on one host.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # before libtpu loads
 
@@ -71,7 +72,21 @@ def _compile_fwd_and_grad(fn, n_diff, args):
     return jax.jit(step).lower(*args).compile().as_text()
 
 
+def _scoped_vmem(text):
+    """{kernel name: the scoped VMEM limits its compiled calls were given}."""
+    limits = {}
+    for line in text.splitlines():
+        if KERNEL in line:
+            name = re.search(r"%(gather_neighbors_onehot(?:_bwd)?)", line)[1]
+            size = re.search(r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', line)
+            limits.setdefault(name, set()).add(int(size[1]))
+    return limits
+
+
 # ---- block-local neighbour gather (ops/local_gather.py) ---------------------
+
+FLOOR = {"gather_neighbors_onehot": {32 * 1024**2},
+         "gather_neighbors_onehot_bwd": {32 * 1024**2}}
 
 
 @pytest.mark.parametrize(
@@ -79,9 +94,11 @@ def _compile_fwd_and_grad(fn, n_diff, args):
     [
         (88648, 225, 12, 256),  # the PNA cell's largest bucket: h = 2
         (39432, 129, 12, 1),  # its first layer: one feature, padded to 128
-        (4104, 257, 19, 512),  # the rule's limit: 20 tiles, blocks at the budget
+        (4104, 257, 30, 512),  # the rule's limit: 20 tiles, the backward at the budget
         (4104, 1153, 12, 1),  # the farthest reach it admits: h = 9, 19 tiles
-        (4104, 129, 100, 128),  # a hundred slots: the forward's 0/1 matrix at the budget
+        (4104, 129, 145, 128),  # the most slots it admits at h = 1
+        (22192, 225, 50, 256),  # the SchNet cell's largest bucket: h = 2
+        (14416, 137, 50, 256),  # and its second: h = 2
     ],
 )
 def pytest_local_gather_compiles_where_the_rule_selects_it(
@@ -91,8 +108,9 @@ def pytest_local_gather_compiles_where_the_rule_selects_it(
     h = lg.window_halo(jnp.bfloat16, reach, k_in, dim, "tpu")
     assert h == -(-(reach - 1) // lg.BLOCK)
     # one more slot, or one more block of reach, and the rule keeps XLA's
-    if (n, dim) == (4104, 512):
+    if n == 4104 and k_in > 12:
         assert lg.window_halo(jnp.bfloat16, reach, k_in + 1, dim, "tpu") is None
+    if (n, dim) == (4104, 512):
         assert lg.window_halo(jnp.bfloat16, reach + 1, k_in, dim, "tpu") is None
     lists = lambda dt, k: _shape(chip, (n, k), dt)
     text = _compile_fwd_and_grad(
@@ -108,6 +126,16 @@ def pytest_local_gather_compiles_where_the_rule_selects_it(
         ),
     )
     assert text.count(KERNEL) == 2
+    # each call's limit follows its own reckoning, and is the one every
+    # call had wherever that reckoning is under it (the PNA cell's calls)
+    fwd, bwd = lg._vmem_bytes(k_in, h, -(-dim // 128))
+    limits = _scoped_vmem(text)
+    assert limits == {"gather_neighbors_onehot": {lg._vmem_limit(fwd)},
+                      "gather_neighbors_onehot_bwd": {lg._vmem_limit(bwd)}}
+    if k_in == 12:
+        assert limits == FLOOR
+    if k_in == 50:  # the backward's window needs more than that
+        assert lg._vmem_limit(bwd) > max(FLOOR["gather_neighbors_onehot_bwd"])
 
 
 @pytest.mark.parametrize(
@@ -151,6 +179,7 @@ def pytest_egnn_products_compile_at_the_cell_buckets(chip, monkeypatch, n, reach
     )
     assert text.count(KERNEL) == 4
     assert "f32[%d,128]" % n in text  # the pieces' sums leave the kernel in f32
+    assert _scoped_vmem(text) == FLOOR  # the limit every call had
 
 
 # ---- the whole jitted train step of chip_smoke.py ---------------------------

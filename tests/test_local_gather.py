@@ -42,11 +42,11 @@ def _graph(n, rng, degree=5):
     return d
 
 
-def _collated(sizes, seed=0, device_multiple=1):
+def _collated(sizes, seed=0, device_multiple=1, degree=5):
     """One collated dense-list batch of graphs of ``sizes`` nodes, in that
     order, under the layout ``compute_layout`` gives them."""
     rng = np.random.default_rng(seed)
-    samples = [_graph(n, rng) for n in sizes]
+    samples = [_graph(n, rng, degree) for n in sizes]
     layout = compute_layout(
         [samples], batch_size=len(samples), need_neighbors=True,
         device_multiple=device_multiple,
@@ -84,18 +84,27 @@ def _rule_answers(monkeypatch):
     return taken
 
 
-@pytest.mark.parametrize("dim", [1, 64, 130])
-@pytest.mark.parametrize("h", [1, 2])
-def pytest_product_equals_indexed_read(h, dim):
-    batch, layout = _collated(_SIZES[h])
+@pytest.mark.parametrize(
+    "h,dim,slots",
+    [(1, 1, None), (1, 64, None), (1, 130, None),
+     (2, 1, None), (2, 64, None), (2, 130, None),
+     # the SchNet cell's large buckets: 50 slots, h = 2, 256 columns
+     (2, 256, 50)],
+    ids=["1-1", "1-64", "1-130", "2-1", "2-64", "2-130", "2-256-k50"],
+)
+def pytest_product_equals_indexed_read(h, dim, slots):
+    batch, layout = _collated(_SIZES[h], degree=slots or 5)
     ex = batch.extras
     assert ex["nbr_reach"].shape == (layout.nbr_reach,)
     assert lg._cdiv(layout.nbr_reach - 1, lg.BLOCK) == h
-    n = batch.x.shape[0]
+    n, k = ex["nbr_idx"].shape
     assert n % lg.BLOCK  # the last block is ragged
+    assert slots in (None, k)
+    assert lg.window_halo(jnp.bfloat16, layout.nbr_reach, k, dim, "tpu") == h
     rng = np.random.default_rng(dim)
     x = jnp.asarray(rng.standard_normal((n, dim)), jnp.bfloat16)
     idx, mask = jnp.asarray(ex["nbr_idx"]), jnp.asarray(ex["nbr_mask"])
+    assert not np.asarray(mask).all()  # padded slots
 
     # forward: bit for bit on every real slot; padded slots read zero or
     # row 0, which every consumer masks
@@ -432,6 +441,10 @@ def pytest_stack_grads_equal_on_both_paths(monkeypatch, model_type, products):
         ("window_over_crossover", {"reach": 2000}, None),
         ("wide_rows_over_crossover", {"dim": 1536}, None),
         ("blocks_over_vmem", {"k_in": 400}, None),
+        # the SchNet cell's two large buckets: 50 slots at h = 2, the
+        # backward's window of 31 MiB within the budget
+        ("schnet_n14416", {"reach": 137, "k_in": 50}, 2),
+        ("schnet_n22192", {"reach": 225, "k_in": 50}, 2),
     ],
 )
 def pytest_rule_selects_from_operands_alone(case, kwargs, want):
