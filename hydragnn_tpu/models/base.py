@@ -16,6 +16,7 @@ TPU-first differences:
     of ``torch.utils.checkpoint`` (``Base.py:296-301``).
 """
 
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -124,15 +125,26 @@ class HydraBase(nn.Module):
     # updates) set this True; for the rest the partitioned halo exchange
     # skips the pos columns — pure ICI bandwidth savings
     conv_needs_pos: bool = False
-    # stacks whose distances come from positions set this (not a field);
-    # on periodic data their batches then carry each edge's image
-    # (``models/create.py needs_edge_offsets``)
-    reads_edge_offset = False
+    @classmethod
+    def reads_offsets(cls, arch: dict) -> bool:
+        """Whether the stack that ``arch`` (an Architecture section, or the
+        module's own fields) configures computes distances from positions;
+        on periodic data its batches then carry each edge's image
+        (``models/create.py needs_edge_offsets``)."""
+        return False
+
+    @classmethod
+    def conv_width_key(cls, arch: dict) -> str:
+        """The Architecture key that states the width this stack's convs
+        run at, which the family and precision rows of
+        ``ops/agg_policy.py`` key on."""
+        return "hidden_dim"
 
     @property
     def needs_edge_offsets(self) -> bool:
         """This stack's answer to ``models/create.py needs_edge_offsets``."""
-        return self.reads_edge_offset and getattr(self, "periodic", False)
+        fields = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)}
+        return type(self).reads_offsets(fields) and getattr(self, "periodic", False)
 
     @property
     def use_edge_attr(self) -> bool:

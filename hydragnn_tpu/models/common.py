@@ -97,6 +97,7 @@ def get_activation(name: str) -> Callable:
         "lrelu_025": lambda x: jax.nn.leaky_relu(x, 0.25),
         "lrelu_05": lambda x: jax.nn.leaky_relu(x, 0.5),
         "sigmoid": jax.nn.sigmoid,
+        "softplus": jax.nn.softplus,
         "ssp": shifted_softplus,
     }
     if name not in table:

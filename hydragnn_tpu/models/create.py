@@ -41,12 +41,13 @@ def needs_edge_offsets(arch: dict) -> bool:
     """Whether the batches of the stack that ``arch`` (an Architecture
     section) names must carry each edge's periodic image,
     ``extras["edge_offset"]``: the stack computes distances from positions
-    (its ``reads_edge_offset``) and the data is periodic. Every layout
+    (its ``reads_offsets(arch)``) and the data is periodic. Every layout
     builder asks this (the loaders, the examples' loaders, serving plans,
     graph partitions), and the stack refuses a periodic batch without it."""
     stack = STACKS.get(arch.get("model_type"))
     return bool(
-        getattr(stack, "reads_edge_offset", False)
+        stack is not None
+        and stack.reads_offsets(arch)
         and arch.get("periodic_boundary_conditions")
     )
 
@@ -117,8 +118,7 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
             **common,
         )
     if model_type == "CGCNN":
-        # constant width: hidden == input (CGCNNStack.py:30-40); conv node
-        # heads unsupported (CGCNNStack.py:66-89)
+        # conv node heads unsupported (CGCNNStack.py:66-89)
         heads_cfg = config["output_heads"]
         if (
             "node" in heads_cfg
@@ -128,6 +128,27 @@ def create_model_config(config: dict, verbosity: int = 0) -> HydraBase:
             raise ValueError(
                 '"conv" for node features decoder part in CGCNN is not ready yet.'
             )
+        if config.get("cgcnn_conv"):
+            # the published block (models/cgcnn.py): an embedding to
+            # hidden_dim, Gaussians of true distances, no partitioning
+            # (its edge BatchNorm and geometry are per batch)
+            for key in ("num_gaussians", "radius"):
+                if config.get(key) is None:
+                    raise ValueError(f"the published CGCNN block requires {key}")
+            if common["equivariance"] or common["partition_axis"]:
+                raise ValueError(
+                    "the published CGCNN block has no equivariant or "
+                    "partitioned form; set cgcnn_conv false"
+                )
+            return CGCNNStack(
+                cgcnn_conv=True,
+                conv_needs_pos=True,
+                num_gaussians=config["num_gaussians"],
+                radius=config["radius"],
+                periodic=bool(config.get("periodic_boundary_conditions", False)),
+                **common,
+            )
+        # constant width: hidden == input (CGCNNStack.py:30-40)
         common["hidden_dim"] = common["input_dim"]
         return CGCNNStack(edge_dim=edge_dim if edge_dim is not None else 0, **common)
     if model_type == "SAGE":

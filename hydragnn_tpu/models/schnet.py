@@ -87,7 +87,7 @@ def edge_vectors(pos, batch, periodic=False):
     offset = extras.get("edge_offset")
     if periodic and offset is None:
         raise ValueError(
-            "periodic SchNet batch without extras['edge_offset']: build its "
+            "periodic batch without extras['edge_offset']: build its "
             "layout with need_offsets=models.create.needs_edge_offsets(arch)"
         )
     if "nbr_idx" in extras:
@@ -243,9 +243,6 @@ class CFConv(nn.Module):
 
 class SCFStack(HydraBase):
     conv_needs_pos: bool = True
-    # distances from positions: periodic batches carry each edge's image
-    # (models/create.py needs_edge_offsets; not a field)
-    reads_edge_offset = True
     periodic: bool = False
     num_filters: int = 126
     num_gaussians: int = 50
@@ -254,6 +251,11 @@ class SCFStack(HydraBase):
     # SchNet's own block (module docstring): embedding, residual
     # interactions, no activation between them
     interaction_block: bool = False
+
+    @classmethod
+    def reads_offsets(cls, arch: dict) -> bool:
+        # distances from positions: periodic batches carry each edge's image
+        return True
 
     @property
     def conv_activation(self) -> bool:

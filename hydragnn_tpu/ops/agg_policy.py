@@ -80,6 +80,18 @@ CHOICES = ("segment", "dense")
 # 40.2 | 75.5, 120.3 | 122.8, 181.0 | 246.1), though the two large
 # buckets' step programs run no product kernel (their window is refused,
 # XLA's gathers). So the row is bf16-only, at 1,024: only 1,024 was read.
+#
+# CGCNN's published block (``cgcnn_conv``, keyed on hidden_dim, the width
+# its convs run at) was read on THIS tree, 2026-10-18 (one TPU v5 lite,
+# benchmarks/cgcnn_family_ab.py: the train step of
+# cgcnn_h512x3_train_oc20, its traffic, 512 x 3 convs, 100 Gaussians,
+# 6 A / <= 50 neighbours, the shipped edge form, dense_aggregation true |
+# false, ms a step). The cell's rung of 64, bf16: 77.925 | 91.878 (1.18 x;
+# by bucket 44.9 | 39.4, 72.1 | 85.1, 110.6 | 141.4, 162.1 | 222.8: the
+# edge list wins the smallest bucket alone); rung 32, f32: 55.768 |
+# 49.534. Every step program gathers through XLA (a 512-wide table at
+# K = 47-50 takes no product kernel: ``kernels`` 0). So the row is
+# bf16-only, at 512: only 512 was read.
 DENSE_AUTO_MIN_HIDDEN = {
     "PNA": 96,
     "GAT": 96,
@@ -89,9 +101,9 @@ DENSE_AUTO_MIN_HIDDEN = {
     "SAGE": 192,
     "EGNN": 128,
     "SchNet": 1024,
-    # CGCNN absent from THIS table: its convs run at input_dim width
-    # (constant-width CGConv), so hidden_dim says nothing about where it
-    # sits relative to the crossover — it gets its own rule below.
+    # the published block's row (above); HydraGNN's constant-width CGConv
+    # has its own rule below
+    "CGCNN": 512,
 }
 
 # Rows that hold only where the run computes in bf16 (the dense side wins
@@ -99,31 +111,52 @@ DENSE_AUTO_MIN_HIDDEN = {
 # states the run's precision as ``bf16_compute``, resolved by the one
 # precision rule (``models/create.py precision_for``); absent, the row
 # does not apply.
-DENSE_ROWS_READ_IN_BF16 = ("EGNN", "SchNet")
+DENSE_ROWS_READ_IN_BF16 = ("EGNN", "SchNet", "CGCNN")
 
-# CGCNN's crossover keyed on its TRUE conv width (round-4 verdict item 8,
-# measured round 5 at OC20 shape): INVERSE to the hidden-width table —
-# dense gathers [N, K, input_dim] blocks, so gather traffic grows with
-# input width while the segment scatter cost stays flat. Maximum input_dim
-# at which the dense path is picked automatically.
+# HydraGNN's constant-width CGConv (CGCNN without ``cgcnn_conv``) keyed on
+# its TRUE conv width, input_dim, and INVERSELY: dense gathers
+# [N, K, input_dim] blocks, so gather traffic grows with input width while
+# the segment scatter cost stays flat. Maximum input_dim at which the
+# dense path is picked automatically. Read at small widths before the
+# current tree (2026-07/08, like the older rows above), not since.
 DENSE_AUTO_MAX_INPUT_DIM = {
     "CGCNN": 64,
 }
 
 
+def _conv_width_key(arch_config: dict) -> str:
+    """The Architecture key the stack states its conv width under (its
+    ``conv_width_key``; hidden_dim for a model type no stack names)."""
+    from hydragnn_tpu.models.create import STACKS
+
+    stack = STACKS.get(arch_config.get("model_type"))
+    return "hidden_dim" if stack is None else stack.conv_width_key(arch_config)
+
+
+def _constant_width(arch_config: dict) -> bool:
+    """Convs that run at the input width, decided by the inverse row."""
+    return arch_config.get("model_type") in DENSE_AUTO_MAX_INPUT_DIM and (
+        _conv_width_key(arch_config) == "input_dim"
+    )
+
+
+def conv_width(arch_config: dict) -> int:
+    """The width a stack's convs run at, which the family and precision
+    rows key on."""
+    return int(arch_config.get(_conv_width_key(arch_config)) or 0)
+
+
 def auto_dense_aggregation(arch_config: dict) -> bool:
-    """The measured-crossover policy: dense iff the (model type, width)
-    point sits on the dense-winning side of the tables above. Width is
-    hidden_dim for most stacks; CGCNN's constant-width convs key on
-    input_dim instead — and inversely. Absent/0 input_dim stays
-    conservative: segment."""
+    """The measured-crossover policy: dense iff the (model type, conv
+    width) point sits on the dense-winning side of the tables above.
+    HydraGNN's constant-width CGConv keys on input_dim instead, and
+    inversely; absent/0 input_dim stays conservative: segment."""
     mt = arch_config.get("model_type")
-    th_in = DENSE_AUTO_MAX_INPUT_DIM.get(mt)
-    if th_in is not None:
-        dim = int(arch_config.get("input_dim") or 0)
-        return 1 <= dim <= th_in
+    width = conv_width(arch_config)
+    if _constant_width(arch_config):
+        return 1 <= width <= DENSE_AUTO_MAX_INPUT_DIM[mt]
     th = DENSE_AUTO_MIN_HIDDEN.get(mt)
-    if th is None or int(arch_config.get("hidden_dim") or 0) < th:
+    if th is None or width < th:
         return False
     return mt not in DENSE_ROWS_READ_IN_BF16 or bool(
         arch_config.get("bf16_compute")
@@ -152,7 +185,11 @@ def arch_for_auto_policy(nn_config: dict) -> dict:
     if feats and "input_dim" not in arch:
         derived["input_dim"] = len(feats)
     mt = arch.get("model_type")
-    if mt in DENSE_ROWS_READ_IN_BF16 and "bf16_compute" not in arch:
+    if (
+        mt in DENSE_ROWS_READ_IN_BF16
+        and "bf16_compute" not in arch
+        and not _constant_width(arch)
+    ):
         from hydragnn_tpu.models.create import precision_for
 
         derived["bf16_compute"] = precision_for(
