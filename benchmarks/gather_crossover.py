@@ -5,7 +5,7 @@ backward, on one TPU chip. The reading ``MAX_WINDOW_TILES`` was set from
 
     python benchmarks/gather_crossover.py [--out chiprun_out/gather_crossover.jsonl]
 
-Four sweeps, every line a JSON object on stdout (and in ``--out``;
+Five sweeps, every line a JSON object on stdout (and in ``--out``;
 ``--sweep`` runs some of them):
 
 - ``bucket``: the four buckets of the benchmark's PNA cell (padded rows,
@@ -18,6 +18,10 @@ Four sweeps, every line a JSON object on stdout (and in ``--out``;
   row, its 256-column ``W_1 h`` table: the ``bucket`` sweep's readings
   at the many-slot windows the VMEM budget admits since it counts what the
   kernels hold (h = 2 in the two large buckets: PERF.md section 6).
+- ``cgcnn``: the four buckets of the benchmark's CGCNN cell, 47-50 slots a
+  row, its 512-column ``h_j`` table: the many-slot windows whose backward
+  sums its slots in chunks (``local_gather.slot_chunks``; h = 1 in the two
+  small buckets, h = 2 in the two large: PERF.md section 6).
 - ``egnn``: the four buckets of the benchmark's EGNN cell laid out dense,
   16 slots a row: E_GCL's gather (128 bf16 columns + 3 f32 positions: 137
   through the product, the positions as pieces, against XLA's gather of
@@ -57,6 +61,9 @@ EGNN_K, EGNN_HIDDEN = 16, 128
 # the same of schnet_h1024x5_train_oc20, 50 slots a row, 256 columns
 SCHNET_BUCKETS = [(6128, 61), (9864, 93), (14416, 137), (22192, 225)]
 SCHNET_K, SCHNET_DIM = 50, 256
+# (padded rows, largest graph, slots) of cgcnn_h512x3_train_oc20, 512 columns
+CGCNN_BUCKETS = [(3064, 62, 47), (4944, 94, 50), (7208, 137, 50), (11056, 225, 50)]
+CGCNN_DIM = 512
 
 
 def block_diagonal_lists(n, reach, rng, k=K_IN):
@@ -115,6 +122,7 @@ def bucket_reading(n, reach, dim, rng, k_in=K_IN, sweep="bucket"):
     line = {
         "sweep": sweep, "n": n, "reach": reach, "h": h, "dim": dim,
         "k_in": k_in, "k_out": int(ex["rev_idx"].shape[1]),
+        "chunks": lg.slot_chunks(k_in, h, dim),
         "fwd_equal_on_real_slots": fwd_equal, "bwd_gap_ulps": bwd_gap * 2**7,
     }
     for name, fn, args in [
@@ -239,7 +247,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
     ap.add_argument("--sweep", nargs="+", default=["bucket", "window", "egnn"],
-                    choices=["bucket", "window", "egnn", "schnet"])
+                    choices=["bucket", "window", "egnn", "schnet", "cgcnn"])
     args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -268,6 +276,9 @@ def main():
     if "schnet" in args.sweep:
         for n, reach in SCHNET_BUCKETS:
             emit(bucket_reading(n, reach, SCHNET_DIM, rng, SCHNET_K, "schnet"))
+    if "cgcnn" in args.sweep:
+        for n, reach, k in CGCNN_BUCKETS:
+            emit(bucket_reading(n, reach, CGCNN_DIM, rng, k, "cgcnn"))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:

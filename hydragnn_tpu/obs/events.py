@@ -67,7 +67,8 @@ EVENT_FIELDS: Dict[str, tuple] = {
     # (models/base.py); source "operands" is the dense path's neighbour
     # gather or sender sum (ops/dense_agg.py; bucket gather/... or
     # scatter/...): gather = choice = onehot|xla, h the window's halo in
-    # blocks
+    # blocks, chunks the slot chunks of ops/local_gather.py's
+    # scatter_product (the gather's backward, the sender sum's forward)
     "agg_choice": ("bucket", "choice", "source"),
     # the input pipeline's host buffers (graph/slots.py), one per
     # train_epoch over a loader that pools: slots acquired in the epoch

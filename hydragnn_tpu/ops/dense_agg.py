@@ -244,24 +244,37 @@ def _halo(x, exact, nbr_idx, nbr_mask, nbr_reach):
     """``window_halo`` for the table (operand) ``x`` with the f32 columns
     ``exact`` beside it as pieces, behind these lists: the one question
     both neighbour products ask, answered from the operands alone."""
-    from hydragnn_tpu.ops.local_gather import lane_width, window_halo
+    from hydragnn_tpu.ops.local_gather import window_halo
 
     if nbr_mask is None or nbr_reach is None:
         return None
-    widths = (x.shape[-1],) + (() if exact is None else (3 * exact.shape[-1],))
     return window_halo(
-        x.dtype, nbr_reach.shape[-1], nbr_idx.shape[1], lane_width(*widths),
+        x.dtype, nbr_reach.shape[-1], nbr_idx.shape[1], _lanes(x, exact),
         _backend(),
     )
 
 
-def _report(kind, n, k, width, dtype, h):
+def _lanes(x, exact):
+    """The lane width the product's tables take: ``x``'s columns beside
+    the pieces of ``exact``'s."""
+    from hydragnn_tpu.ops.local_gather import lane_width
+
+    widths = (x.shape[-1],) + (() if exact is None else (3 * exact.shape[-1],))
+    return lane_width(*widths)
+
+
+def _report(kind, n, k, width, dtype, h, lanes=None):
+    """One ``agg_choice`` line; a product's carries its ``h`` and the slot
+    chunks of its ``scatter_product`` call (``local_gather.slot_chunks``
+    over tables ``lanes`` wide)."""
     from hydragnn_tpu.ops.agg_policy import emit_choice
+    from hydragnn_tpu.ops.local_gather import slot_chunks
 
     impl = "xla" if h is None else "onehot"
+    extra = {} if h is None else {"h": h, "chunks": slot_chunks(k, h, lanes)}
     emit_choice(
         f"{kind}/n{n}/k{k}/d{width}/{jnp.dtype(dtype).name}", impl,
-        "operands", gather=impl, **({} if h is None else {"h": h}),
+        "operands", gather=impl, **extra,
     )
 
 
@@ -307,7 +320,8 @@ def gather_neighbors(
     ``xla`` they are further columns of one (f32) table, as EGNN always
     gathered them. A table that IS f32 is not split: it keeps ``xla``.
 
-    The choice is reported as an ``agg_choice`` event (``gather``, ``h``).
+    The choice is reported as an ``agg_choice`` event (``gather``, ``h``,
+    ``chunks``).
     """
     (n, d), k_in = x.shape, nbr_idx.shape[1]
     h = _halo(x, exact, nbr_idx, nbr_mask, nbr_reach)
@@ -317,7 +331,7 @@ def gather_neighbors(
         rows = _apart(_gather_xla(table, nbr_idx, rev_idx, rev_mask), x)
     else:
         pieces = 0 if exact is None else 3 * exact.shape[-1]
-        _report("gather", n, k_in, d + pieces, x.dtype, h)
+        _report("gather", n, k_in, d + pieces, x.dtype, h, _lanes(x, exact))
         rows = _gather_onehot(h, x, exact, nbr_idx, nbr_mask)
     return rows[0] if exact is None else rows
 
@@ -580,7 +594,8 @@ def aggregate_to_senders(
     accumulator leaves as it is), their cotangents likewise; under ``xla``
     they are further columns of one (f32) operand.
 
-    Reported as an ``agg_choice`` event (``scatter/...``, ``gather``, ``h``).
+    Reported as an ``agg_choice`` event (``scatter/...``, ``gather``, ``h``,
+    ``chunks``).
     """
     n, k_in, d = h.shape
     halo = _halo(h, exact, nbr_idx, nbr_mask, nbr_reach)
@@ -592,7 +607,9 @@ def aggregate_to_senders(
         )
     else:
         pieces = 0 if exact is None else 3 * exact.shape[-1]
-        _report("scatter", n, k_in, d + pieces, h.dtype, halo)
+        _report(
+            "scatter", n, k_in, d + pieces, h.dtype, halo, _lanes(h, exact)
+        )
         sums = _sender_sum_onehot(halo, h, exact, nbr_idx, nbr_mask)
     return sums[0] if exact is None else sums
 

@@ -16,7 +16,8 @@ window:
 - backward, per block of ``BLOCK`` senders: ``gx[block] = sum_o onehot^T
   @ g[:, block b + o]``, one product per block of the window over its K x
   ``BLOCK`` (slot, receiver) rows, the 0/1 matrix masked by ``nbr_mask``,
-  accumulated in f32 and cast once.
+  accumulated in f32 and cast once; where the whole list's window does
+  not fit VMEM, over ``nk`` chunks of the K slots in turn (below).
 
 One product per block, not one per slot: the kernels' bodies stay a few
 dozen operations, so tracing and lowering them (set-up time, paid by
@@ -77,6 +78,23 @@ call asks for its own count with room and never for less than those 32
 MiB (a call that fitted there compiles as it did), and the rule refuses a
 window whose limit would pass ``_VMEM_BLOCK_BUDGET``: one count for the
 rule and the kernel, so the two cannot disagree.
+
+Where the backward's whole list would pass the budget (CGCNN's 47-50
+slots over 512 columns: 46-74 MiB), it sums the slots in chunks:
+:func:`slot_chunks` is the fewest chunks of ``Kc = ceil(K / nk)`` slots
+whose one grid step fits, from ``(K, h, lanes)`` alone, and
+the rule, the call and the ``agg_choice`` event's ``chunks`` all take it
+from there. The chunks are a second, inner grid axis (``"arbitrary"``):
+each step holds the window's ``[Kc, BLOCK, D]`` cotangent blocks and its
+``[1, Kc BLOCK]`` sender lanes (block-major, so a chunk's lanes are
+contiguous); the f32 accumulator is zeroed at the first chunk and cast
+and written at the last. The 0/1 work is the whole list's, in pieces.
+Where ``Kc`` does not divide K the last chunk's block reaches past the
+array's end, and that chunk reads its real slots alone (a static slice:
+whatever the buffer holds there, a NaN, reaches no product). At ``nk =
+1`` the call is the one-axis grid it always was, the same traced kernel
+and limit. The forward is never chunked: its count then sets the rule's
+edge (60 slots at h = 2 over 512 columns).
 """
 
 import functools
@@ -176,9 +194,23 @@ def window_halo(
     lanes = _cdiv(dim, _LANES)
     if (2 * h + 1) * lanes > MAX_WINDOW_TILES:
         return None
-    if _vmem_limit(max(_vmem_bytes(k_in, h, lanes))) > _VMEM_BLOCK_BUDGET:
+    if not all(map(_fits, _vmem_bytes(k_in, h, lanes))):
         return None
     return h
+
+
+def slot_chunks(k_in: int, h: int, dim: int) -> int:
+    """How many chunks of its ``k_in`` slots the backward kernel sums a
+    block's lists in, for a window of ``2h + 1`` blocks over tables
+    ``dim`` columns wide (:func:`lane_width`): the fewest whose one
+    chunk's count fits the budget, 1 wherever the whole list does. The
+    one answer for the rule, the kernel call and the ``agg_choice``
+    event's ``chunks``."""
+    lanes = _cdiv(dim, _LANES)
+    nk = 1
+    while nk < k_in and not _fits(_bwd_bytes(_cdiv(k_in, nk), h, lanes)):
+        nk += 1
+    return nk
 
 
 def _vmem_bytes(k_in: int, h: int, lanes: int):
@@ -186,10 +218,11 @@ def _vmem_bytes(k_in: int, h: int, lanes: int):
     for lists of ``k_in`` slots, a window of ``2h + 1`` blocks and tables
     of ``lanes`` lane tiles side by side, as the v5e compiler allocates it:
     every block a ``BlockSpec`` names twice (Pallas double-buffers them),
-    the scratch, and the kernels' large values. Over K 4-100, h 1-3 and 1-4
-    lane tiles it is 1.07-1.7 x what the compiler asked for the backward,
-    1.2-3 x for the forward (PERF.md section 6); the backward is the
-    larger at every shape."""
+    the scratch, and the kernels' large values. The backward's is that of
+    ONE chunk of :func:`slot_chunks`' (the whole list wherever it fits).
+    Over K 4-100, h 1-3 and 1-4 lane tiles it is 1.07-1.7 x what the
+    compiler asked for the backward, 1.2-3 x for the forward (PERF.md
+    section 6)."""
     w, d = 2 * h + 1, lanes * _LANES
     slab = k_in * BLOCK * d * 2  # one [K, BLOCK, D] bf16 block
     onehot = k_in * BLOCK * BLOCK  # elements of the 0/1 matrix per block
@@ -200,14 +233,22 @@ def _vmem_bytes(k_in: int, h: int, lanes: int):
         + w * onehot * 3  # the [K BLOCK, w BLOCK] 0/1 matrix and its compare
         + 2 * slab  # the product, in f32
     )
-    backward = (
-        2 * w * k_in * BLOCK * 4  # the senders' [1, K BLOCK] windows
+    chunk = _cdiv(k_in, slot_chunks(k_in, h, d))
+    return forward, _bwd_bytes(chunk, h, lanes)
+
+
+def _bwd_bytes(kc: int, h: int, lanes: int) -> int:
+    """What one grid step of the backward holds for a chunk of ``kc``
+    slots (see :func:`_vmem_bytes`)."""
+    w, d = 2 * h + 1, lanes * _LANES
+    slab = kc * BLOCK * d * 2  # one [Kc, BLOCK, D] bf16 block
+    return (
+        2 * w * kc * BLOCK * 4  # the senders' [1, Kc BLOCK] windows
         + 2 * w * slab  # the cotangents' window
         + 3 * BLOCK * d * 4  # the result block (f32 at most), the accumulator
-        + onehot * 6  # the [BLOCK, K BLOCK] 0/1 matrix and the int32 iota
+        + kc * BLOCK * BLOCK * 6  # the [BLOCK, Kc BLOCK] 0/1 matrix, the int32 iota
         + slab  # a ragged block's cotangents, masked
     )
-    return forward, backward
 
 
 def _vmem_limit(need: int) -> int:
@@ -215,24 +256,37 @@ def _vmem_limit(need: int) -> int:
     return max(_VMEM_LIMIT_FLOOR, need + _VMEM_HEADROOM)
 
 
-def _compiler_params(need: int):
+def _fits(need: int) -> bool:
+    """Whether that limit stays within ``_VMEM_BLOCK_BUDGET`` (the floor
+    lies under the budget, so only the count and its headroom can pass
+    it)."""
+    return need + _VMEM_HEADROOM <= _VMEM_BLOCK_BUDGET
+
+
+def _compiler_params(need: int, chunked: bool = False):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",), vmem_limit_bytes=_vmem_limit(need)
+        dimension_semantics=(
+            ("parallel", "arbitrary") if chunked else ("parallel",)
+        ),
+        vmem_limit_bytes=_vmem_limit(need),
     )
 
 
-def _window_specs(block, axis: int, h: int, last: int):
+def _window_specs(block, axis: int, h: int, last: int, chunk_axis=None):
     """One BlockSpec per block ``b - h .. b + h`` of the window along
     ``axis``, each held inside the array (what a block beyond either end
-    fetches instead is never used: see the kernels)."""
+    fetches instead is never used: see the kernels); along ``chunk_axis``,
+    where given, the block of the grid's second index."""
     from jax.experimental import pallas as pl
 
     def spec(offset):
-        def index(b):
+        def index(b, *chunk):
             at = [0] * len(block)
             at[axis] = jnp.clip(b + offset, 0, last)
+            if chunk_axis is not None:
+                at[chunk_axis] = chunk[0]
             return tuple(at)
 
         return pl.BlockSpec(block, index)
@@ -268,7 +322,7 @@ def _fwd_kernel(h, idx_ref, *refs):
         out_ref[...] = out.astype(out_ref.dtype).reshape(out_ref.shape)
 
 
-def _bwd_kernel(h, n_rows, *refs):
+def _bwd_kernel(h, n_rows, k_in, *refs):
     from jax.experimental import pallas as pl
 
     w = 2 * h + 1
@@ -279,42 +333,77 @@ def _bwd_kernel(h, n_rows, *refs):
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     tail = n_rows % BLOCK  # real rows of a ragged last block
-    k_in = g_refs[0].shape[0]
-    sub = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, k_in * BLOCK), 0)
-    for acc_ref in acc_refs:
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    kc = g_refs[0].shape[0]  # slots a chunk (all K where the list is one)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, kc * BLOCK), 0)
 
-    def accumulate(o, ragged):
+    def zero():
+        for acc_ref in acc_refs:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(o, ragged, slots):
+        # the last chunk, where Kc does not divide K, reads its real slots
+        # alone: the rest of its block lies past the array's end, where
+        # whatever the buffer held (a NaN) would spread over the block
         gs = []
         for p in range(parts):
-            g = g_refs[p * w + o + h][...]  # [K, BLOCK receivers, D]
+            g_ref = g_refs[p * w + o + h]  # [Kc, BLOCK receivers, D]
+            g = g_ref[...] if slots == kc else g_ref[:slots]
             if ragged:
                 # rows past the array's end hold whatever the buffer held:
                 # a product would spread a NaN there over the block
                 row = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
                 g = jnp.where(row < tail, g, 0)
             gs.append(g)
-        # [1, K BLOCK]: lanes = (slot, receiver)
-        senders = idx_refs[o + h][...] - b * BLOCK
-        onehot_t = (senders == sub).astype(gs[0].dtype)
+        # [1, slots BLOCK]: lanes = (slot, receiver)
+        if slots == kc:
+            senders, rows = idx_refs[o + h][...] - b * BLOCK, sub
+        else:  # an iota of its own: the compiler slices none along lanes
+            senders = idx_refs[o + h][:, : slots * BLOCK] - b * BLOCK
+            rows = jax.lax.broadcasted_iota(
+                jnp.int32, (BLOCK, slots * BLOCK), 0
+            )
+        onehot_t = (senders == rows).astype(gs[0].dtype)
         for g, acc_ref in zip(gs, acc_refs):
             acc_ref[...] += jnp.dot(
-                onehot_t, g.reshape(k_in * BLOCK, g.shape[2]),
+                onehot_t, g.reshape(slots * BLOCK, g.shape[2]),
                 preferred_element_type=jnp.float32,
             )
 
-    for o in range(-h, h + 1):
-        blk = b + o
-        inside = (blk >= 0) & (blk < nb)
-        if tail:
-            pl.when(inside & (blk < nb - 1))(
-                functools.partial(accumulate, o, False)
-            )
-            pl.when(blk == nb - 1)(functools.partial(accumulate, o, True))
-        else:
-            pl.when(inside)(functools.partial(accumulate, o, False))
-    for out_ref, acc_ref in zip(out_refs, acc_refs):
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+    def window(slots):
+        for o in range(-h, h + 1):
+            blk = b + o
+            inside = (blk >= 0) & (blk < nb)
+            if tail:
+                pl.when(inside & (blk < nb - 1))(
+                    functools.partial(accumulate, o, False, slots)
+                )
+                pl.when(blk == nb - 1)(
+                    functools.partial(accumulate, o, True, slots)
+                )
+            else:
+                pl.when(inside)(functools.partial(accumulate, o, False, slots))
+
+    def write():
+        for out_ref, acc_ref in zip(out_refs, acc_refs):
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    nk = _cdiv(k_in, kc)
+    if nk == 1:
+        zero()
+        window(kc)
+        write()
+        return
+    # the chunks are the grid's inner axis: the accumulator holds a block
+    # of senders over them, zeroed at the first and written at the last
+    c = pl.program_id(1)
+    last = k_in - (nk - 1) * kc  # real slots of the last chunk
+    pl.when(c == 0)(zero)
+    if last == kc:
+        window(kc)
+    else:
+        pl.when(c < nk - 1)(functools.partial(window, kc))
+        pl.when(c == nk - 1)(functools.partial(window, last))
+    pl.when(c == nk - 1)(write)
 
 
 def _pad_lanes(a):
@@ -401,42 +490,52 @@ def scatter_product(
     given = g if isinstance(g, tuple) else (g,)
     if not isinstance(out_dtype, tuple):
         out_dtype = (out_dtype,) * len(given)
+    parts = tuple(_pad_lanes(p) for p in given)
+    nk = slot_chunks(given[0].shape[0], h, sum(p.shape[2] for p in parts))
     sums = _scatter_call(
-        tuple(_pad_lanes(p) for p in given), nbr_idx, nbr_mask, h, interpret,
+        parts, nbr_idx, nbr_mask, h, nk, interpret,
         tuple(jnp.dtype(d or p.dtype) for d, p in zip(out_dtype, given)),
     )
     sums = tuple(s[:, : p.shape[2]] for s, p in zip(sums, given))
     return sums if isinstance(g, tuple) else sums[0]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5))
-def _scatter_call(parts, nbr_idx, nbr_mask, h, interpret, out_dtypes):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _scatter_call(parts, nbr_idx, nbr_mask, h, nk, interpret, out_dtypes):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     k, n, _ = parts[0].shape
     nb = _cdiv(n, BLOCK)
     w = 2 * h + 1
+    kc = _cdiv(k, nk)  # slots a chunk
     # the mask folded into the lists (-1 names no sender), whole blocks,
-    # then block-major: one row of K x BLOCK (slot, receiver) per block
+    # then block-major: one row of K x BLOCK (slot, receiver) per block,
+    # whole chunks of it
     senders = jnp.pad(
         jnp.where(nbr_mask, nbr_idx, -1),
-        ((0, nb * BLOCK - n), (0, 0)), constant_values=-1,
+        ((0, nb * BLOCK - n), (0, nk * kc - k)), constant_values=-1,
     )
-    senders = senders.reshape(nb, BLOCK, k).transpose(0, 2, 1)
-    senders = senders.reshape(nb, 1, k * BLOCK)
-    _, need = _vmem_bytes(k, h, sum(p.shape[2] for p in parts) // _LANES)
+    senders = senders.reshape(nb, BLOCK, nk * kc).transpose(0, 2, 1)
+    senders = senders.reshape(nb, 1, nk * kc * BLOCK)
+    need = _bwd_bytes(kc, h, sum(p.shape[2] for p in parts) // _LANES)
+    chunked = nk > 1
     # numlint: disable=pallas-vmem-unbounded — gated by window_halo above
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, h, n),
-        grid=(nb,),
-        in_specs=_window_specs((None, 1, k * BLOCK), 0, h, nb - 1)
+        functools.partial(_bwd_kernel, h, n, k),
+        grid=(nb, nk) if chunked else (nb,),
+        in_specs=_window_specs(
+            (None, 1, kc * BLOCK), 0, h, nb - 1, 2 if chunked else None
+        )
         + [
             spec for p in parts
-            for spec in _window_specs((k, BLOCK, p.shape[2]), 1, h, nb - 1)
+            for spec in _window_specs(
+                (kc, BLOCK, p.shape[2]), 1, h, nb - 1, 0 if chunked else None
+            )
         ],
         out_specs=[
-            pl.BlockSpec((BLOCK, p.shape[2]), lambda b: (b, 0)) for p in parts
+            pl.BlockSpec((BLOCK, p.shape[2]), lambda b, *_: (b, 0))
+            for p in parts
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, p.shape[2]), d)
@@ -445,7 +544,7 @@ def _scatter_call(parts, nbr_idx, nbr_mask, h, interpret, out_dtypes):
         scratch_shapes=[
             pltpu.VMEM((BLOCK, p.shape[2]), jnp.float32) for p in parts
         ],
-        compiler_params=_compiler_params(need),
+        compiler_params=_compiler_params(need, chunked),
         interpret=interpret,
         name="gather_neighbors_onehot_bwd",
     )(*([senders] * w), *(p for p in parts for _ in range(w)))

@@ -90,23 +90,31 @@ FLOOR = {"gather_neighbors_onehot": {32 * 1024**2},
 
 
 @pytest.mark.parametrize(
-    "n,reach,k_in,dim",
+    "n,reach,k_in,dim,chunks",
     [
-        (88648, 225, 12, 256),  # the PNA cell's largest bucket: h = 2
-        (39432, 129, 12, 1),  # its first layer: one feature, padded to 128
-        (4104, 257, 30, 512),  # the rule's limit: 20 tiles, the backward at the budget
-        (4104, 1153, 12, 1),  # the farthest reach it admits: h = 9, 19 tiles
-        (4104, 129, 145, 128),  # the most slots it admits at h = 1
-        (22192, 225, 50, 256),  # the SchNet cell's largest bucket: h = 2
-        (14416, 137, 50, 256),  # and its second: h = 2
+        (88648, 225, 12, 256, 1),  # the PNA cell's largest bucket: h = 2
+        (39432, 129, 12, 1, 1),  # its first layer: one feature, padded to 128
+        # the rule's limit: 20 tiles, the forward at the budget (the
+        # backward in two chunks of 30)
+        (4104, 257, 60, 512, 2),
+        (4104, 1153, 12, 1, 1),  # the farthest reach it admits: h = 9, 19 tiles
+        # the most slots it admits at h = 1: the forward at the budget
+        (4104, 129, 171, 128, 2),
+        (22192, 225, 50, 256, 1),  # the SchNet cell's largest bucket: h = 2
+        (14416, 137, 50, 256, 1),  # and its second: h = 2
+        # the CGCNN cell's smallest bucket (h = 1, 47 slots as 24 + 23)
+        # and its largest (h = 2, 50 as 25 + 25), 512 columns
+        (3064, 62, 47, 512, 2),
+        (11056, 225, 50, 512, 2),
     ],
 )
 def pytest_local_gather_compiles_where_the_rule_selects_it(
-    chip, monkeypatch, n, reach, k_in, dim
+    chip, monkeypatch, n, reach, k_in, dim, chunks
 ):
     monkeypatch.setattr(da, "_backend", lambda: "tpu")
     h = lg.window_halo(jnp.bfloat16, reach, k_in, dim, "tpu")
     assert h == -(-(reach - 1) // lg.BLOCK)
+    assert lg.slot_chunks(k_in, h, dim) == chunks
     # one more slot, or one more block of reach, and the rule keeps XLA's
     if n == 4104 and k_in > 12:
         assert lg.window_halo(jnp.bfloat16, reach, k_in + 1, dim, "tpu") is None
@@ -126,9 +134,12 @@ def pytest_local_gather_compiles_where_the_rule_selects_it(
         ),
     )
     assert text.count(KERNEL) == 2
-    # each call's limit follows its own reckoning, and is the one every
-    # call had wherever that reckoning is under it (the PNA cell's calls)
-    fwd, bwd = lg._vmem_bytes(k_in, h, -(-dim // 128))
+    # each call's limit follows its own reckoning (the backward's, of one
+    # chunk), and is the one every call had wherever that reckoning is
+    # under it (the PNA cell's calls)
+    lanes = -(-dim // 128)
+    fwd, bwd = lg._vmem_bytes(k_in, h, lanes)
+    assert bwd == lg._bwd_bytes(-(-k_in // chunks), h, lanes)
     limits = _scoped_vmem(text)
     assert limits == {"gather_neighbors_onehot": {lg._vmem_limit(fwd)},
                       "gather_neighbors_onehot_bwd": {lg._vmem_limit(bwd)}}

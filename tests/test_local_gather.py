@@ -85,22 +85,28 @@ def _rule_answers(monkeypatch):
 
 
 @pytest.mark.parametrize(
-    "h,dim,slots",
-    [(1, 1, None), (1, 64, None), (1, 130, None),
-     (2, 1, None), (2, 64, None), (2, 130, None),
+    "h,dim,degree,chunks",
+    [(1, 1, 5, 1), (1, 64, 5, 1), (1, 130, 5, 1),
+     (2, 1, 5, 1), (2, 64, 5, 1), (2, 130, 5, 1),
      # the SchNet cell's large buckets: 50 slots, h = 2, 256 columns
-     (2, 256, 50)],
-    ids=["1-1", "1-64", "1-130", "2-1", "2-64", "2-130", "2-256-k50"],
+     (2, 256, 50, 1),
+     # the backward over chunks of the slots, forced by a budget lowered
+     # to one chunk's count: 6 slots as 3 + 3; 7 as 4 + 3 and 50 as
+     # 17 + 17 + 16, a last chunk whose block reaches past K
+     (1, 130, 5, 2), (2, 130, 6, 2), (2, 256, 50, 3)],
+    ids=["1-1", "1-64", "1-130", "2-1", "2-64", "2-130", "2-256-k50",
+         "1-130-c2", "2-130-k7-c2", "2-256-k50-c3"],
 )
-def pytest_product_equals_indexed_read(h, dim, slots):
-    batch, layout = _collated(_SIZES[h], degree=slots or 5)
+def pytest_product_equals_indexed_read(monkeypatch, h, dim, degree, chunks):
+    batch, layout = _collated(_SIZES[h], degree=degree)
     ex = batch.extras
     assert ex["nbr_reach"].shape == (layout.nbr_reach,)
     assert lg._cdiv(layout.nbr_reach - 1, lg.BLOCK) == h
     n, k = ex["nbr_idx"].shape
     assert n % lg.BLOCK  # the last block is ragged
-    assert slots in (None, k)
+    assert degree < 50 or k == 50
     assert lg.window_halo(jnp.bfloat16, layout.nbr_reach, k, dim, "tpu") == h
+    assert lg.slot_chunks(k, h, dim) == 1  # the whole list fits
     rng = np.random.default_rng(dim)
     x = jnp.asarray(rng.standard_normal((n, dim)), jnp.bfloat16)
     idx, mask = jnp.asarray(ex["nbr_idx"]), jnp.asarray(ex["nbr_mask"])
@@ -120,7 +126,24 @@ def pytest_product_equals_indexed_read(h, dim, slots):
     # backward: today's reverse-list gather, within one bf16 ulp (both
     # accumulate in f32 and cast once; only the order of the sum differs)
     g = jnp.asarray(rng.standard_normal(ref.shape), jnp.bfloat16)
-    gx = lg.scatter_product(g.transpose(1, 0, 2), idx, mask, h, interpret=True)
+    g_t = g.transpose(1, 0, 2)
+    if chunks > 1:
+        f32_sums = lambda: lg.scatter_product(  # noqa: E731
+            g_t, idx, mask, h, interpret=True, out_dtype=jnp.float32
+        )
+        whole = f32_sums()
+        kc = lg._cdiv(k, chunks)
+        monkeypatch.setattr(
+            lg, "_VMEM_BLOCK_BUDGET",
+            lg._bwd_bytes(kc, h, lg._cdiv(dim, 128)) + lg._VMEM_HEADROOM,
+        )
+        assert lg.slot_chunks(k, h, dim) == chunks
+        # the chunks' sums equal the whole list's up to the order of an
+        # f32 sum
+        np.testing.assert_allclose(
+            f32_sums(), whole, rtol=2.0 ** -20, atol=2.0 ** -20
+        )
+    gx = lg.scatter_product(g_t, idx, mask, h, interpret=True)
     _, vjp = jax.vjp(
         lambda t: da._gather_xla(t, idx, ex["rev_idx"], ex["rev_mask"]), x
     )
@@ -129,6 +152,50 @@ def pytest_product_equals_indexed_read(h, dim, slots):
     a, b = np.asarray(gx, np.float32), np.asarray(gx_ref, np.float32)
     scale = np.maximum(np.abs(b), 1.0)
     assert np.max(np.abs(a - b) / scale) <= BF16_ULP
+
+
+def pytest_last_chunk_reads_no_slot_past_k(monkeypatch):
+    """7 slots in chunks of 4: the last chunk's cotangent block holds 3
+    real slots and one past K, where the interpreter (asked for it) fills
+    NaN, as a chip's buffer may hold anything. No output row reads it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, _ = _collated(_SIZES[2], degree=6)
+    ex = batch.extras
+    idx, mask = jnp.asarray(ex["nbr_idx"]), jnp.asarray(ex["nbr_mask"])
+    n, k = idx.shape
+    assert k == 7
+    monkeypatch.setattr(
+        lg, "_VMEM_BLOCK_BUDGET", lg._bwd_bytes(4, 2, 1) + lg._VMEM_HEADROOM
+    )
+    assert lg.slot_chunks(k, 2, 8) == 2
+    rng = np.random.default_rng(3)
+    g = jnp.asarray(rng.standard_normal((k, n, 8)), jnp.bfloat16)
+
+    @jax.jit
+    def last_chunk(g):  # what the last chunk's block holds, as read
+        return pl.pallas_call(
+            lambda g_ref, o_ref: o_ref.__setitem__(..., g_ref[...]),
+            grid=(1,),
+            in_specs=[pl.BlockSpec((4, lg.BLOCK, 8), lambda i: (1, 0, 0))],
+            out_specs=pl.BlockSpec((4, lg.BLOCK, 8), lambda i: (0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((4, lg.BLOCK, 8), g.dtype),
+        )(g)
+
+    nan = pltpu.InterpretParams(uninitialized_memory="nan")
+    with pltpu.force_tpu_interpret_mode(nan):
+        held = np.asarray(last_chunk(g), np.float32)
+        gx = jax.jit(lambda g: lg.scatter_product(g, idx, mask, 2))(g)
+    assert np.isnan(held[3]).all() and np.isfinite(held[:3]).all()
+    a = np.asarray(gx, np.float32)
+    _, vjp = jax.vjp(
+        lambda t: da._gather_xla(t, idx, ex["rev_idx"], ex["rev_mask"]),
+        jnp.zeros((n, 8), jnp.bfloat16),
+    )
+    b = np.asarray(vjp(g.transpose(1, 0, 2))[0], np.float32)
+    assert np.isfinite(a).all()
+    assert np.max(np.abs(a - b) / np.maximum(np.abs(b), 1.0)) <= BF16_ULP
 
 
 def pytest_padded_slot_cotangents_are_not_read():
@@ -445,6 +512,13 @@ def pytest_stack_grads_equal_on_both_paths(monkeypatch, model_type, products):
         # backward's window of 31 MiB within the budget
         ("schnet_n14416", {"reach": 137, "k_in": 50}, 2),
         ("schnet_n22192", {"reach": 225, "k_in": 50}, 2),
+        # the CGCNN cell's smallest and largest buckets: 512 columns, the
+        # backward's window over the budget whole, within it in two chunks
+        ("cgcnn_n3064", {"reach": 62, "k_in": 47, "dim": 512}, 1),
+        ("cgcnn_n11056", {"reach": 225, "k_in": 50, "dim": 512}, 2),
+        # past 60 slots at that window the forward's count passes it
+        ("cgcnn_forward_over_vmem",
+         {"reach": 225, "k_in": 61, "dim": 512}, None),
     ],
 )
 def pytest_rule_selects_from_operands_alone(case, kwargs, want):
@@ -453,6 +527,31 @@ def pytest_rule_selects_from_operands_alone(case, kwargs, want):
     )
     args.update(kwargs)
     assert lg.window_halo(**args) == want
+
+
+@pytest.mark.parametrize(
+    "k_in,h,dim,chunks",
+    [
+        (12, 2, 256, 1),  # the PNA cell's largest bucket
+        (16, 2, lg.lane_width(128, 12), 1),  # the EGNN cell's, tables and pieces
+        (50, 2, 256, 1),  # the SchNet cell's two large buckets
+        (47, 1, 512, 2),  # the CGCNN cell's four: 24 + 23 slots
+        (50, 1, 512, 2),  # 25 + 25
+        (50, 2, 512, 2),
+        (60, 2, 512, 2),  # the most slots the rule admits there: 30 + 30
+    ],
+)
+def pytest_backward_sums_the_fewest_chunks_that_fit(k_in, h, dim, chunks):
+    """The chunk count is a property of the operands: the fewest whose
+    one chunk's count fits the budget, one where the whole list does."""
+    lanes = lg._cdiv(dim, 128)
+    assert lg.slot_chunks(k_in, h, dim) == chunks
+    fits = lambda kc: lg._fits(lg._bwd_bytes(kc, h, lanes))  # noqa: E731
+    assert fits(lg._cdiv(k_in, chunks))
+    assert chunks == 1 or not fits(lg._cdiv(k_in, chunks - 1))
+    assert lg._vmem_bytes(k_in, h, lanes)[1] == lg._bwd_bytes(
+        lg._cdiv(k_in, chunks), h, lanes
+    )
 
 
 def _emitted(tmp_path, fn):
@@ -490,12 +589,24 @@ def pytest_edge_list_caller_keeps_xla_gather(tmp_path, monkeypatch):
     ]
 
 
-@pytest.mark.parametrize("kind", ["gather", "scatter"])
-def pytest_stated_batch_reports_onehot(tmp_path, monkeypatch, kind):
+@pytest.mark.parametrize(
+    "kind,chunks", [("gather", 1), ("scatter", 1), ("gather", 2)],
+    ids=["gather", "scatter", "gather-chunks2"],
+)
+def pytest_stated_batch_reports_onehot(tmp_path, monkeypatch, kind, chunks):
     """One line per gathered table and per summed operand, its width what
-    goes through the product: 8 columns and 3 f32 columns as 9 pieces."""
+    goes through the product: 8 columns and 3 f32 columns as 9 pieces (two
+    lane tiles); ``chunks`` the backward kernel's, two under a budget that
+    holds the forward and half the list's backward, not all of it."""
     batch, _ = _collated(_SIZES[2])
     n, k = batch.extras["nbr_idx"].shape
+    if chunks > 1:
+        fwd, _ = lg._vmem_bytes(k, 2, 2)
+        half = lg._bwd_bytes(lg._cdiv(k, 2), 2, 2)
+        monkeypatch.setattr(
+            lg, "_VMEM_BLOCK_BUDGET", max(fwd, half) + lg._VMEM_HEADROOM
+        )
+        assert lg.slot_chunks(k, 2, 256) == 2
     if kind == "gather":
         x = jnp.ones((n, 8), jnp.bfloat16)
         call = lambda: da.neighbor_rows(  # noqa: E731
@@ -512,6 +623,7 @@ def pytest_stated_batch_reports_onehot(tmp_path, monkeypatch, kind):
         dict(
             events[0], bucket=f"{kind}/n{n}/k{k}/d17/bfloat16",
             choice="onehot", source="operands", gather="onehot", h=2,
+            chunks=chunks,
         )
     ]
 
